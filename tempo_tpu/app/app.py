@@ -146,6 +146,10 @@ class App:
         self.bus = None
         self.blockbuilder = None
         self._lifecyclers: list[Lifecycler] = []
+        # before the first jit: a restarted server must find its compiled
+        # bucket shapes again instead of paying minutes of XLA per boot
+        from tempo_tpu.obs.jaxruntime import configure_compile_cache
+        configure_compile_cache()
         # warm the native layer at startup so the first proto push never
         # pays the g++ compile inside a request handler
         from tempo_tpu import native

@@ -173,45 +173,48 @@ def pa_is_fixed(t) -> bool:
 
 
 def _rows_to_spans(tbl, rows: np.ndarray) -> list[dict]:
-    """Materialize selected rows back into flat span dicts (find-by-id path)."""
-    cols = {n: tbl.column(n) for n in tbl.schema.names}
+    """Materialize selected rows back into flat span dicts (find-by-id
+    path, WAL completion). Each column converts ONCE (a per-row scalar
+    `as_py()` costs tens of microseconds per span across ~30 columns)."""
+    import pyarrow as pa
+
+    whole = len(rows) == tbl.num_rows and \
+        bool((rows == np.arange(tbl.num_rows)).all())
+    picked = tbl if whole else tbl.take(pa.array(rows, pa.int64()))
+    c = {n: picked.column(n).to_pylist() for n in tbl.schema.names}
     out = []
-    for r in rows.tolist():
+    for r in range(picked.num_rows):
         attrs: dict = {}
         for kcol, vcol in (("sattr_str_keys", "sattr_str_vals"),
                            ("sattr_int_keys", "sattr_int_vals"),
                            ("sattr_f64_keys", "sattr_f64_vals"),
                            ("sattr_bool_keys", "sattr_bool_vals")):
-            ks = cols[kcol][r].as_py() or []
-            vs = cols[vcol][r].as_py() or []
-            attrs.update(zip(ks, vs))
+            attrs.update(zip(c[kcol][r] or [], c[vcol][r] or []))
         res_attrs: dict = {}
         for kcol, vcol in (("rattr_str_keys", "rattr_str_vals"),
                            ("rattr_int_keys", "rattr_int_vals"),
                            ("rattr_f64_keys", "rattr_f64_vals"),
                            ("rattr_bool_keys", "rattr_bool_vals")):
-            ks = cols[kcol][r].as_py() or []
-            vs = cols[vcol][r].as_py() or []
-            res_attrs.update(zip(ks, vs))
-        start = cols["start_unix_nano"][r].as_py()
+            res_attrs.update(zip(c[kcol][r] or [], c[vcol][r] or []))
+        start = c["start_unix_nano"][r]
         out.append({
-            "trace_id": cols["trace_id"][r].as_py(),
-            "span_id": cols["span_id"][r].as_py(),
-            "parent_span_id": cols["parent_span_id"][r].as_py(),
-            "name": cols["name"][r].as_py(),
-            "service": cols["service"][r].as_py(),
-            "kind": cols["kind"][r].as_py(),
-            "status_code": cols["status_code"][r].as_py(),
-            "status_message": cols["status_message"][r].as_py(),
+            "trace_id": c["trace_id"][r],
+            "span_id": c["span_id"][r],
+            "parent_span_id": c["parent_span_id"][r],
+            "name": c["name"][r],
+            "service": c["service"][r],
+            "kind": c["kind"][r],
+            "status_code": c["status_code"][r],
+            "status_message": c["status_message"][r],
             "start_unix_nano": start,
-            "end_unix_nano": start + cols["duration_ns"][r].as_py(),
+            "end_unix_nano": start + c["duration_ns"][r],
             "attrs": attrs,
             "res_attrs": res_attrs,
             "events": [{"time_unix_nano": t, "name": n} for t, n in
-                       zip(cols["event_times"][r].as_py() or [],
-                           cols["event_names"][r].as_py() or [])],
+                       zip(c["event_times"][r] or [],
+                           c["event_names"][r] or [])],
             "links": [{"trace_id": t, "span_id": s} for t, s in
-                      zip(cols["link_trace_ids"][r].as_py() or [],
-                          cols["link_span_ids"][r].as_py() or [])],
+                      zip(c["link_trace_ids"][r] or [],
+                          c["link_span_ids"][r] or [])],
         })
     return out

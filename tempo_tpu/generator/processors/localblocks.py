@@ -25,6 +25,7 @@ import numpy as np
 from tempo_tpu.backend.raw import RawWriter, block_keypath
 from tempo_tpu.ingester.instance import InstanceConfig, TenantInstance
 from tempo_tpu.model.span_batch import SpanBatch
+from tempo_tpu.overrides.limits import IngestionLimits, Limits
 from tempo_tpu.traceql.memview import view_from_traces
 from tempo_tpu.traceql.metrics_summary import MetricsResults, get_metrics
 
@@ -63,6 +64,10 @@ class LocalBlocksProcessor:
                 max_block_bytes=self.cfg.max_block_bytes,
                 trace_idle_s=self.cfg.trace_idle_s,
                 replication_factor=1),
+            # this store's cap is ITS config (0 = unlimited), not the
+            # ingester's per-tenant default of 10,000 live traces
+            limits=Limits(ingestion=IngestionLimits(
+                max_traces_per_user=self.cfg.max_live_traces)),
             now=now)
         self.inst.replay()
 

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import threading
 
 import numpy as np
 
@@ -87,6 +88,10 @@ class ServiceGraphsProcessor:
             self.messaging_hist = None
         self._store: dict[bytes, _HalfEdge] = {}
         self._ttl: collections.deque[tuple[float, bytes]] = collections.deque()
+        # one tenant's pushes arrive on concurrent HTTP handler threads:
+        # the edge store AND the families' read-update-rebind of device
+        # state must see one push at a time, or a completed edge is lost
+        self._push_lock = threading.Lock()
         self.dropped = 0  # store-full drops (`store.go` max_items)
         self.expired = 0
 
@@ -99,6 +104,10 @@ class ServiceGraphsProcessor:
         if sb.interner is not self.registry.interner:
             raise ValueError(
                 "SpanBatch must be built with the tenant registry's interner")
+        with self._push_lock:
+            self._push_batch(sb)
+
+    def _push_batch(self, sb: SpanBatch) -> None:
         now = self.registry.now()
         kinds = sb.kind
         client_like = (kinds == KIND_CLIENT) | (kinds == KIND_PRODUCER)

@@ -14,6 +14,7 @@ never pay (or require) a jax initialization.
 from __future__ import annotations
 
 import contextlib
+import os
 import time
 
 from tempo_tpu.obs.registry import Registry, exponential_buckets
@@ -42,37 +43,44 @@ KERNEL_SECONDS = RUNTIME.histogram(
     buckets=exponential_buckets(1e-5, 4.0, 12))
 
 
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def configure_compile_cache() -> str:
+    """Place JAX's persistent compile cache before the first jit; returns
+    the directory in use. Where JAX_COMPILATION_CACHE_DIR is set JAX reads
+    it itself and no other directory is set here. Otherwise ONE fixed
+    directory inside the checkout: the path is part of every cache key,
+    so one built from a temp name, pid or time would never hit."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def instrumented_jit(fn, *, name: str | None = None, **jit_kwargs):
     """`jax.jit` wrapper that detects per-call compile-cache growth and
-    records compile count + wall seconds under the `fn` label.
-
-    Detection uses the jitted callable's `_cache_size()` when available
-    (any growth during a call means at least one fresh trace+compile);
-    older jax falls back to counting only the first call."""
+    records compile count + wall seconds under the `fn` label: any growth
+    of the jitted callable's `_cache_size()` during a call means at least
+    one fresh trace+compile."""
     import jax
 
     jfn = jax.jit(fn, **jit_kwargs)
     label = name or getattr(fn, "__name__", "jit")
-    state = {"first": True}
-
-    def _cache_size():
-        try:
-            return jfn._cache_size()
-        except Exception:
-            return None
 
     def wrapper(*args, **kwargs):
-        before = _cache_size()
+        before = jfn._cache_size()
         t0 = time.perf_counter()
         out = jfn(*args, **kwargs)
-        after = _cache_size()
-        if after is not None and before is not None:
-            if after > before:
-                JIT_COMPILES.inc(after - before, (label,))
-                JIT_COMPILE_SECONDS.inc(time.perf_counter() - t0, (label,))
-        elif state["first"]:
-            state["first"] = False
-            JIT_COMPILES.inc(1, (label,))
+        grown = jfn._cache_size() - before
+        if grown > 0:
+            JIT_COMPILES.inc(grown, (label,))
             JIT_COMPILE_SECONDS.inc(time.perf_counter() - t0, (label,))
         return out
 
@@ -96,6 +104,6 @@ def kernel_timer(kernel: str):
         KERNEL_SECONDS.observe(time.perf_counter() - t0, (kernel,))
 
 
-__all__ = ["RUNTIME", "instrumented_jit", "record_device_put",
-           "kernel_timer", "JIT_COMPILES", "JIT_COMPILE_SECONDS",
+__all__ = ["RUNTIME", "configure_compile_cache", "instrumented_jit",
+           "record_device_put", "kernel_timer", "JIT_COMPILES", "JIT_COMPILE_SECONDS",
            "DEVICE_PUT_BYTES", "KERNEL_SECONDS"]

@@ -45,14 +45,10 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map as _shard_map
 
 from tempo_tpu.obs.jaxruntime import instrumented_jit
 from tempo_tpu.ops import sketches
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -620,7 +616,7 @@ def fused_step(edges: tuple, gamma: float, min_value: float, dd_rows: int,
         batch_specs = (P(),) if packed else (P(),) * 4
         fn = _shard_map(sharded, mesh=mesh,
                         in_specs=arena_specs + table_specs + batch_specs,
-                        out_specs=arena_specs, check_rep=False)
+                        out_specs=arena_specs, check_vma=False)
         return instrumented_jit(fn, name="spanmetrics_fused_update_paged_mesh",
                                 donate_argnums=tuple(range(n_arenas)))
 

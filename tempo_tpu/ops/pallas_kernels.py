@@ -7,21 +7,20 @@ the state is dense or paged:
 
 1. **XLA scatter** (`ops/sketches.py` / `registry/metrics.py`,
    `.at[slots, ...].add`): XLA:TPU lowers batched scatters to a sort +
-   segmented reduction. Measured on a real v5e chip this sustains
-   ~3.7e9 spans/s through the FULL fused spanmetrics step (bench.py) —
-   370x the north-star target. On DENSE state this is the production
-   default and the measured winner.
+   segmented reduction. On DENSE state this is the production default;
+   its speed on the chip is not measured on today's code (the served
+   path runs on a v5e — `chip_smoke.py` — and nothing has timed it).
 2. **MXU one-hot matmul** (`fused_spanmetrics_matmul`): each span block
    builds a one-hot slot matrix and a feature matrix
    (count|dur|size|hist-onehot), and the partial state is
    `onehotᵀ @ features` — a dense [S, F] accumulation on the systolic
    array across a sequential grid over span blocks. This is the
    canonical "scatter as matmul" TPU trick; it pays S*F*N FLOPs for a
-   job that is information-theoretically O(N*F), so it only wins when S
-   is tiny. Measured on a real v5e-1 (262144 spans, 4096 series, 16
-   features): XLA scatter 81.4M spans/s, MXU matmul 81.6M spans/s —
-   parity on the fresh-delta shape, which is why dense state stays on
-   XLA.
+   job that is information-theoretically O(N*F), so it could only win
+   when S is tiny. **Refused by the v5e compiler, never run on a chip**
+   (Mosaic: "XLA layout ({0:T(1024)}) does not match Mosaic layout
+   ({0:T(512)}) for an operand of shape s32[16384]" — the 1-D
+   `(block,)` operands); it runs in interpret mode only.
 3. **Paged ragged fused update** (`paged_fused_update`, this PR): the
    paged layout (`registry/pages.py`) changed the shape of the problem.
    There the composed-scatter path (`ops/pages.py` `_fused_body`) issues
@@ -61,24 +60,25 @@ differential arm in tests/test_plane_fuzz.py):
   runbook "Choosing the update kernel"). The default `sketch: dd` f32
   tier stays bit-identical as above.
 
-Measured (benchmarks/bench_kernels.py `paged_fused` line / bench.py
-`paged_fused` stage), alongside the dense numbers above: on this repo's
-CPU-only containers the line gates on interpret-mode parity, not speed
-(Mosaic cannot lower to CPU) — r06 container run: interpret parity OK,
-composed-scatter baseline 0.72M / 0.65M / 0.94M spans/s at packed
-bucket sizes 256 / 4096 / 65536 through the full 7-scatter paged step
-(one contended CPU core; for scale, the same class of container runs
-the DENSE fused step at multi-M spans/s — the per-role indirection
-re-gather is exactly the gap this kernel exists to close). The ≥2x fused-update
-target over composed scatters on the packed `[roles, bucket]` shape is
-a real-TPU gate and is recorded by the same bench line when an
-accelerator is reachable at bench time.
+**Refused by the v5e compiler, never run on a chip.** Asked without a
+chip (tests/test_chip_compile.py, the strict xfail), Mosaic refuses
+`paged_fused_update` at the default paged shapes: "cannot statically
+prove that index in dimension 0 is a multiple of 1024" at the
+`slots_ref[pl.ds(base, blk)]` load; with the two repairs that message
+names (1,024-aligned span chunks via `pl.multiple_of`, slots as a
+`(1, n)` block) it next refuses "infer-vector-layout: unsupported shape
+cast" at `tpu.reshape vector<1024xi1> -> vector<1024x1xi1>` — the
+`[:, None]` column broadcasts the one-hot build rests on. More probably
+waits behind it (1-D `(page_rows,)` arena blocks, `acc_ref[:, c]` column
+reads, the unaligned feature concatenate). Everything this docstring
+says about one page-table walk and one writeback per page describes the
+formulation, checked in interpret mode on the CPU only; the tier stays
+opt-in (`spanmetrics.kernel: pallas`, default `xla`) and its ≥2x target
+over the composed scatters has never been evaluated.
 
-The dense MXU kernel is kept (a) as the measured justification for the
-dense-XLA default, (b) as the grid/BlockSpec/accumulator template this
-paged kernel grew from (per /opt/skills/guides/pallas_guide.md), and
-(c) because it fuses the whole feature plane into one MXU pass — the
-property the paged kernel inherits.
+The dense MXU kernel is kept as the grid/BlockSpec/accumulator template
+the paged kernel grew from; ROADMAP Design 9 decides whether either
+stays.
 """
 
 from __future__ import annotations

@@ -21,12 +21,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map as _shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 from tempo_tpu.ops import sketches
 from tempo_tpu.registry import metrics as rm
@@ -315,12 +311,12 @@ def sharded_serving_step(mesh: Mesh, edges: tuple, gamma: float,
     if mom_shard:
         state_specs += (P("series", None),)
     batch_specs = (P(None, "data"),) if packed else (P("data"),) * 4
-    # check_rep=False: the base-scatter branch's outputs ARE replicated
+    # check_vma=False: the base-scatter branch's outputs ARE replicated
     # over 'data' (the axis has size 1 there), but without a psum the
     # static replication checker can't infer it
     fn = _shard_map(step, mesh=mesh,
                     in_specs=state_specs + batch_specs,
-                    out_specs=state_specs, check_rep=False)
+                    out_specs=state_specs, check_vma=False)
     # instrumented: the serving path's zero-steady-state-recompile gate
     # (bench multichip stage) reads the per-fn compile counters
     from tempo_tpu.obs.jaxruntime import instrumented_jit

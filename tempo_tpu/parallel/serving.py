@@ -56,9 +56,10 @@ class MeshConfig:
     """Knobs for the serving mesh (`mesh:` in the app YAML)."""
 
     enabled: bool = False
-    # devices to enlist; 0 = every visible device. Non-power-of-two
-    # counts are clamped DOWN to the largest power of two so pow-2
-    # coalescer buckets always split evenly across shards.
+    # devices to enlist; 0 = every visible device. More than JAX sees is
+    # an error. Non-power-of-two counts are clamped DOWN to the largest
+    # power of two so pow-2 coalescer buckets always split evenly
+    # across shards.
     devices: int = 0
     # series shards; 0 = auto (all enlisted devices — data axis 1, the
     # bit-stable no-collective layout). Must divide the device count;
@@ -118,7 +119,12 @@ class ServingMesh:
         self.cfg = cfg
         devs = jax.devices()
         n = cfg.devices or len(devs)
-        n = min(n, len(devs))
+        if n > len(devs):
+            # never a silent clamp: a four-chip deployment that came up
+            # on one device would serve, and report, as if it were four
+            raise ValueError(
+                f"mesh.devices asks for {n} devices, JAX sees "
+                f"{len(devs)} ({devs[0].platform})")
         p2 = _pow2_floor(max(n, 1))
         if p2 != n:
             _LOG.warning(
@@ -244,19 +250,16 @@ _lock = threading.Lock()
 
 def configure(cfg: MeshConfig | None) -> "ServingMesh | None":
     """Build (or drop) the process serving mesh from the `mesh:` config
-    block. Returns the active mesh or None when disabled. Never raises
-    on a bad shape — it warns and falls back (serve time must not die
-    on a config typo; `Config.check()` already surfaced it)."""
+    block. Returns the active mesh or None when disabled. A bad SHAPE
+    warns and falls back inside `ServingMesh` (serve time must not die
+    on a shard-count typo; `Config.check()` already surfaced it); asking
+    for more devices than JAX sees raises — that is not a typo, it is a
+    deployment on the wrong machine."""
     global _active
     with _lock:
-        if cfg is None or not cfg.enabled:
-            _active = None
-            return None
-        try:
+        _active = None            # a failed build leaves no stale mesh
+        if cfg is not None and cfg.enabled:
             _active = ServingMesh(cfg)
-        except Exception as e:  # noqa: BLE001 — config fallback, logged
-            _LOG.error("serving mesh disabled: %r", e)
-            _active = None
         return _active
 
 

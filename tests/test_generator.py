@@ -141,6 +141,44 @@ def test_servicegraphs_edge_completion():
                         client="frontend", server="backend") == pytest.approx(0.2)
 
 
+def test_servicegraphs_concurrent_pushes_lose_no_edge():
+    """One tenant's pushes arrive on concurrent handler threads; every
+    completed edge must land (the store and the families' state rebind
+    are read-modify-write)."""
+    import sys
+    import threading
+
+    reg = ManagedRegistry(now=FakeClock())
+    p = ServiceGraphsProcessor(reg, ServiceGraphsConfig())
+    n_threads, n_pushes = 6, 15
+
+    def batch(k: int):
+        t = k.to_bytes(16, "big")
+        return _mk_batch(interner=reg.interner, spans=[
+            _span(1, service="frontend", kind=KIND_CLIENT, trace=t),
+            _span(2, service="backend", kind=KIND_SERVER, trace=t,
+                  parent=bytes([1]) * 8)])
+
+    # batches stage on one thread: interning is not what is under test
+    work = [[batch(i * n_pushes + j) for j in range(n_pushes)]
+            for i in range(n_threads)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(
+            target=lambda w=w: [p.push_batch(sb) for sb in w]) for w in work]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+            assert not th.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert series_value(reg.collect(1), "traces_service_graph_request_total",
+                        client="frontend", server="backend") \
+        == n_threads * n_pushes
+
+
 def test_servicegraphs_expiry_virtual_nodes():
     clock = FakeClock()
     reg = ManagedRegistry(now=clock)
@@ -223,6 +261,17 @@ def test_write_request_encoding_decodes():
     assert labels == {"__name__": "m_total", "svc": "a"}
     sf = pw.decode_fields(bytes(fields[2][0]))
     assert pw.f64(sf[1][0]) == 42.0 and sf[2][0] == 1234
+
+
+def test_write_request_label_memo_changes_no_byte():
+    """The per-request memo of encoded label pairs is an encoding cache,
+    nothing else: a request whose series share label pairs is the
+    concatenation of its samples encoded one by one."""
+    samples = [Sample(f"m_{k}", (("__name__", f"m_{k}"), ("svc", f"s{i % 3}"),
+                                 ("le", str(i))), float(i), 99)
+               for k in ("bucket", "count") for i in range(20)]
+    assert rw.encode_write_request(samples) == b"".join(
+        rw.encode_write_request([s]) for s in samples)
 
 
 def test_native_histogram_encoding():

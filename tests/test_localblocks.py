@@ -48,6 +48,18 @@ def test_span_dicts_respect_valid_mask():
     assert all(s["trace_id"] != bytes([3]) * 16 for s in spans)
 
 
+@pytest.mark.parametrize("cap,held", [(0, 20), (5, 5)])
+def test_live_trace_cap_is_the_processors_own(cap, held, tmp_path):
+    """`max_live_traces` (0 = unlimited) bounds the store — not the
+    ingester's per-tenant default, which silently dropped every trace
+    past 10,000 from the RF1 blocks TraceQL metrics read."""
+    p = LocalBlocksProcessor("t", LocalBlocksConfig(
+        data_dir=str(tmp_path), max_live_traces=cap))
+    assert p.inst.live.max_live_traces == cap
+    p.push_batch(build_batch(20))
+    assert len(p.inst.live) == held
+
+
 def test_span_dicts_round_trip():
     sb = build_batch(5)
     spans = sb.to_span_dicts()

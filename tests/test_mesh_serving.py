@@ -286,6 +286,18 @@ def test_configure_falls_back_on_bad_shape():
     assert serving.active() is None
 
 
+def test_configure_refuses_more_devices_than_visible():
+    """A mesh over more devices than JAX sees is a deployment on the
+    wrong machine: an error at boot, never a silent clamp that serves —
+    and reports — one device as if it were four."""
+    import jax
+
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match=f"asks for {n + 1} devices"):
+        serving.configure(serving.MeshConfig(enabled=True, devices=n + 1))
+    assert serving.active() is None
+
+
 def test_step_cache_not_keyed_by_mesh_id():
     """product._cached_step keys by mesh VALUE identity — two meshes
     with identical layouts share an entry; id() reuse can't alias."""

@@ -500,3 +500,29 @@ def test_concurrent_record_and_scrape_conformant():
     assert total > 0
     assert abs(total - sum(led.tenant_device_ns().values())) \
         <= total * 0.05
+
+
+def test_compile_cache_is_placed_from_outside_or_inside_the_checkout(
+        monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and no code sets another directory;
+    unset, the cache is ONE fixed directory inside the checkout (the path
+    is part of the cache key: a temp name would never hit)."""
+    import os
+
+    import jax
+
+    from tempo_tpu.obs import jaxruntime
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/outside")
+        assert jaxruntime.configure_compile_cache() == "/placed/outside"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        assert jaxruntime.configure_compile_cache() == want
+        assert jaxruntime.configure_compile_cache() == want    # idempotent
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -169,6 +169,30 @@ def test_wal_replay_after_crash(tmp_path):
     assert len(list_blocks(backend, "t1")) == 1
 
 
+def test_one_wal_segment_per_cut_sweep(tmp_path):
+    """A sweep appends every trace it cut as ONE fsynced segment (the
+    reference appends each and flushes the head block once); a segment
+    per trace cost milliseconds per trace. Nothing of the sweep is lost:
+    the completed block holds every trace, spans merged per trace."""
+    t, now = make_clock()
+    ing = Ingester(str(tmp_path / "ing"), flush_writer=MemBackend(),
+                   cfg=IngesterConfig(instance=InstanceConfig(trace_idle_s=1.0)),
+                   now=now, instance_id="ing-0")
+    tids = [bytes([i]) * 16 for i in range(1, 9)]
+    ing.push("t1", [(tid, [mkspan(tid, b"\x01" * 8), mkspan(tid, b"\x02" * 8)])
+                    for tid in tids])
+    inst = ing.instance("t1")
+    assert inst.cut_complete_traces(immediate=True) == len(tids)
+    assert len(inst.head.segments()) == 1
+    assert inst.head.spans_appended == 2 * len(tids)
+    ing.push("t1", [(tids[0], [mkspan(tids[0], b"\x03" * 8)])])
+    assert inst.cut_complete_traces(immediate=True) == 1
+    assert len(inst.head.segments()) == 2
+    got = dict(inst.head.complete())
+    assert sorted(got) == tids
+    assert [len(got[tid]) for tid in tids] == [3] + [2] * 7
+
+
 def test_shutdown_flushes_everything(rig):
     t, now, backend, ring, ingesters, dist = rig
     dist.push_spans("t1", [mkspan(bytes([i]) * 16, b"\x01" * 8)
