@@ -1207,8 +1207,14 @@ def _soak_prewarm(spans_per_push: int) -> None:
     # payloads of this size (bench_sched's deterministic-warmup rule)
     for n in (spans_per_push, 2 * spans_per_push, 4 * spans_per_push,
               8 * spans_per_push):
-        app.distributor.push_otlp("warm-lb", _soak_payload(991 + n, n))
-    sched.flush()
+        # "warm-sm" rides the arms' own route (span-metrics alone: the
+        # scheduler's packed windows), which "warm-lb" does not. Each
+        # push lands ALONE: behind a cold compile (an empty persistent
+        # cache) the later ones would queue and merge into one bigger
+        # bucket, leaving theirs to compile mid-steady
+        for tenant in ("warm-lb", "warm-sm"):
+            app.distributor.push_otlp(tenant, _soak_payload(991 + n, n))
+            sched.flush()
     c = Client(base, tenant="warm-lb")
     try:
         c.search('{ resource.service.name = "svc-0" }', limit=5)

@@ -9,8 +9,9 @@ threads, checking every answer against a numpy oracle built from
 
     python chip_smoke.py                      # one chip, the real size
     python chip_smoke.py --chips 4            # serving mesh vs one device, only
-    JAX_PLATFORMS=cpu python chip_smoke.py --size rehearsal   # no chip: a
-        # rehearsal of the control flow; never prints "ok": true
+    JAX_PLATFORMS=cpu python chip_smoke.py --size rehearsal   # a rehearsal
+        # of the control flow at a toy size, with or without a chip:
+        # never prints "ok": true, always exits 1
 
 Every line on stdout is one JSON object; the last one is the verdict.
 A failed check raises: no phase sits in a try/except that lets the run
@@ -763,6 +764,12 @@ def abandon(app, srv, sink: Sink) -> None:
     srv.server_close()
     for part in (app, app.ingester, app.generator):
         part._stop.set()
+    # a collection tick in flight still sends to this App's sink: let it
+    # land, or the process-wide failed-sends counter fails the next arm
+    for t in app.generator._threads:
+        t.join(timeout=300)
+        check(not t.is_alive(), "the generator's collection loop did not "
+                                "stop within 300 s")
     app.sched.flush()
     app.db.shutdown()
     sink.close()
@@ -896,7 +903,8 @@ def main() -> int:
     devices = jax.devices()
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices)}
-    if not on_tpu:
+    if not on_tpu or args.size != "real":
+        # a toy-size pass must not read like the real one, chip or not
         say(ok=False, rehearsal=True, device=device)
         return 1
     say(ok=True, device=device)

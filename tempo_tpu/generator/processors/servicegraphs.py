@@ -89,9 +89,11 @@ class ServiceGraphsProcessor:
         self._store: dict[bytes, _HalfEdge] = {}
         self._ttl: collections.deque[tuple[float, bytes]] = collections.deque()
         # one tenant's pushes arrive on concurrent HTTP handler threads:
-        # the edge store AND the families' read-update-rebind of device
-        # state must see one push at a time, or a completed edge is lost
-        self._push_lock = threading.Lock()
+        # the half-edge store's pop / check / put-back must see one push
+        # at a time, or two halves of one edge each find the store empty.
+        # Device state is NOT this lock's: `_emit` takes the registry's
+        # state_lock (order: store lock, then state_lock)
+        self._store_lock = threading.Lock()
         self.dropped = 0  # store-full drops (`store.go` max_items)
         self.expired = 0
 
@@ -104,7 +106,7 @@ class ServiceGraphsProcessor:
         if sb.interner is not self.registry.interner:
             raise ValueError(
                 "SpanBatch must be built with the tenant registry's interner")
-        with self._push_lock:
+        with self._store_lock:
             self._push_batch(sb)
 
     def _push_batch(self, sb: SpanBatch) -> None:
@@ -195,18 +197,26 @@ class ServiceGraphsProcessor:
             cdur[j], sdur[j], fail[j] = cd, sd, 1.0 if failed else 0.0
             mdur[j] = msg_delay
         slots = np.full(cap, -1, np.int32)
-        slots[:n] = self.total.resolve_slots(rows)
-        # family-level slot updates: the same dense scatter kernels as
-        # before, but the families own the device half — the paged
-        # layout (registry/pages.py) swaps it for arena scatters
-        self.total.add_slots(slots)
-        self.failed.add_slots(slots, fail)
-        self.client_hist.observe_slots(slots, cdur)
-        self.server_hist.observe_slots(slots, sdur)
-        if self.messaging_hist is not None:
-            msg = np.zeros(cap, bool)
-            msg[:n] = [e[2] == "messaging_system" for e in edges]
-            self.messaging_hist.observe_slots(np.where(msg, slots, -1), mdur)
+        # the families' updates read, update and REBIND device state, as
+        # the staleness purge's zeroing does on the housekeeping thread:
+        # both sit under the registry's state_lock (the spanmetrics
+        # dispatch discipline), or one side's rebind drops the other's.
+        # The slot resolve rides inside so a purge cannot free a slot
+        # between its resolve and its update
+        with self.registry.state_lock:
+            slots[:n] = self.total.resolve_slots(rows)
+            # family-level slot updates: the same dense scatter kernels as
+            # before, but the families own the device half — the paged
+            # layout (registry/pages.py) swaps it for arena scatters
+            self.total.add_slots(slots)
+            self.failed.add_slots(slots, fail)
+            self.client_hist.observe_slots(slots, cdur)
+            self.server_hist.observe_slots(slots, sdur)
+            if self.messaging_hist is not None:
+                msg = np.zeros(cap, bool)
+                msg[:n] = [e[2] == "messaging_system" for e in edges]
+                self.messaging_hist.observe_slots(
+                    np.where(msg, slots, -1), mdur)
 
     def _expire(self, now: float) -> None:
         """Expired half-edges become virtual-node edges (`servicegraphs.go:390-421`)."""

@@ -81,22 +81,8 @@ def _enc_label(name: str, value: str) -> bytes:
     return pw.enc_field_str(1, name) + pw.enc_field_str(2, value)
 
 
-def _enc_labels(labels: Sequence[tuple[str, str]],
-                memo: "dict | None" = None) -> bytes:
-    """`memo` (one per WriteRequest) keeps each encoded (name, value)
-    pair: a histogram series repeats its label set ~17 times per tick,
-    and at 18k series a tick otherwise spends seconds re-encoding the
-    same few thousand strings."""
-    if memo is None:
-        return b"".join(pw.enc_field_msg(1, _enc_label(n, v))
-                        for n, v in sorted(labels))
-    out = []
-    for pair in sorted(labels):
-        enc = memo.get(pair)
-        if enc is None:
-            enc = memo[pair] = pw.enc_field_msg(1, _enc_label(*pair))
-        out.append(enc)
-    return b"".join(out)
+def _enc_labels(labels: Sequence[tuple[str, str]]) -> bytes:
+    return b"".join(pw.enc_field_msg(1, _enc_label(n, v)) for n, v in sorted(labels))
 
 
 def _zigzag(v: int) -> int:
@@ -158,10 +144,9 @@ def encode_write_request(samples: Iterable[Sample],
     """samples → WriteRequest bytes. Stale markers become NaN samples (the
     Prometheus staleness convention the reference relies on)."""
     out = bytearray()
-    memo: dict = {}
     for s in samples:
         ts = s.ts_ms if ts_ms is None else ts_ms
-        body = _enc_labels(s.labels, memo) + pw.enc_field_msg(
+        body = _enc_labels(s.labels) + pw.enc_field_msg(
             2, pw.enc_field_double(1, s.value) + pw.enc_field_varint(2, ts))
         if s.exemplar is not None:
             ex = (pw.enc_field_msg(1, _enc_label("trace_id", s.exemplar.trace_id_hex))
@@ -171,7 +156,7 @@ def encode_write_request(samples: Iterable[Sample],
         out += pw.enc_field_msg(1, body)
     for labels, log2_counts, sum_, count, zeros, ts, *rest in native_histograms:
         offset = rest[0] if rest else 0
-        body = _enc_labels(labels, memo) + pw.enc_field_msg(
+        body = _enc_labels(labels) + pw.enc_field_msg(
             4, encode_native_histogram(log2_counts, count, zeros, sum_, ts, offset))
         out += pw.enc_field_msg(1, body)
     return bytes(out)

@@ -773,6 +773,10 @@ class MetricsEvaluator:
         nseries = len(self.series)
         if nseries == 0:
             return out
+        # a group whose measured attribute was missing on every matching
+        # span minted its series but never dispatched: its grids must
+        # still exist (all zeros), as the device plane answers it
+        self._ensure_capacity()
         k = self.m.kind
         if self._moments:
             # one series per moment column (merge = add) + the two

@@ -99,24 +99,18 @@ def _reset_device_scheduler():
 # of silently eating the shared budget. Opt out (local debugging only)
 # with TEMPO_TEST_NO_TIME_GUARD=1.
 
-# What is charged is min(wall, CPU seconds of the test's own process):
-# the driver runs six xdist workers on eight cores, and a test that
-# takes 7 s alone was seen at 33 s of wall waiting behind the others —
-# a different test each run — while its CPU seconds stayed put. 15 s,
-# not 10: the compile cache lives inside the checkout (PR 22), so the
-# driver's fresh checkout starts with it EMPTY and the first test of a
-# module to touch a kernel pays its XLA:CPU compile (largest guarded
-# reading on a cold cache: 8.8 s).
-_RUNTIME_BUDGET_S = 15.0
+_RUNTIME_BUDGET_S = 10.0
 # explicit, per-test budget exceptions — each must say WHY. The point
 # of the guard is surfacing slow tests in the PR that adds them; an
 # entry here is that surfacing, not an escape hatch.
 _BUDGET_OVERRIDES = {
     # two REAL fleet-worker process boots (~4s of jax+App init each,
     # irreducible) around a SIGKILL: the ingest-WAL crash-recovery
-    # contract cannot be exercised in-process
+    # contract cannot be exercised in-process. 40 s, not 25, since the
+    # workers' compile cache starts empty in a fresh checkout (see the
+    # cold-cache block below): 14.5 and 16.1 s read cold, 10.4 warm
     "tests/test_fleet.py::test_sigkill_restart_replays_wal_bit_identically":
-        25.0,
+        40.0,
     # compiles the structure kernel at three EXTRA pad shapes on purpose
     # (the invariance under test is exactly that recompilation at a new
     # pow-2 pad cannot change results); ~5s of XLA compile per shape
@@ -129,6 +123,41 @@ _BUDGET_OVERRIDES = {
     # four-way sharded twin, seen at 46 s / 17 s beside five busy workers.
     "tests/test_chip_compile.py::test_dense_fused_update_compiles": 120.0,
     "tests/test_chip_compile.py::test_serving_mesh_step_compiles": 60.0,
+    # the read plane's grid over a 1M-span block: ~3 s alone, 6 s seen
+    # beside five busy workers (the other compile tests stay under 3 s)
+    "tests/test_chip_compile.py::test_read_plane_metrics_grid_compiles": 30.0,
+    # The suite's compile cache now lives inside the checkout (PR 22:
+    # the program reads and writes nothing around its checkout), so the
+    # driver's fresh checkout starts with it EMPTY and whichever guarded
+    # test first touches a kernel pays that kernel's XLA:CPU compile,
+    # beside five other workers doing the same. These are the guarded
+    # tests that read 5 s or more on an empty cache under the driver's
+    # `-n 6 --dist loadfile` (cold reading / the same run with the cache
+    # warm); the budget is ~2.5x the cold reading. A test that needs
+    # more than its line here got slower, it did not get colder.
+    # device compaction merges at several block-split shapes: 25.9 / 9.8
+    "tests/test_compact.py::test_device_compaction_block_split_parity": 60.0,
+    # 12.9 / 7.6
+    "tests/test_compact.py::test_backfill_skips_done_and_respects_limit":
+        30.0,
+    # 6.5 / 2.6
+    "tests/test_compact.py::test_sidecar_merge_and_cardinality": 20.0,
+    # 5.2 / 3.7
+    "tests/test_compact.py::test_sidecar_fold_rate_matches_rescan_exactly":
+        20.0,
+    # every moments-kind grid of the fuzz grammar compiles here: 12.8 / 1.7
+    "tests/test_plane_fuzz.py::test_fuzz_moments_tier_query_range_parity":
+        30.0,
+    # 5.6 / 1.9
+    "tests/test_traceanalytics.py::test_processor_known_topology_attribution":
+        20.0,
+    # real fleet-worker processes, each of which boots an App and
+    # compiles its kernels from the same empty cache: 10.7 / 2.3,
+    # 9.5 / 1.1 and 5.3 / 3.8
+    "tests/test_fleet.py::test_shutdown_checkpoint_then_boot_restore": 25.0,
+    "tests/test_fleet.py::test_controller_handoff_and_restore_zero_loss":
+        25.0,
+    "tests/test_fleet.py::test_fleet_worker_process_spawn_and_reap": 20.0,
 }
 _GRANDFATHERED_MODULES = frozenset({
     "test_app.py", "test_aux.py", "test_backend.py",
@@ -149,18 +178,6 @@ _GRANDFATHERED_MODULES = frozenset({
 _runtime_offenders: list = []
 
 
-@pytest.hookimpl(hookwrapper=True)
-def pytest_runtest_call(item):
-    """CPU seconds of the test's own process, for the runtime guard below
-    (user_properties travel with the report from an xdist worker to the
-    controller)."""
-    import time
-
-    t0 = time.process_time()
-    yield
-    item.user_properties.append(("cpu_s", time.process_time() - t0))
-
-
 def pytest_runtest_logreport(report):
     if report.when != "call" or os.environ.get("TEMPO_TEST_NO_TIME_GUARD"):
         return
@@ -169,10 +186,8 @@ def pytest_runtest_logreport(report):
         or "moments" in report.nodeid
     budget = _BUDGET_OVERRIDES.get(report.nodeid.split("[", 1)[0],
                                    _RUNTIME_BUDGET_S)
-    charged = min(report.duration,
-                  dict(report.user_properties).get("cpu_s", report.duration))
-    if guarded and charged > budget:
-        _runtime_offenders.append((report.nodeid, charged))
+    if guarded and report.duration > budget:
+        _runtime_offenders.append((report.nodeid, report.duration))
 
 
 def pytest_terminal_summary(terminalreporter):

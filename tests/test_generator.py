@@ -141,10 +141,12 @@ def test_servicegraphs_edge_completion():
                         client="frontend", server="backend") == pytest.approx(0.2)
 
 
-def test_servicegraphs_concurrent_pushes_lose_no_edge():
-    """One tenant's pushes arrive on concurrent handler threads; every
-    completed edge must land (the store and the families' state rebind
-    are read-modify-write)."""
+@pytest.mark.parametrize("with_purge", [False, True], ids=["push", "purge"])
+def test_servicegraphs_concurrent_pushes_lose_no_edge(with_purge):
+    """One tenant's pushes arrive on concurrent handler threads while the
+    housekeeping thread purges stale series; every completed edge must
+    land (the store, and the families' state rebind against each other
+    and against the purge's zeroing, are read-modify-write)."""
     import sys
     import threading
 
@@ -162,11 +164,26 @@ def test_servicegraphs_concurrent_pushes_lose_no_edge():
     # batches stage on one thread: interning is not what is under test
     work = [[batch(i * n_pushes + j) for j in range(n_pushes)]
             for i in range(n_threads)]
+    targets = [lambda w=w: [p.push_batch(sb) for sb in w] for w in work]
+    evicted = []
+    if with_purge:
+        # one idle edge series per purge (last seen at t=0, the clock
+        # stands at 1000 s, stale after 900 s), so every purge zeroes
+        # rows of the very state arrays the pushes are rebinding
+        junk = [reg.interner.intern_many([f"idle-{k}", "x", ""])[None, :]
+                for k in range(40)]
+
+        def purge():
+            for row in junk:
+                with reg.state_lock:
+                    p.total.table.lookup_or_create(row, 0.0)
+                evicted.append(reg.purge_stale())
+
+        targets.append(purge)
     old = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:
-        threads = [threading.Thread(
-            target=lambda w=w: [p.push_batch(sb) for sb in w]) for w in work]
+        threads = [threading.Thread(target=t) for t in targets]
         for th in threads:
             th.start()
         for th in threads:
@@ -174,9 +191,14 @@ def test_servicegraphs_concurrent_pushes_lose_no_edge():
             assert not th.is_alive()
     finally:
         sys.setswitchinterval(old)
-    assert series_value(reg.collect(1), "traces_service_graph_request_total",
+    samples = reg.collect(1)
+    assert series_value(samples, "traces_service_graph_request_total",
                         client="frontend", server="backend") \
         == n_threads * n_pushes
+    if with_purge:
+        assert evicted == [1] * len(junk)
+        assert series_value(samples, "traces_service_graph_request_total",
+                            client="idle-0") is None
 
 
 def test_servicegraphs_expiry_virtual_nodes():
@@ -261,17 +283,6 @@ def test_write_request_encoding_decodes():
     assert labels == {"__name__": "m_total", "svc": "a"}
     sf = pw.decode_fields(bytes(fields[2][0]))
     assert pw.f64(sf[1][0]) == 42.0 and sf[2][0] == 1234
-
-
-def test_write_request_label_memo_changes_no_byte():
-    """The per-request memo of encoded label pairs is an encoding cache,
-    nothing else: a request whose series share label pairs is the
-    concatenation of its samples encoded one by one."""
-    samples = [Sample(f"m_{k}", (("__name__", f"m_{k}"), ("svc", f"s{i % 3}"),
-                                 ("le", str(i))), float(i), 99)
-               for k in ("bucket", "count") for i in range(20)]
-    assert rw.encode_write_request(samples) == b"".join(
-        rw.encode_write_request([s]) for s in samples)
 
 
 def test_native_histogram_encoding():

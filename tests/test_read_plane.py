@@ -98,6 +98,12 @@ QUERIES = [
     '{ } | avg_over_time(span.retries) by (resource.service.name)',
     '{ } | min_over_time(span.retries) by (resource.service.name)',
     '{ } | quantile_over_time(span.retries, .9) by (resource.service.name)',
+    # ... and missing on EVERY matching span: the host evaluator mints
+    # the series without ever dispatching, and must still answer zeros
+    # (found by test_plane_fuzz's moments arm, seed 149256142)
+    '{ resource.service.name = "svc-2" } | avg_over_time(span.retries)',
+    '{ resource.service.name = "svc-2" } | sum_over_time(span.retries)'
+    ' by (name)',
     # two-key group-by (the RED-dashboard shape) rides the fused plane
     '{ } | rate() by (resource.service.name, name)',
     '{ duration > 50ms } | quantile_over_time(duration, .9)'
