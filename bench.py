@@ -3386,7 +3386,7 @@ def bench_selftrace() -> dict:
     """Self-tracing loopback overhead: the distributor OTLP push path
     with the loopback SelfTracer installed (every push emits spans;
     periodic flushes re-enter the SAME distributor under the reserved
-    ops tenant) vs NoopTracer. Alternating arms, median-of-5 ratio.
+    ops tenant) vs the tracer with no exporter. Alternating arms, median-of-5 ratio.
     Gates: push overhead <= 3% and zero steady-state recompiles —
     self-span batches must reuse the bucketed kernel shapes the user
     tenant already compiled, never add their own.
@@ -3438,7 +3438,7 @@ def bench_selftrace() -> dict:
                        generator_clients={"g0": gen}, now=now)
     tr = tracing.SelfTracer(sink=lambda b: dist.push_otlp("tempo-self", b),
                             flush_interval_s=3600.0)
-    noop = tracing.NoopTracer()
+    noop = tracing.Tracer()
 
     def arm(tracer) -> float:
         tracing.install(tracer)

@@ -160,8 +160,9 @@ class Handler(BaseHTTPRequestHandler):
             # `a|b` org ids are read-side federation only; writes must name
             # ONE tenant (the reference rejects multi-tenant pushes)
             return self._err(400, "multi-tenant org id not allowed on writes")
+        from tempo_tpu.utils import tracing
+
         if path in ("/v1/traces", "/api/v2/spans", "/api/traces"):
-            from tempo_tpu.utils import tracing
             if tracing.is_reserved(tenant):
                 # the loopback ops tenant is written ONLY by the tracer's
                 # own sink/RPC plane; public pushes into it would forge
@@ -170,7 +171,10 @@ class Handler(BaseHTTPRequestHandler):
                                       "for selftrace loopback ingest")
         try:
             if path == "/v1/traces":
-                return self._push(tenant)
+                # the root of a push: body read, decompress, the
+                # distributor call and the response write
+                with tracing.span_for_tenant("api.push", tenant):
+                    return self._push(tenant)
             if path == "/api/v2/spans":       # zipkin v2 receiver
                 return self._push_zipkin(tenant)
             if path == "/api/traces":         # jaeger thrift-http collector

@@ -168,25 +168,14 @@ class App:
             "tempo_usage_stats_reports_written_total", reports,
             help="Usage-stats reports written by the leader reporter")
 
-        def tracer_dropped():
-            from tempo_tpu.utils import tracing
-            return [((), float(getattr(tracing.tracer(), "dropped", 0)))]
-
-        # registered unconditionally (NoopTracer reports 0) so the drift
-        # gate sees the family whether or not self-tracing is configured
-        self.obs.counter_func(
-            "tempo_self_tracer_dropped_spans_total", tracer_dropped,
-            help="Self-tracing spans lost to buffer overflow or failed "
-                 "OTLP exports (silent span loss is an alerting signal)")
-
-        # the selftrace loopback families (runbook "Tracing Tempo with
-        # Tempo"): registered unconditionally — NoopTracer reports 0 —
-        # so the drift gate sees every name on every deployment
+        # the selftrace export families (runbook "Tracing Tempo with
+        # Tempo"): registered unconditionally — a tracer with no
+        # exporter reports 0 — so the drift gate sees every name on
+        # every deployment
         def _selftrace_stat(key):
             def read():
                 from tempo_tpu.utils import tracing
-                stats = getattr(tracing.tracer(), "stats", None) or {}
-                return [((), float(stats.get(key, 0)))]
+                return [((), float(tracing.tracer().stats.get(key, 0)))]
             return read
 
         for key, txt in (
@@ -210,9 +199,7 @@ class App:
 
         def tail_buffer():
             from tempo_tpu.utils import tracing
-            t = tracing.tracer()
-            return [((), float(t.tail_buffered()))] \
-                if hasattr(t, "tail_buffered") else [((), 0.0)]
+            return [((), float(tracing.tracer().tail_buffered()))]
 
         self.obs.gauge_func(
             "tempo_selftrace_tail_buffer_spans", tail_buffer,
@@ -763,7 +750,7 @@ class App:
             # uninstall the global only while it is still OURS — another
             # App in this process may have installed its own since
             if tracing.tracer() is mine:
-                tracing.install(tracing.NoopTracer())
+                tracing.install(tracing.Tracer())
         if getattr(self, "jaeger_agent", None) is not None:
             self.jaeger_agent.stop()
         if self.frontend_worker:

@@ -193,8 +193,9 @@ class Distributor:
         reg.counter_func(
             "tempo_discarded_spans_total",
             lambda: [((r,), v) for r, v in self.discarded.items()],
-            help="Spans discarded by the distributor, by reason",
-            labels=("reason",))
+            help="Spans discarded by the distributor, by reason, and by "
+                 "the metrics-generator's slack filter (outside_slack)",
+            labels=("reason",), shared=True)
         reg.gauge_func(
             "tempo_distributor_sampling_keep_fraction",
             lambda: self.sampler.fractions(),
@@ -286,9 +287,11 @@ class Distributor:
                 self._admit(tenant, lim, len(raw), _count_spans)
                 interner, need_span, need_res = plan
                 try:
-                    staged = stage_otlp(raw, interner,
-                                        include_span_attrs=need_span,
-                                        include_res_attrs=need_res)
+                    with tracing.span_for_tenant("distributor.decode",
+                                                 tenant, n_bytes=len(raw)):
+                        staged = stage_otlp(raw, interner,
+                                            include_span_attrs=need_span,
+                                            include_res_attrs=need_res)
                 except ValueError as e:
                     raise MalformedPayload(str(e)) from None
                 if staged is not None:
@@ -303,7 +306,9 @@ class Distributor:
                         self.push_duration.observe(time.perf_counter() - t0)
             if recs is None:
                 try:
-                    recs = native.otlp_scan(raw)
+                    with tracing.span_for_tenant("distributor.decode",
+                                                 tenant, n_bytes=len(raw)):
+                        recs = native.otlp_scan(raw)
                 except ValueError as e:
                     raise MalformedPayload(str(e)) from None
             if recs is not None:
@@ -316,10 +321,13 @@ class Distributor:
                 finally:
                     self.push_duration.observe(time.perf_counter() - t0)
         try:
-            got = native.spans_from_otlp_proto_native(raw, return_recs=True)
-            if got[0] is None:
-                from tempo_tpu.model.otlp import spans_from_otlp_proto
-                got = (list(spans_from_otlp_proto(raw)), None)
+            with tracing.span_for_tenant("distributor.decode", tenant,
+                                         n_bytes=len(raw)):
+                got = native.spans_from_otlp_proto_native(
+                    raw, return_recs=True)
+                if got[0] is None:
+                    from tempo_tpu.model.otlp import spans_from_otlp_proto
+                    got = (list(spans_from_otlp_proto(raw)), None)
         except ValueError as e:
             raise MalformedPayload(str(e)) from None
         spans, recs2 = got
@@ -334,20 +342,23 @@ class Distributor:
         long after the scheduler recovers. `n_spans` may be a lazy
         callable: the staged route attributes rejected span counts from a
         cheap non-interning scan only when a rejection actually happens."""
-        retry = self.backpressure.retry_after()
-        if retry is not None:
-            self._discard(REASON_BACKPRESSURE,
-                          n_spans() if callable(n_spans) else n_spans)
-            raise RateLimited(tenant, sz, retry_after_s=retry,
-                              reason=REASON_BACKPRESSURE)
-        rate = effective_rate(lim.ingestion.rate_strategy,
-                              lim.ingestion.rate_limit_bytes,
-                              self.n_distributors())
-        if not self.limiter.allow(tenant, sz, rate,
-                                  lim.ingestion.burst_size_bytes):
-            self._discard(REASON_RATE_LIMITED,
-                          n_spans() if callable(n_spans) else n_spans)
-            raise RateLimited(tenant, sz)
+        from tempo_tpu.utils import tracing
+
+        with tracing.span_for_tenant("distributor.admit", tenant):
+            retry = self.backpressure.retry_after()
+            if retry is not None:
+                self._discard(REASON_BACKPRESSURE,
+                              n_spans() if callable(n_spans) else n_spans)
+                raise RateLimited(tenant, sz, retry_after_s=retry,
+                                  reason=REASON_BACKPRESSURE)
+            rate = effective_rate(lim.ingestion.rate_strategy,
+                                  lim.ingestion.rate_limit_bytes,
+                                  self.n_distributors())
+            if not self.limiter.allow(tenant, sz, rate,
+                                      lim.ingestion.burst_size_bytes):
+                self._discard(REASON_RATE_LIMITED,
+                              n_spans() if callable(n_spans) else n_spans)
+                raise RateLimited(tenant, sz)
 
     def _service_cached(self, raw: bytes, off: int, ln: int) -> str:
         """Memoized `_resource_service` keyed by the resource BYTES."""

@@ -85,6 +85,17 @@ class Generator:
                  "pre-sized planes, paged tenants only backed pages — "
                  "the paging win, visible without a heap dump",
             labels=("tenant", "layout"))
+        # the slack filter's drops, beside the distributor's reasons (one
+        # process serves both on the single binary; a generator-only
+        # member registers the family itself)
+        reg.counter_func(
+            "tempo_discarded_spans_total",
+            lambda: [(("outside_slack",),
+                      sum(gi.spans_filtered_slack
+                          for gi in insts().values()))],
+            help="Spans discarded by the distributor, by reason, and by "
+                 "the metrics-generator's slack filter (outside_slack)",
+            labels=("reason",), shared=True)
         self.collect_duration = reg.histogram(
             "tempo_metrics_generator_collect_duration_seconds",
             "One tenant collection tick: device-state gather through "
@@ -401,7 +412,9 @@ class Generator:
         (acked-is-durable): both sit inside the tracked-push fence, so a
         checkpoint's watermark — read after `wait_pushes_idle` — always
         covers every record whose scatter the snapshot gathered."""
-        with self._tracked_push(tenant) as inst:
+        with tracing.span_for_tenant("generator.Push", tenant,
+                                     n_spans=view.n), \
+                self._tracked_push(tenant) as inst:
             got = inst.push_staged_view(view)
             if got is not None:
                 wal = self._wal_for(tenant)
@@ -607,25 +620,32 @@ class Generator:
         with self._lock:
             insts = list(self.instances.values())
         total = 0
-        for inst in insts:
-            # in-flight fence vs the fleet handoff: a detached instance is
-            # being (or was) checkpointed — collecting it after
-            # release_instance_pages gathers zeros through the unbacked
-            # page table and remote-writes spurious counter resets; the
-            # new owner republishes the restored values instead. Holding
-            # the track makes a concurrent pop_instance's
-            # wait_pushes_idle wait for this gather before the snapshot
-            # cut frees pages (a timed-out fence aborts + retries).
-            if not inst.try_track():
-                continue
-            try:
-                if not inst.registry.overrides.disable_collection:
-                    t0 = time.perf_counter()
-                    total += inst.collect_and_push()
-                    self.collect_duration.observe(time.perf_counter() - t0)
-                inst.tick()
-            finally:
-                inst.untrack()
+        # the collector holds the interpreter for seconds at a time:
+        # every span that overlaps this tick is labelled collect="met"
+        with tracing.collecting():
+            for inst in insts:
+                # in-flight fence vs the fleet handoff: a detached
+                # instance is being (or was) checkpointed — collecting it
+                # after release_instance_pages gathers zeros through the
+                # unbacked page table and remote-writes spurious counter
+                # resets; the new owner republishes the restored values
+                # instead. Holding the track makes a concurrent
+                # pop_instance's wait_pushes_idle wait for this gather
+                # before the snapshot cut frees pages (a timed-out fence
+                # aborts + retries).
+                if not inst.try_track():
+                    continue
+                try:
+                    if not inst.registry.overrides.disable_collection:
+                        t0 = time.perf_counter()
+                        total += inst.collect_and_push()
+                        self.collect_duration.observe(
+                            time.perf_counter() - t0)
+                    with tracing.span_for_tenant("generator.tick",
+                                                 inst.tenant):
+                        inst.tick()
+                finally:
+                    inst.untrack()
         return total
 
     def start(self) -> None:

@@ -32,6 +32,7 @@ from tempo_tpu.generator.processors.traceanalytics import (
 from tempo_tpu.generator.remote_write import RemoteWriteClient, RemoteWriteConfig
 from tempo_tpu.model.span_batch import SpanBatch
 from tempo_tpu.registry import ManagedRegistry, RegistryOverrides
+from tempo_tpu.utils import tracing
 
 
 def _lb_config():
@@ -53,6 +54,14 @@ class GeneratorConfig:
         default_factory=_lb_config)
     localblocks_flush_writer: "object" = None  # RawWriter for flush_to_storage
     ingestion_time_range_slack_s: float = 30.0
+
+
+# one span a processor and push, named from a fixed set: the keys are
+# the processor names `update_processors` accepts
+_PUSH_SPANS = {"span-metrics": "spanmetrics.push",
+               "service-graphs": "servicegraphs.push",
+               "local-blocks": "localblocks.push",
+               "trace-analytics": "traceanalytics.push"}
 
 
 class GeneratorInstance:
@@ -319,14 +328,15 @@ class GeneratorInstance:
             mv.observe_batch(self.tenant, sb,
                              lb=self.processors.get("local-blocks"),
                              limits_fn=self._matview_limits)
-        for proc in self.processors.values():
-            if isinstance(proc, SpanMetricsProcessor):
-                proc.push_batch(sb, span_sizes,
-                                sample_weights=sample_weights)
-            elif isinstance(proc, TraceAnalyticsProcessor):
-                proc.push_batch(sb, sample_weights=sample_weights)
-            else:
-                proc.push_batch(sb)
+        for name, proc in self.processors.items():
+            with tracing.span(_PUSH_SPANS[name]):
+                if isinstance(proc, SpanMetricsProcessor):
+                    proc.push_batch(sb, span_sizes,
+                                    sample_weights=sample_weights)
+                elif isinstance(proc, TraceAnalyticsProcessor):
+                    proc.push_batch(sb, sample_weights=sample_weights)
+                else:
+                    proc.push_batch(sb)
 
     def _apply_slack(self, sb: SpanBatch,
                      now_s: "float | None" = None) -> SpanBatch:
@@ -352,15 +362,19 @@ class GeneratorInstance:
         # would misroute the update to a new series). The staging
         # pipeline reaps its buffer ring behind the same barrier, so
         # collected state is bit-identical to synchronous mode.
-        self.drain()
-        if self.now() - self._last_purge > 60.0:
-            self.registry.purge_stale()
-            self._last_purge = self.now()
-        samples = self.registry.collect(ts_ms)
-        native = (self.registry.native_histograms(ts_ms)
-                  if self.cfg.remote_write.send_native_histograms else [])
-        self.remote_write.send(samples, native)
-        return len(samples)
+        with tracing.span_for_tenant("generator.collect", self.tenant):
+            with tracing.span("generator.drain"):
+                self.drain()
+            if self.now() - self._last_purge > 60.0:
+                with tracing.span("registry.purge"):
+                    self.registry.purge_stale()
+                self._last_purge = self.now()
+            samples = self.registry.collect(ts_ms)
+            native = (self.registry.native_histograms(ts_ms)
+                      if self.cfg.remote_write.send_native_histograms
+                      else [])
+            self.remote_write.send(samples, native)
+            return len(samples)
 
     # -- accounting --------------------------------------------------------
 

@@ -32,6 +32,7 @@ from tempo_tpu.ops import moments, sketches
 from tempo_tpu.registry import metrics as rm
 from tempo_tpu.registry.registry import (DEFAULT_HISTOGRAM_EDGES,
                                          ManagedRegistry, _pad_len)
+from tempo_tpu.utils import tracing
 from tempo_tpu.utils.spanfilter import FilterPolicy, compile_policies
 
 import logging
@@ -106,18 +107,27 @@ def _fused_update_impl(calls, latency, sizes, dd, mom, slots, dur_s,
     """One device step for all spanmetrics families (slots shared).
     `dd` / `mom` are the optional quantile-sketch sidecars (the tier
     knob: dd, moments, or both); a None sidecar traces to exactly the
-    pre-tier graph, keeping `sketch: dd` behavior bit-identical."""
-    calls = rm.counter_update(calls, slots, weights)
-    latency = rm.histogram_update(latency, slots, dur_s, weights)
-    sizes = rm.counter_update(sizes, slots, size_bytes * weights)
+    pre-tier graph, keeping `sketch: dd` behavior bit-identical.
+
+    The named scopes change no operation: they put the stage into each
+    op's metadata (`op_name`), where a profile shows which plane a
+    relayout or a scatter belongs to."""
+    with jax.named_scope("spanmetrics.counters"):
+        calls = rm.counter_update(calls, slots, weights)
+    with jax.named_scope("spanmetrics.histogram"):
+        latency = rm.histogram_update(latency, slots, dur_s, weights)
+    with jax.named_scope("spanmetrics.counters"):
+        sizes = rm.counter_update(sizes, slots, size_bytes * weights)
     if dd is not None:
-        keep = (slots >= 0) & (slots < dd.counts.shape[0])
-        dd = sketches.dd_update(dd, jax.numpy.where(keep, slots, 0), dur_s,
-                                mask=keep, weights=weights)
+        with jax.named_scope("spanmetrics.ddsketch"):
+            keep = (slots >= 0) & (slots < dd.counts.shape[0])
+            dd = sketches.dd_update(dd, jax.numpy.where(keep, slots, 0),
+                                    dur_s, mask=keep, weights=weights)
     if mom is not None:
-        mkeep = (slots >= 0) & (slots < mom.data.shape[0])
-        mom = moments.moments_update(mom, slots, dur_s, mask=mkeep,
-                                     weights=weights)
+        with jax.named_scope("spanmetrics.moments"):
+            mkeep = (slots >= 0) & (slots < mom.data.shape[0])
+            mom = moments.moments_update(mom, slots, dur_s, mask=mkeep,
+                                         weights=weights)
     return calls, latency, sizes, dd, mom
 
 
@@ -739,10 +749,11 @@ class SpanMetricsProcessor:
         sc = self._sched()
         pipe = self._pipeline(sc)
         bufs = pipe.acquire(cap, len(dims)) if pipe is not None else None
-        got = native.spanmetrics_resolve(
-            self.calls.table._nat, spans, dims, klut, slut,
-            slack_lo, slack_hi, now, self.calls.table.last_seen, cap,
-            out=bufs)
+        with tracing.span("generator.resolve", rows=n):
+            got = native.spanmetrics_resolve(
+                self.calls.table._nat, spans, dims, klut, slut,
+                slack_lo, slack_hi, now, self.calls.table.last_seen, cap,
+                out=bufs)
         return self._push_resolved(got, spans["trace_id"], n, now,
                                    sc=sc, pipe=pipe, bufs=bufs,
                                    weights=weights)
@@ -766,10 +777,11 @@ class SpanMetricsProcessor:
         sc = self._sched()
         pipe = self._pipeline(sc)
         bufs = pipe.acquire(cap, len(dims)) if pipe is not None else None
-        got = native.spanmetrics_from_recs(
-            self.calls.table._nat, nat_it._h, raw, recs, dims, klut, slut,
-            slack_lo, slack_hi, now, self.calls.table.last_seen, cap,
-            out=bufs)
+        with tracing.span("generator.resolve", rows=n):
+            got = native.spanmetrics_from_recs(
+                self.calls.table._nat, nat_it._h, raw, recs, dims, klut,
+                slut, slack_lo, slack_hi, now, self.calls.table.last_seen,
+                cap, out=bufs)
         if got is None:
             if pipe is not None:
                 pipe.release(bufs)   # fixup bail: full path re-stages
@@ -788,6 +800,8 @@ class SpanMetricsProcessor:
         device ones-vector and the exact pre-sampling dispatch."""
         slots, packed, rows, valid, miss, n_valid, n_filtered = got
         if miss.size:
+            # new series only: the rare half of the resolve, outside the
+            # generator.resolve span
             self.calls.table.apply_misses(rows, slots, miss, valid, now)
         if sc is None:
             sc = self._sched()
@@ -928,8 +942,9 @@ class SpanMetricsProcessor:
             keep = self._policies(sb)
             self.spans_discarded += int((valid & ~keep).sum())
             valid &= keep
-        rows = self._label_rows(sb)
-        slots = self.calls.resolve_slots(rows, valid=valid)
+        with tracing.span("generator.resolve", rows=sb.n):
+            rows = self._label_rows(sb)
+            slots = self.calls.resolve_slots(rows, valid=valid)
         dur_s = (sb.duration_ns / 1e9).astype(np.float32)
         if span_sizes is None:
             span_sizes = np.zeros(sb.capacity, np.float32)

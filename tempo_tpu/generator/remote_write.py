@@ -40,6 +40,7 @@ import numpy as np
 
 from tempo_tpu.model import proto_wire as pw
 from tempo_tpu.registry.series import Sample
+from tempo_tpu.utils import tracing
 
 MAX_LITERAL = (1 << 32) - 1
 
@@ -227,7 +228,14 @@ class RemoteWriteClient:
     def send(self, samples: Sequence[Sample], native_histograms: Sequence[tuple] = ()) -> bool:
         if not self.cfg.url or (not samples and not native_histograms):
             return True
-        payload = snappy_compress(encode_write_request(samples, native_histograms))
+        with tracing.span("remote_write.encode", n_samples=len(samples)):
+            payload = snappy_compress(
+                encode_write_request(samples, native_histograms))
+        with tracing.span("remote_write.send", n_bytes=len(payload)):
+            return self._post(payload, len(samples))
+
+    def _post(self, payload: bytes, n_samples: int) -> bool:
+        """One remote-write request, retries and their sleeps included."""
         req = urllib.request.Request(self.cfg.url, data=payload, method="POST")
         req.add_header("Content-Encoding", "snappy")
         req.add_header("Content-Type", "application/x-protobuf")
@@ -244,7 +252,7 @@ class RemoteWriteClient:
                 with urllib.request.urlopen(req, timeout=self.cfg.timeout_s) as resp:
                     if 200 <= resp.status < 300:
                         self.sent_bytes += len(payload)
-                        self.sent_samples += len(samples)
+                        self.sent_samples += n_samples
                         with _RW_LOCK:
                             _RW_STATS["sends"] += 1
                         return True

@@ -21,6 +21,7 @@ from tempo_tpu.backend.raw import RawWriter, block_keypath
 from tempo_tpu.ingester.instance import InstanceConfig, TenantInstance
 from tempo_tpu.obs import Registry
 from tempo_tpu.overrides import Overrides
+from tempo_tpu.utils import tracing
 from tempo_tpu.utils.flushqueues import FlushQueues, backoff_at
 
 OP_COMPLETE = "complete"
@@ -123,8 +124,9 @@ class Ingester:
         (or None) aligned with the input — the PushResponse error slice of
         `PushBytesV2`, letting the distributor dedupe reasons across
         replicas instead of summing them RF times."""
-        inst = self.instance(tenant)
-        return [inst.push_trace(tid, spans) for tid, spans in traces]
+        with tracing.span_for_tenant("ingester.push", tenant):
+            inst = self.instance(tenant)
+            return [inst.push_trace(tid, spans) for tid, spans in traces]
 
     def push_otlp(self, tenant: str, payload: bytes) -> dict[str, str]:
         """OTLP wire-slice push (the columnar distributor's PushBytesV2
@@ -134,19 +136,21 @@ class Ingester:
         from tempo_tpu import native
         from tempo_tpu.model.otlp import spans_from_otlp_proto
 
-        spans = native.spans_from_otlp_proto_native(payload)
-        if spans is None:
-            spans = list(spans_from_otlp_proto(payload))
-        by_tid: dict[bytes, list[dict]] = {}
-        for s in spans:
-            by_tid.setdefault(s["trace_id"], []).append(s)
-        inst = self.instance(tenant)
-        out: dict[str, str] = {}
-        for tid, group in by_tid.items():
-            reason = inst.push_trace(tid, group)
-            if reason:
-                out[tid.hex()] = reason
-        return out
+        with tracing.span_for_tenant("ingester.push", tenant,
+                                     n_bytes=len(payload)):
+            spans = native.spans_from_otlp_proto_native(payload)
+            if spans is None:
+                spans = list(spans_from_otlp_proto(payload))
+            by_tid: dict[bytes, list[dict]] = {}
+            for s in spans:
+                by_tid.setdefault(s["trace_id"], []).append(s)
+            inst = self.instance(tenant)
+            out: dict[str, str] = {}
+            for tid, group in by_tid.items():
+                reason = inst.push_trace(tid, group)
+                if reason:
+                    out[tid.hex()] = reason
+            return out
 
     def push_staged(self, tenant: str, view) -> dict[str, str]:
         """Staged-view push (the decode-once distributor tee): this
@@ -157,13 +161,15 @@ class Ingester:
         the staging's one lazy payload pass. No per-replica protobuf
         re-decode. Same return contract as `push_otlp`:
         {trace_id_hex: reason} for rejected traces only."""
-        inst = self.instance(tenant)
-        out: dict[str, str] = {}
-        for tid, rows in view.trace_groups():
-            reason = inst.push_trace(tid, view.to_span_dicts(rows))
-            if reason:
-                out[tid.hex()] = reason
-        return out
+        with tracing.span_for_tenant("ingester.push", tenant,
+                                     n_spans=view.n):
+            inst = self.instance(tenant)
+            out: dict[str, str] = {}
+            for tid, rows in view.trace_groups():
+                reason = inst.push_trace(tid, view.to_span_dicts(rows))
+                if reason:
+                    out[tid.hex()] = reason
+            return out
 
     # -- cut/flush machinery ----------------------------------------------
 
@@ -171,9 +177,10 @@ class Ingester:
         """One cut tick for a tenant (`sweepInstance` flush.go:142):
         cut idle traces, maybe seal head, enqueue completion."""
         t0 = time.perf_counter()
-        inst = self.instance(tenant)
-        inst.cut_complete_traces(immediate=immediate)
-        sealed = inst.cut_block_if_ready(immediate=immediate)
+        with tracing.span_for_tenant("ingester.cut", tenant):
+            inst = self.instance(tenant)
+            inst.cut_complete_traces(immediate=immediate)
+            sealed = inst.cut_block_if_ready(immediate=immediate)
         self.cut_duration.observe(time.perf_counter() - t0)
         if sealed is not None:
             self.queues.enqueue(

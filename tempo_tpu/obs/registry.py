@@ -230,6 +230,11 @@ class Histogram(_Family):
         with self._lock:
             items = sorted((k, (list(v[0]), v[1], v[2]))
                            for k, v in self._series.items())
+        self._render_series(out, items)
+
+    def _render_series(self, out: list[str], items) -> None:
+        """`items`: sorted (label values, (per-bucket counts, sum,
+        count)) rows; shared with the callback-backed family."""
         lnames = self.labelnames + ("le",)
         for labels, (counts, total, n) in items:
             cum = 0
@@ -257,11 +262,13 @@ class _FuncFamily(_Family):
         super().__init__(name, help, labelnames)
         self.kind = kind
         self.fn = fn
+        self.more: list[Callable[[], Iterable]] = []   # shared families
 
     def render(self, out: list[str]) -> None:
         try:
             items = sorted((tuple(str(v) for v in labels), value)
-                           for labels, value in self.fn())
+                           for fn in (self.fn, *self.more)
+                           for labels, value in fn())
         except Exception:
             return    # a failing collector must never break /metrics
         if not self.labelnames and not items and self.kind == "counter":
@@ -271,6 +278,34 @@ class _FuncFamily(_Family):
                 continue
             out.append(f"{self.name}{_fmt_labels(self.labelnames, labels)} "
                        f"{_fmt_value(v)}")
+
+
+class _HistogramFuncFamily(_Family):
+    """Histogram twin of `_FuncFamily`: `fn()` yields `(label values,
+    per-bucket counts, sum, count)`, one row a series, with
+    `len(edges) + 1` non-cumulative counts (the last is beyond the last
+    edge). The module keeps the state and pays for the snapshot at
+    render; its hot path never touches this family's lock."""
+
+    kind = "histogram"
+
+    def __init__(self, name: str, help: str, labelnames: tuple,
+                 fn: Callable[[], Iterable], edges: Sequence[float]) -> None:
+        super().__init__(name, help, labelnames)
+        self.fn = fn
+        self.edges = tuple(edges)
+
+    metric_names = Histogram.metric_names
+    _render_series = Histogram._render_series
+
+    def render(self, out: list[str]) -> None:
+        try:
+            items = sorted((tuple(str(v) for v in labels),
+                            (list(counts), total, n))
+                           for labels, counts, total, n in self.fn())
+        except Exception:
+            return    # a failing collector must never break /metrics
+        self._render_series(out, items)
 
 
 class _Noop:
@@ -346,11 +381,21 @@ class Registry:
                                    buckets=buckets)
 
     def counter_func(self, name: str, fn: Callable[[], Iterable],
-                     help: str = "", labels: tuple = ()) -> None:
+                     help: str = "", labels: tuple = (),
+                     shared: bool = False) -> None:
+        """`shared`: modules that each own some label values of ONE
+        family all say so; the first call registers it and the later
+        ones add their rows to it."""
         if not self.enabled:
             return
         with self._lock:
-            if name in self._families:
+            fam = self._families.get(name)
+            if shared and isinstance(fam, _FuncFamily) \
+                    and fam.kind == "counter" \
+                    and fam.labelnames == tuple(labels):
+                fam.more.append(fn)
+                return
+            if fam is not None:
                 raise ValueError(f"metric {name!r} already registered")
             self._families[name] = _FuncFamily(name, help, tuple(labels),
                                                fn, "counter")
@@ -364,6 +409,17 @@ class Registry:
                 raise ValueError(f"metric {name!r} already registered")
             self._families[name] = _FuncFamily(name, help, tuple(labels),
                                                fn, "gauge")
+
+    def histogram_func(self, name: str, fn: Callable[[], Iterable],
+                       help: str = "", labels: tuple = (),
+                       buckets: Sequence[float] = ()) -> None:
+        if not self.enabled:
+            return
+        with self._lock:
+            if name in self._families:
+                raise ValueError(f"metric {name!r} already registered")
+            self._families[name] = _HistogramFuncFamily(
+                name, help, tuple(labels), fn, tuple(sorted(buckets)))
 
     # -- introspection / exposition ----------------------------------------
 

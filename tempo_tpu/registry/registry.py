@@ -30,6 +30,7 @@ import numpy as np
 from tempo_tpu.model.interner import StringInterner
 from tempo_tpu.registry import metrics as m
 from tempo_tpu.registry.series import Exemplar, Sample, SeriesBudget, SeriesTable
+from tempo_tpu.utils import tracing
 
 STALE_NAN = float("nan")
 
@@ -435,11 +436,12 @@ class ManagedRegistry:
         # donating push would invalidate); the per-sample formatting —
         # the bulk of the tick at high cardinality — runs outside so
         # ingest never stalls behind it
-        with self.state_lock:
+        with tracing.span("registry.gather"), self.state_lock:
             snaps = [(mt, mt._snap()) for mt in self._metrics.values()]
         out: list[Sample] = []
-        for mt, snap in snaps:
-            out.extend(mt.collect(ts, snap))
+        with tracing.span("registry.format"):
+            for mt, snap in snaps:
+                out.extend(mt.collect(ts, snap))
         return out
 
     def purge_stale(self) -> int:

@@ -67,6 +67,7 @@ import time
 from collections import OrderedDict, deque
 from typing import Callable, Sequence
 
+import jax
 import numpy as np
 
 from tempo_tpu.utils import faults
@@ -321,7 +322,8 @@ class Job:
 
     def wait(self, timeout: "float | None" = None) -> bool:
         """Block until dispatched; re-raises the dispatch error, if any."""
-        ok = self.event.wait(timeout)
+        with tracing.span("sched.wait", kernel=self.kernel):
+            ok = self.event.wait(timeout)
         if ok and self.error is not None:
             raise self.error
         return ok
@@ -864,6 +866,34 @@ class DeviceScheduler:
                 i += 1
             self._dispatch_chunk(g, chunk, rows)
 
+    @staticmethod
+    def _pad_chunk(g: _MergeGroup, chunk: list[Job], rows: int,
+                   bucket: int) -> tuple[list, int]:
+        """The chunk's arrays merged and padded to `bucket` rows:
+        (host arrays, one a dispatch operand; padding bytes)."""
+        if g.pack:
+            # one row-major f32 matrix = ONE H2D for the whole batch
+            mat = np.empty((len(g.pads), bucket), np.float32)
+            for role, pad_val in enumerate(g.pads):
+                off = 0
+                for j in chunk:
+                    a = j.arrays[role]
+                    mat[role, off:off + len(a)] = a
+                    off += len(a)
+                mat[role, off:] = pad_val
+            return [mat], (bucket - rows) * mat.dtype.itemsize * len(g.pads)
+        padded, waste = [], 0
+        for role, pad_val in enumerate(g.pads):
+            parts = [np.asarray(j.arrays[role]) for j in chunk]
+            cat = parts[0] if len(parts) == 1 else np.concatenate(parts)
+            if len(cat) < bucket:
+                out = np.full(bucket, pad_val, dtype=cat.dtype)
+                out[: len(cat)] = cat
+                cat = out
+            waste += (bucket - rows) * cat.dtype.itemsize
+            padded.append(cat)
+        return padded, waste
+
     def _dispatch_chunk(self, g: _MergeGroup, chunk: list[Job],
                         rows: int) -> None:
         # queue wait stamps at execution start (enqueue → now), summed
@@ -890,70 +920,16 @@ class DeviceScheduler:
                 # serving mesh: the padded window must split evenly over
                 # the 'data' shards for the single shard_map dispatch
                 bucket = -(-bucket // g.align) * g.align
-            waste = 0
-            if g.pack:
-                # one row-major f32 matrix = ONE H2D for the whole batch
-                mat = np.empty((len(g.pads), bucket), np.float32)
-                for role, pad_val in enumerate(g.pads):
-                    off = 0
-                    for j in chunk:
-                        a = j.arrays[role]
-                        mat[role, off:off + len(a)] = a
-                        off += len(a)
-                    mat[role, off:] = pad_val
-                waste = (bucket - rows) * mat.dtype.itemsize * len(g.pads)
-                padded = [mat]
-            else:
-                padded = []
-                for role, pad_val in enumerate(g.pads):
-                    parts = [np.asarray(j.arrays[role]) for j in chunk]
-                    cat = parts[0] if len(parts) == 1 \
-                        else np.concatenate(parts)
-                    if len(cat) < bucket:
-                        out = np.full(bucket, pad_val, dtype=cat.dtype)
-                        out[: len(cat)] = cat
-                        cat = out
-                    waste += (bucket - rows) * cat.dtype.itemsize
-                    padded.append(cat)
-            sig = (g.kernel, bucket) + tuple(a.dtype.str for a in padded)
-            occ = rows / bucket
-            with self._stats_lock:
-                if sig not in self._warm_buckets:
-                    self._warm_buckets.add(sig)
-                    self.bucket_warmups[g.kernel] = \
-                        self.bucket_warmups.get(g.kernel, 0) + 1
-                self.occupancy_sum[g.kernel] = \
-                    self.occupancy_sum.get(g.kernel, 0.0) + occ
-                self.batches_total[g.kernel] = \
-                    self.batches_total.get(g.kernel, 0) + 1
-                self.coalesced_total[g.kernel] = \
-                    self.coalesced_total.get(g.kernel, 0) + len(chunk)
-                self.padding_waste_bytes[g.kernel] = \
-                    self.padding_waste_bytes.get(g.kernel, 0) + waste
-                if g.shards:
-                    self._note_shard_stats(g, bucket, rows, waste)
-            if g.shards:
-                # mesh mode: one occupancy sample PER 'data' shard — rows
-                # pack contiguously, so the tail shard carries the
-                # padding; a persistently cold last shard means the batch
-                # window is closing under-full for this mesh width
-                per = bucket // g.shards
-                for i in range(g.shards):
-                    real = min(max(rows - i * per, 0), per)
-                    _OCCUPANCY.observe(real / per, (g.kernel, str(i)))
-            else:
-                _OCCUPANCY.observe(occ, (g.kernel, ""))
-            h2d_bytes = sum(int(a.nbytes) for a in padded)
             # slow dispatches are findable by trace: same span surface
-            # as distributor.push / frontend.Search (NoopTracer default
-            # costs one dict build per MERGED batch). The span LINKS the
+            # as distributor.push / frontend.Search. The span LINKS the
             # coalesced batch back to each contributing request's tree
             # (bounded: a batch is a fan-in, links are how OTel models
             # it) and carries the devtime ledger identity — kernel,
-            # bucket, device_ns — so device time is attributable per
-            # trace. A single-tenant batch goes through the tenant-aware
-            # guard: an all-reserved-tenant batch (loopback self-ingest)
-            # must not re-trace itself.
+            # bucket — with enqueue_ns, the HOST's time inside the
+            # asynchronous enqueue (device time is the profiler's). A
+            # single-tenant batch goes through the tenant-aware guard:
+            # an all-reserved-tenant batch (loopback self-ingest) must
+            # not re-trace itself.
             attrs = {"kernel": g.kernel, "bucket": bucket, "rows": rows,
                      "shard": str(g.shards) if g.shards else ""}
             links = sorted({j.traceparent for j in chunk
@@ -965,10 +941,26 @@ class DeviceScheduler:
             cm = tracing.span_for_tenant("sched.dispatch", only, **attrs) \
                 if only else tracing.span("sched.dispatch", **attrs)
             with cm as sp:
+                with tracing.span("sched.h2d") as hs:
+                    padded, waste = self._pad_chunk(g, chunk, rows, bucket)
+                    dtypes = tuple(a.dtype.str for a in padded)
+                    h2d_bytes = sum(int(a.nbytes) for a in padded)
+                    if hs is not None:
+                        hs.attrs["h2d_bytes"] = h2d_bytes
+                    if g.pack and not g.shards:
+                        # the packed matrix exists to be ONE H2D: made
+                        # here, on one device, it is timed apart from
+                        # the enqueue. A mesh group's closure places its
+                        # own sharded operand, and unpacked operands stay
+                        # host arrays (some closures read them on the
+                        # host): their H2D is inside sched.enqueue.
+                        padded = [jax.device_put(padded[0])]
+                self._note_batch(g, len(chunk), rows, bucket, dtypes, waste)
                 td0 = time.perf_counter()
-                g.dispatch(*padded)
+                with tracing.span("sched.enqueue"):
+                    g.dispatch(*padded)
                 if sp is not None:
-                    sp.attrs["device_ns"] = \
+                    sp.attrs["enqueue_ns"] = \
                         int((time.perf_counter() - td0) * 1e9)
         except BaseException as e:           # noqa: BLE001 — propagated
             err = e
@@ -998,6 +990,38 @@ class DeviceScheduler:
                     max(t_end - j.enqueue_t, 0.0), (g.kernel,))
             j.error = err
             j.event.set()
+
+    def _note_batch(self, g: _MergeGroup, n_jobs: int, rows: int,
+                    bucket: int, dtypes: tuple, waste: int) -> None:
+        """Coalescer counters and occupancy of one padded batch."""
+        sig = (g.kernel, bucket) + dtypes
+        occ = rows / bucket
+        with self._stats_lock:
+            if sig not in self._warm_buckets:
+                self._warm_buckets.add(sig)
+                self.bucket_warmups[g.kernel] = \
+                    self.bucket_warmups.get(g.kernel, 0) + 1
+            self.occupancy_sum[g.kernel] = \
+                self.occupancy_sum.get(g.kernel, 0.0) + occ
+            self.batches_total[g.kernel] = \
+                self.batches_total.get(g.kernel, 0) + 1
+            self.coalesced_total[g.kernel] = \
+                self.coalesced_total.get(g.kernel, 0) + n_jobs
+            self.padding_waste_bytes[g.kernel] = \
+                self.padding_waste_bytes.get(g.kernel, 0) + waste
+            if g.shards:
+                self._note_shard_stats(g, bucket, rows, waste)
+        if g.shards:
+            # mesh mode: one occupancy sample PER 'data' shard — rows
+            # pack contiguously, so the tail shard carries the
+            # padding; a persistently cold last shard means the batch
+            # window is closing under-full for this mesh width
+            per = bucket // g.shards
+            for i in range(g.shards):
+                real = min(max(rows - i * per, 0), per)
+                _OCCUPANCY.observe(real / per, (g.kernel, str(i)))
+        else:
+            _OCCUPANCY.observe(occ, (g.kernel, ""))
 
     def _note_shard_stats(self, g: _MergeGroup, bucket: int, rows: int,
                           waste: int) -> None:
@@ -1050,7 +1074,7 @@ class DeviceScheduler:
                 else:
                     job.result = job.fn()
                 if sp is not None:
-                    sp.attrs["device_ns"] = \
+                    sp.attrs["enqueue_ns"] = \
                         int((time.perf_counter() - t0) * 1e9)
         except BaseException as e:           # noqa: BLE001 — propagated
             # fn jobs have a waiting caller who re-raises and owns the

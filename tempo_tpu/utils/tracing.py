@@ -1,11 +1,30 @@
-"""Self-tracing: the framework traces its own hot entry points.
+"""Spans: the one way this program marks a layer boundary.
 
-The reference installs an OTel tracer at startup (`cmd/tempo/main.go:
-227-281`) and wraps hot entries in spans (`distributor.PushBytes`
-`distributor.go:401`, `traceql.Engine.ExecuteSearch` `engine.go:50`) with
-W3C traceparent propagation. This is a from-scratch minimal tracer with
-the same surface plus two properties the reference gets from the OTel
-SDK + collector pair:
+`span(name, **attrs)` / `span_for_tenant(...)` do two things.
+
+**Always, with nothing to turn on:**
+
+- the span enters `jax.profiler.TraceAnnotation(name)`. With no profiler
+  session that is a no-op inside the runtime; under a session the span
+  lands in the `/host:CPU` plane of the `.xplane.pb`, on the clock of the
+  device's `XLA Ops`, so an idle gap of the device is named by the layer
+  the host was in. The profiler's own start is the switch;
+- the span keeps its self time (its duration less the part its child
+  spans cover; a child is a span opened under it ON THE SAME THREAD) and
+  adds both, at close, to `tempo_span_duration_seconds{span,collect}` and
+  `tempo_span_self_seconds{span,collect}` on `/metrics`. `span` is the
+  span's name, from the fixed set in the code; attributes never become
+  labels. `collect` is `met` when a generator collection tick ran at the
+  span's start or end or began in between (`collecting()`), else `clear`:
+  the collector holds the interpreter for seconds, so the `clear` rows
+  are a path's own cost and the `met` rows are the stall;
+- this part takes no lock another thread takes and draws no random
+  bytes: every thread adds to rows of its own, summed at the scrape.
+
+**Configured (`selftrace.enabled` / `selftrace.endpoint`), `SelfTracer`:**
+trace and span ids, W3C traceparent propagation, and OTLP export, with
+two properties the reference gets from the OTel SDK + collector pair
+(`cmd/tempo/main.go:227-281`):
 
 - **Tail-keep.** Spans buffer per trace until the trace's last local
   span closes; the whole tree is then either kept (exported) or dropped
@@ -20,12 +39,13 @@ SDK + collector pair:
   whole ingest call-tree for the reserved tenant (a remote fleet member
   ingesting a peer's self-spans must not trace that ingestion either).
 
-No global mutable state beyond one module-level tracer the app installs;
-disabled (zero overhead beyond a None check) until configured.
+Process-wide state: the installed tracer, the collect mark and the span
+rows, all reset between tests by `tests/conftest.py`.
 """
 
 from __future__ import annotations
 
+import bisect
 import collections
 import contextlib
 import contextvars
@@ -36,6 +56,10 @@ import threading
 import time
 import urllib.request
 from typing import Callable
+
+from jax.profiler import TraceAnnotation
+
+from tempo_tpu.obs.jaxruntime import RUNTIME
 
 _current_span = contextvars.ContextVar("tempo_self_span", default=None)
 # recursion guard: True while this process is ingesting its own export
@@ -83,27 +107,215 @@ class SelfTraceConfig:
         return ["selftrace: " + p for p in problems] if problems else []
 
 
+# -- the always-on part ------------------------------------------------------
+
+_clock = time.perf_counter_ns          # tests inject another
+
+# The collect mark: [collection ticks running now, ticks begun so far].
+# Written by the collector under `_collect_lock`; a span reads the pair at
+# its start and at its end and takes no lock.
+_collect = [0, 0]
+_collect_lock = threading.Lock()
+
+
+@contextlib.contextmanager
+def collecting():
+    """Mark a generator collection tick (`Generator.collect_all`): spans
+    that overlap it carry `collect="met"`."""
+    with _collect_lock:
+        _collect[0] += 1
+        _collect[1] += 1
+    try:
+        yield
+    finally:
+        with _collect_lock:
+            _collect[0] -= 1
+
+
+# Span rows: {thread ident: {span name: (clear row, met row)}}, a row
+# being [count, duration ns, self ns, duration buckets, self buckets].
+# A thread writes only under its own ident (the OS hands a dead thread's
+# ident to a new one, which then carries its rows on: the table is as
+# large as the most threads alive at once), so no row has two writers.
+_rows: dict[int, dict[str, tuple[list, list]]] = {}
+_BUCKETS_S = (1e-5, 1e-4, 1e-3, 1e-2, 1e-1, 1.0, 10.0, 100.0)
+_EDGES_NS = tuple(int(e * 1e9) for e in _BUCKETS_S)
+
+
+def _new_row() -> list:
+    n = len(_EDGES_NS) + 1
+    return [0, 0, 0, [0] * n, [0] * n]
+
+
+def span_rows() -> dict[tuple[str, str], list]:
+    """{(span, collect): [count, duration ns, self ns, duration buckets,
+    self buckets]} summed over the threads: what `/metrics` renders."""
+    out: dict[tuple[str, str], list] = {}
+    for per_thread in list(_rows.values()):
+        for name, pair in list(per_thread.items()):
+            for label, row in zip(("clear", "met"), pair):
+                if not row[0]:
+                    continue
+                agg = out.setdefault((name, label), _new_row())
+                agg[0] += row[0]
+                agg[1] += row[1]
+                agg[2] += row[2]
+                for i, c in enumerate(row[3]):
+                    agg[3][i] += c
+                for i, c in enumerate(row[4]):
+                    agg[4][i] += c
+    return out
+
+
+def reset_span_rows() -> None:
+    """Forget every span seen so far (tests)."""
+    _rows.clear()
+
+
+def _family(total_at: int, buckets_at: int):
+    def rows():
+        return [(key, row[buckets_at], row[total_at] / 1e9, row[0])
+                for key, row in span_rows().items()]
+    return rows
+
+
+RUNTIME.histogram_func(
+    "tempo_span_duration_seconds", _family(1, 3),
+    help="Host wall time of the program's own spans (tracing.span), by "
+         "span name; collect=met when a generator collection tick "
+         "overlapped the span, else clear",
+    labels=("span", "collect"), buckets=_BUCKETS_S)
+RUNTIME.histogram_func(
+    "tempo_span_self_seconds", _family(2, 4),
+    help="Self time of the program's own spans: duration less the part "
+         "covered by child spans opened on the same thread; the self "
+         "times of a tree sum to its root's duration",
+    labels=("span", "collect"), buckets=_BUCKETS_S)
+
+
 class _Span:
+    """One span, and its own context manager. The fields up to
+    `status_code` are the export part's and stay empty without one."""
+
     __slots__ = ("trace_id", "span_id", "parent_span_id", "name",
-                 "start_ns", "end_ns", "attrs", "status_code")
+                 "start_ns", "end_ns", "attrs", "status_code",
+                 "_tracer", "_parent", "_token", "_thread", "_ann",
+                 "_t0", "_child_ns", "_met", "_epoch")
 
-    def __init__(self, trace_id: bytes, span_id: bytes,
-                 parent_span_id: bytes, name: str, start_ns: int):
-        self.trace_id = trace_id
-        self.span_id = span_id
-        self.parent_span_id = parent_span_id
+    def __init__(self, tracer: "Tracer | None", name: str,
+                 attrs: dict) -> None:
+        self.trace_id = self.span_id = self.parent_span_id = b""
         self.name = name
-        self.start_ns = start_ns
-        self.end_ns = 0
-        self.attrs: dict = {}
+        self.start_ns = self.end_ns = 0
+        self.attrs = attrs
         self.status_code = 0
+        self._tracer = tracer
+        self._thread = None       # a remote parent is on no thread here
+        self._child_ns = 0
+
+    def __enter__(self) -> "_Span":
+        parent = self._parent = _current_span.get()
+        self._token = _current_span.set(self)
+        if self._tracer.exports:
+            self._tracer._begin(self, parent)
+        self._thread = threading.get_ident()
+        self._met, self._epoch = _collect[0] > 0, _collect[1]
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self._t0 = _clock()
+        return self
+
+    def __exit__(self, etype, exc, tb) -> None:
+        dur = _clock() - self._t0
+        self._ann.__exit__(etype, exc, tb)
+        _current_span.reset(self._token)
+        ident = self._thread
+        parent = self._parent
+        if parent is not None and parent._thread == ident:
+            # a span closed on another thread than its parent ran beside
+            # it, not inside it: the parent's self time keeps that part
+            parent._child_ns += dur
+        per_thread = _rows.get(ident)
+        if per_thread is None:
+            per_thread = _rows[ident] = {}
+        pair = per_thread.get(self.name)
+        if pair is None:
+            pair = per_thread[self.name] = (_new_row(), _new_row())
+        row = pair[self._met or _collect[0] > 0
+                   or _collect[1] != self._epoch]
+        self_ns = dur - self._child_ns
+        row[0] += 1
+        row[1] += dur
+        row[2] += self_ns
+        row[3][bisect.bisect_left(_EDGES_NS, dur)] += 1
+        row[4][bisect.bisect_left(_EDGES_NS, self_ns)] += 1
+        if isinstance(exc, Exception):
+            self.status_code = 2
+            self.attrs["error.message"] = str(exc)[:200]
+        if self._tracer.exports:
+            self._tracer._record(self)
 
 
-class SelfTracer:
-    """Minimal tracer: span stack via contextvars, per-trace tail buffer,
-    bounded export buffer, batch export thread. Spans export as OTLP (the
-    codec this framework already speaks) so any OTLP endpoint — including
-    this process (loopback) — can ingest its own traces."""
+class _NoSpan:
+    """What `span()` hands out while span creation is suppressed."""
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, etype, exc, tb) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+
+
+class Tracer:
+    """The installed tracer with no exporter (the default): spans feed
+    the profiler and `/metrics` and go nowhere else."""
+
+    exports = False
+    exported = 0
+    loopback = False
+    tenant = None
+    stats: dict = {}
+
+    def span(self, name: str, **attrs):
+        if _suppress.get():
+            return _NO_SPAN          # ingesting our own export: no spans
+        return _Span(self, name, attrs)
+
+    def traceparent(self) -> "str | None":
+        return None
+
+    def adopt(self, traceparent):
+        return None
+
+    def mark_keep(self) -> None:
+        pass
+
+    def trace_kept(self) -> "str | None":
+        return None
+
+    def tail_buffered(self) -> int:
+        return 0
+
+    def status(self) -> "dict | None":
+        return None
+
+    def flush(self) -> int:
+        return 0
+
+    def shutdown(self) -> None:
+        pass
+
+
+class SelfTracer(Tracer):
+    """The export part: ids, per-trace tail buffer, bounded export
+    buffer, batch export thread. Spans export as OTLP (the codec this
+    framework already speaks) so any OTLP endpoint — including this
+    process (loopback) — can ingest its own traces."""
+
+    exports = True
 
     def __init__(self, endpoint: str = "", *,
                  service_name: str = "tempo-tpu",
@@ -147,29 +359,16 @@ class SelfTracer:
 
     # -- span API ----------------------------------------------------------
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs):
-        if _suppress.get():
-            yield None               # ingesting our own export: no spans
-            return
-        parent: _Span | None = _current_span.get()
-        tid = parent.trace_id if parent is not None else os.urandom(16)
-        psid = parent.span_id if parent is not None else b""
-        s = _Span(tid, os.urandom(8), psid, name, int(self.now() * 1e9))
-        s.attrs.update(attrs)
-        token = _current_span.set(s)
+    def _begin(self, s: _Span, parent: "_Span | None") -> None:
+        """Ids, wall-clock start and the open count of a span entering."""
+        if parent is not None and parent.trace_id:
+            s.trace_id, s.parent_span_id = parent.trace_id, parent.span_id
+        else:
+            s.trace_id = os.urandom(16)
+        s.span_id = os.urandom(8)
+        s.start_ns = int(self.now() * 1e9)
         with self._lock:
-            self._open[tid] = self._open.get(tid, 0) + 1
-        try:
-            yield s
-        except Exception as e:
-            s.status_code = 2
-            s.attrs["error.message"] = str(e)[:200]
-            raise
-        finally:
-            _current_span.reset(token)
-            s.end_ns = int(self.now() * 1e9)
-            self._record(s)
+            self._open[s.trace_id] = self._open.get(s.trace_id, 0) + 1
 
     def mark_keep(self) -> None:
         """Force the current trace past head sampling (SLO miss, error):
@@ -213,6 +412,7 @@ class SelfTracer:
     # -- tail buffer -------------------------------------------------------
 
     def _record(self, s: _Span) -> None:
+        s.end_ns = int(self.now() * 1e9)
         tid = s.trace_id
         with self._lock:
             self.stats["spans"] += 1
@@ -272,18 +472,10 @@ class SelfTracer:
         with self._lock:
             return sum(len(v) for v in self._traces.values())
 
-    @property
-    def dropped(self) -> int:
-        """Spans lost to buffer overflow OR failed exports — the span-loss
-        signal behind `tempo_self_tracer_dropped_spans_total`. Head-
-        sampled-out spans are NOT losses and count separately."""
-        with self._lock:
-            return self.stats["dropped_spans"]
-
     def traceparent(self) -> str | None:
         """W3C traceparent for outgoing RPCs (`main.go:252-258`)."""
         s = _current_span.get()
-        if s is None:
+        if s is None or not s.trace_id:
             return None
         return f"00-{s.trace_id.hex()}-{s.span_id.hex()}-01"
 
@@ -299,7 +491,8 @@ class SelfTracer:
             tid, sid = bytes.fromhex(parts[1]), bytes.fromhex(parts[2])
         except ValueError:
             return None      # W3C: invalid traceparent values are ignored
-        remote = _Span(tid, sid, b"", "remote-parent", 0)
+        remote = _Span(None, "remote-parent", {})
+        remote.trace_id, remote.span_id = tid, sid
         return _current_span.set(remote)
 
     # -- export ------------------------------------------------------------
@@ -386,53 +579,15 @@ class SelfTracer:
         self.flush()        # second pass drains a held retry batch
 
 
-class NoopTracer:
-    """Disabled tracer: the default; `span()` costs one None check."""
-
-    dropped = 0
-    exported = 0
-    loopback = False
-    tenant = None
-    stats: dict = {}
-
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs):
-        yield None
-
-    def traceparent(self) -> None:
-        return None
-
-    def adopt(self, traceparent):
-        return None
-
-    def mark_keep(self) -> None:
-        pass
-
-    def trace_kept(self) -> None:
-        return None
-
-    def tail_buffered(self) -> int:
-        return 0
-
-    def status(self) -> None:
-        return None
-
-    def flush(self) -> int:
-        return 0
-
-    def shutdown(self) -> None:
-        pass
+_tracer: Tracer = Tracer()
 
 
-_tracer: "SelfTracer | NoopTracer" = NoopTracer()
-
-
-def install(tracer: "SelfTracer | NoopTracer") -> None:
+def install(tracer: Tracer) -> None:
     global _tracer
     _tracer = tracer
 
 
-def tracer() -> "SelfTracer | NoopTracer":
+def tracer() -> Tracer:
     return _tracer
 
 
@@ -457,14 +612,14 @@ def current_trace_id_hex() -> "str | None":
     None outside any span — the metrics-side exemplar bridge: slow
     requests stamp this onto their histogram observation."""
     s = _current_span.get()
-    return s.trace_id.hex() if s is not None else None
+    return s.trace_id.hex() if s is not None and s.trace_id else None
 
 
 def reserved_tenant() -> "str | None":
     """The loopback ops tenant, when self-ingest is active — excluded
     from fleet handoff, matview auto-subscribe, and public push APIs."""
     t = _tracer
-    return t.tenant if getattr(t, "loopback", False) else None
+    return t.tenant if t.loopback else None
 
 
 def is_reserved(tenant: str) -> bool:
@@ -494,7 +649,7 @@ def span_for_tenant(name: str, tenant: str, **attrs):
     ingestion of our own spans would emit new spans per flush, forever.
     Plain nullcontext would only skip THIS span; nested wal.append /
     sched.dispatch spans under the ingest call-tree must go quiet too."""
-    if getattr(_tracer, "tenant", None) == tenant:
+    if _tracer.tenant == tenant:
         return suppress()
     return _tracer.span(name, tenant=tenant, **attrs)
 
@@ -512,7 +667,8 @@ def adopted(traceparent: str | None):
             _current_span.reset(token)
 
 
-__all__ = ["SelfTracer", "NoopTracer", "SelfTraceConfig", "install",
+__all__ = ["Tracer", "SelfTracer", "SelfTraceConfig", "install",
            "tracer", "span", "span_for_tenant", "adopted", "mark_keep",
+           "collecting", "span_rows", "reset_span_rows",
            "kept_trace_id_hex", "current_trace_id_hex", "reserved_tenant",
            "is_reserved", "suppress", "suppressed"]

@@ -73,7 +73,9 @@ def _reset_device_scheduler():
     # suppression/reserved-tenant guards in surprising places
     from tempo_tpu.utils import tracing
 
-    tracing.install(tracing.NoopTracer())
+    tracing.install(tracing.Tracer())
+    # the span rows behind tempo_span_*_seconds are process-wide too
+    tracing.reset_span_rows()
     # trace-analytics operational counters and the dataquality orphan
     # tally are process-wide callback-family state (monotonic by
     # design); reset so per-test assertions on late/cycle/orphan counts

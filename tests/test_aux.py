@@ -297,7 +297,7 @@ def test_self_tracing_dogfood(tmp_path):
     app.start_loops()
     srv = serve(app, block=False)
     try:
-        assert not isinstance(tracing.tracer(), tracing.NoopTracer)
+        assert isinstance(tracing.tracer(), tracing.SelfTracer)
         # trigger traced entry points
         t0 = int((time.time() - 3) * 1e9)
         otlp = {"resourceSpans": [{"scopeSpans": [{"spans": [{

@@ -465,7 +465,7 @@ def test_sched_dispatch_span_emitted():
 
     spans = []
 
-    class _Tracer(tracing.NoopTracer):
+    class _Tracer(tracing.Tracer):
         def span(self, name, **attrs):
             spans.append((name, attrs))
             return super().span(name, **attrs)
@@ -478,7 +478,7 @@ def test_sched_dispatch_span_emitted():
                        lambda *a: None, tenant="t")
         sc.drain_once(force=True)
     finally:
-        tracing.install(tracing.NoopTracer())
+        tracing.install(tracing.Tracer())
     names = [s for s in spans if s[0] == "sched.dispatch"]
     assert names and names[0][1]["kernel"] == "k"
     assert names[0][1]["bucket"] == 64 and names[0][1]["rows"] == 4

@@ -422,6 +422,42 @@ def test_staged_fast_path_slack_filter_counts():
     assert inst.spans_received == 512
 
 
+def test_slack_drops_on_metrics_beside_the_distributors_reasons():
+    """The slack filter's drops are on /metrics as
+    `tempo_discarded_spans_total{reason="outside_slack"}`: ONE family,
+    which the distributor (registered second on the single binary) adds
+    its own reasons to."""
+    import bench as _bench
+    import time as _time
+    from tempo_tpu.generator.generator import Generator
+    from tempo_tpu.generator.instance import GeneratorConfig
+    from tempo_tpu.obs.registry import Registry, parse_exposition
+    from tempo_tpu.overrides import Overrides
+
+    reg = Registry()
+    cfg = GeneratorConfig(processors=("span-metrics",))
+    cfg.registry.disable_collection = True
+    cfg.ingestion_time_range_slack_s = 30.0
+    gen = Generator(cfg, overrides=Overrides(), registry=reg)
+    reg.counter_func("tempo_discarded_spans_total",
+                     lambda: [(("rate_limited",), 2)],
+                     labels=("reason",), shared=True)
+    with pytest.raises(ValueError):          # unshared: still a clash
+        reg.counter_func("tempo_discarded_spans_total", lambda: [],
+                         labels=("reason",))
+    inst = gen.instance("t")
+    inst.now = lambda: _time.time() + 10_000     # every span is stale
+    gen.push_otlp("t", _bench._make_otlp_payload(64, seed=9))
+    fam = parse_exposition(reg.render())["tempo_discarded_spans_total"]
+    assert fam["type"] == "counter"
+    assert fam["samples"] == {
+        ("tempo_discarded_spans_total",
+         (("reason", "outside_slack"),)): 64.0,
+        ("tempo_discarded_spans_total",
+         (("reason", "rate_limited"),)): 2.0}
+    assert inst.spans_filtered_slack == 64
+
+
 def test_donating_push_vs_concurrent_collection():
     """The packed fast path DONATES state buffers; collect()/
     native_histograms()/quantile() run on the collection thread and must

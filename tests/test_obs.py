@@ -417,7 +417,7 @@ def test_dogfood_spans_queryable_by_trace_id(tmp_path):
     srv = serve(app, block=False)
     base = f"http://127.0.0.1:{port}"
     try:
-        assert not isinstance(tracing.tracer(), tracing.NoopTracer)
+        assert isinstance(tracing.tracer(), tracing.SelfTracer)
         with tracing.span("obs-dogfood-root") as root:
             app.frontend.search("single-tenant", "{ }", limit=5)
             tid_hex = root.trace_id.hex()

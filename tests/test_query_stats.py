@@ -325,26 +325,26 @@ def test_frontend_emits_exactly_one_query_complete_line(stack, caplog):
         assert rec["totalBlocks"] == sm["totalBlocks"] == 2
         assert isinstance(rec["traceId"], str) and len(rec["traceId"]) == 32
     finally:
-        tracing.install(tracing.NoopTracer())
+        tracing.install(tracing.Tracer())
         tracer._stop.set()
 
 
 def test_self_tracer_counts_failed_export_as_dropped():
     """Satellite bugfix: a failed export must not silently swallow the
     batch NOR drop it immediately — it is held for exactly ONE retry on
-    the next flush tick (export_retries) before counting into `dropped`."""
+    the next flush tick (export_retries) before counting into `dropped_spans`."""
     from tempo_tpu.utils import tracing
 
     tracer = tracing.SelfTracer("http://127.0.0.1:9", flush_interval_s=3600)
     try:
         with tracer.span("doomed"):
             pass
-        assert tracer.dropped == 0
+        assert tracer.stats["dropped_spans"] == 0
         assert tracer.flush() == 0               # unreachable endpoint
-        assert tracer.dropped == 0               # held, not yet lost
+        assert tracer.stats["dropped_spans"] == 0               # held, not yet lost
         assert tracer.stats["export_retries"] == 1
         assert tracer.flush() == 0               # bounded retry fails too
-        assert tracer.dropped == 1               # NOW it's a counted loss
+        assert tracer.stats["dropped_spans"] == 1               # NOW it's a counted loss
         assert tracer.exported == 0
     finally:
         tracer._stop.set()
