@@ -16,8 +16,11 @@ Reference semantics (`modules/generator/processor/servicegraphs/`):
 
 TPU split: edge *matching* is pointer-chasing and stays on the host (a dict
 keyed by 24-byte trace+span ids, vectorized staging in/out); the metric
-updates for matched edges are batched device scatters via the shared
-registry. Latencies additionally feed a DDSketch per edge series.
+updates for a push's edges (completed and expired together) are ONE device
+step over the shared registry's families: on the dense layout one jitted,
+donating call fed by one packed f32 matrix (`_edge_update_impl`), on the
+paged layout the families' own arena scatters. Latencies feed the classic
+histograms only; there is no sketch per edge series.
 """
 
 from __future__ import annotations
@@ -26,6 +29,7 @@ import collections
 import dataclasses
 import threading
 
+import jax.numpy as jnp
 import numpy as np
 
 from tempo_tpu.model.interner import INVALID_ID
@@ -38,10 +42,50 @@ from tempo_tpu.model.span_batch import (
     SpanBatch,
     void_keys,
 )
+from tempo_tpu.obs.jaxruntime import RUNTIME, instrumented_jit
+from tempo_tpu.registry import metrics as rm
 from tempo_tpu.registry.registry import DEFAULT_HISTOGRAM_EDGES, ManagedRegistry
+from tempo_tpu.sched import bucket_rows
 
 _PEER_ATTRS = ("peer.service", "db.name", "db.system", "messaging.system",
                "net.peer.name")  # `servicegraphs.go:287-343` heuristics
+
+EMITS = RUNTIME.counter(
+    "tempo_metrics_generator_servicegraphs_emits_total",
+    "Service-graph edge emits by device path: fused = one jitted, "
+    "donating step over the dense families; family = the families' own "
+    "calls (paged layout)",
+    labels=("path",))
+
+
+def _edge_update_impl(states, packed):
+    """One device step for all edge families (slots shared). `states` is
+    (total, failed, client_hist, server_hist[, messaging_hist]); `packed`
+    is ONE f32 matrix of rows `slots, fail, cdur, sdur` (+ `mslots, mdur`
+    with the messaging histogram on; without it the four-family graph is
+    traced). Slots ride f32 exactly while the series table's capacity is
+    below 2^24 (the caller gates on that); padding rows carry slot -1 and
+    drop on the device. The registry's update functions are the ones the
+    family-level calls run, so the two paths cannot drift."""
+    total, failed, client_hist, server_hist, *messaging = states
+    slots = packed[0].astype(jnp.int32)
+    out = (rm.counter_update(total, slots),
+           rm.counter_update(failed, slots, packed[1]),
+           rm.histogram_update(client_hist, slots, packed[2]),
+           rm.histogram_update(server_hist, slots, packed[3]))
+    if messaging:
+        out += (rm.histogram_update(
+            messaging[0], packed[4].astype(jnp.int32), packed[5]),)
+    return out
+
+
+# donating, as the spanmetrics fused step is: callers hold the registry's
+# state_lock across call + rebind, since donation deletes the input
+# buffers at dispatch for any concurrent reader. The module is named
+# `jit__edge_update_impl` in a profile: outside the `jit__fused_update*`
+# prefix the benchmark's spanmetrics roofline reads
+_edge_update = instrumented_jit(
+    _edge_update_impl, name="servicegraphs_edge_update", donate_argnums=0)
 
 
 @dataclasses.dataclass
@@ -86,6 +130,14 @@ class ServiceGraphsProcessor:
             self.messaging_hist.share_table(self.total)
         else:
             self.messaging_hist = None
+        self._families = [self.total, self.failed, self.client_hist,
+                          self.server_hist] + (
+            [self.messaging_hist] if self.messaging_hist is not None else [])
+        # the layout decides the device path: dense families take the
+        # jitted step (slots ride its f32 matrix exactly below 2^24),
+        # paged families keep their arena scatters under the pool's lock
+        self._fused = registry.pages is None \
+            and self.total.table.capacity < (1 << 24)
         self._store: dict[bytes, _HalfEdge] = {}
         self._ttl: collections.deque[tuple[float, bytes]] = collections.deque()
         # one tenant's pushes arrive on concurrent HTTP handler threads:
@@ -116,7 +168,7 @@ class ServiceGraphsProcessor:
         server_like = (kinds == KIND_SERVER) | (kinds == KIND_CONSUMER)
         interesting = np.flatnonzero(sb.valid & (client_like | server_like))
         if interesting.size == 0:
-            self._expire(now)
+            self._emit(self._expire(now))
             return
         dur_s = sb.duration_ns / 1e9
         failed = sb.status_code == STATUS_ERROR
@@ -127,7 +179,7 @@ class ServiceGraphsProcessor:
         # exact 24-byte concatenation the old loop produced)
         keys_client = void_keys(sb.trace_id, sb.span_id)
         keys_server = void_keys(sb.trace_id, sb.parent_span_id)
-        completed: list[tuple[int, int, str, float, float, bool]] = []
+        completed: list[tuple] = []
         for i in interesting.tolist():
             is_client = bool(client_like[i])
             is_messaging = kinds[i] in (KIND_PRODUCER, KIND_CONSUMER)
@@ -164,9 +216,8 @@ class ServiceGraphsProcessor:
                                int(sb.start_unix_nano[i]), now + self.cfg.wait_s)
                 self._store[key] = he
                 self._ttl.append((he.expire_at, key))
-        if completed:
-            self._emit(completed)
-        self._expire(now)
+        # completed and expired edges ride ONE emit a push: adds commute
+        self._emit(completed + self._expire(now))
 
     def _peer_col(self, sb: SpanBatch) -> np.ndarray:
         col = np.full(sb.capacity, INVALID_ID, np.int32)
@@ -178,47 +229,57 @@ class ServiceGraphsProcessor:
     # -- emission ----------------------------------------------------------
 
     def _emit(self, edges: list[tuple]) -> None:
-        from tempo_tpu.sched import bucket_rows
-
+        if not edges:
+            return
         it = self.registry.interner
         conn_ids = {c: it.intern(c) for c in ("", "messaging_system", "virtual_node")}
         n = len(edges)
         # pad the edge batch to a pow-2 shape bucket: the matched-edge
         # count varies per tick and unbucketed scatters would re-trace on
         # every new cardinality (padding rows ride slot -1 → dropped)
-        cap = bucket_rows(max(n, 1), lo=16)
-        rows = np.zeros((n, 3), np.int32)
-        cdur = np.zeros(cap, np.float32)
-        sdur = np.zeros(cap, np.float32)
-        fail = np.zeros(cap, np.float32)
-        mdur = np.zeros(cap, np.float32)
-        for j, (cid, sid, conn, cd, sd, failed, msg_delay) in enumerate(edges):
-            rows[j] = (cid, sid, conn_ids[conn])
-            cdur[j], sdur[j], fail[j] = cd, sd, 1.0 if failed else 0.0
-            mdur[j] = msg_delay
-        slots = np.full(cap, -1, np.int32)
-        # the families' updates read, update and REBIND device state, as
-        # the staleness purge's zeroing does on the housekeeping thread:
-        # both sit under the registry's state_lock (the spanmetrics
-        # dispatch discipline), or one side's rebind drops the other's.
-        # The slot resolve rides inside so a purge cannot free a slot
-        # between its resolve and its update
+        cap = bucket_rows(n, lo=16)
+        messaging = self.messaging_hist is not None
+        rows = np.array([(e[0], e[1], conn_ids[e[2]]) for e in edges], np.int32)
+        # rows: slots, fail, cdur, sdur (+ mslots, mdur), `_edge_update_impl`
+        packed = np.zeros((6 if messaging else 4, cap), np.float32)
+        packed[1:4, :n] = np.array([(e[5], e[3], e[4]) for e in edges],
+                                   np.float32).T
+        if messaging:
+            packed[5, :n] = [e[6] for e in edges]
+            msg = [e[2] == "messaging_system" for e in edges]
+        # the update reads, updates and REBINDS device state, as the
+        # staleness purge's zeroing and the collect's snapshot do on
+        # their threads: all sit under the registry's state_lock (the
+        # spanmetrics dispatch discipline), or one side's rebind drops
+        # the other's and a reader meets a donated buffer. The slot
+        # resolve rides inside so a purge cannot free a slot between its
+        # resolve and its update
         with self.registry.state_lock:
+            slots = np.full(cap, -1, np.int32)
             slots[:n] = self.total.resolve_slots(rows)
-            # family-level slot updates: the same dense scatter kernels as
-            # before, but the families own the device half — the paged
-            # layout (registry/pages.py) swaps it for arena scatters
-            self.total.add_slots(slots)
-            self.failed.add_slots(slots, fail)
-            self.client_hist.observe_slots(slots, cdur)
-            self.server_hist.observe_slots(slots, sdur)
-            if self.messaging_hist is not None:
-                msg = np.zeros(cap, bool)
-                msg[:n] = [e[2] == "messaging_system" for e in edges]
-                self.messaging_hist.observe_slots(
-                    np.where(msg, slots, -1), mdur)
+            packed[0] = slots
+            if messaging:
+                mslots = np.full(cap, -1, np.int32)
+                mslots[:n] = np.where(msg, slots[:n], -1)
+                packed[4] = mslots
+            if self._fused:
+                states = _edge_update(
+                    tuple(f.state for f in self._families), packed)
+                for fam, state in zip(self._families, states):
+                    fam.state = state
+            else:
+                # family-level slot updates: the families own the device
+                # half, which the paged layout (registry/pages.py) swaps
+                # for arena scatters
+                self.total.add_slots(slots)
+                self.failed.add_slots(slots, packed[1])
+                self.client_hist.observe_slots(slots, packed[2])
+                self.server_hist.observe_slots(slots, packed[3])
+                if messaging:
+                    self.messaging_hist.observe_slots(mslots, packed[5])
+            EMITS.inc(1, ("fused" if self._fused else "family",))
 
-    def _expire(self, now: float) -> None:
+    def _expire(self, now: float) -> list[tuple]:
         """Expired half-edges become virtual-node edges (`servicegraphs.go:390-421`)."""
         it = self.registry.interner
         expired_edges = []
@@ -245,5 +306,4 @@ class ServiceGraphsProcessor:
                 expired_edges.append((it.intern("user"), he.service_id,
                                       "virtual_node", 0.0, he.duration_s,
                                       he.failed, 0.0))
-        if expired_edges:
-            self._emit(expired_edges)
+        return expired_edges

@@ -125,6 +125,10 @@ _BUDGET_OVERRIDES = {
     # four-way sharded twin, seen at 46 s / 17 s beside five busy workers.
     "tests/test_chip_compile.py::test_dense_fused_update_compiles": 120.0,
     "tests/test_chip_compile.py::test_serving_mesh_step_compiles": 60.0,
+    # the service-graph step: XLA:TPU takes ~19 s over two [65536, 15]
+    # histogram planes for a 16-row scatter (19.8 s read alone)
+    "tests/test_chip_compile.py::test_servicegraphs_edge_update_compiles":
+        60.0,
     # the read plane's grid over a 1M-span block: ~3 s alone, 6 s seen
     # beside five busy workers (the other compile tests stay under 3 s)
     "tests/test_chip_compile.py::test_read_plane_metrics_grid_compiles": 30.0,

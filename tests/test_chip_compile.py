@@ -109,6 +109,29 @@ def test_dense_fused_update_compiles(one_chip):
     assert ma.output_size_in_bytes - ma.alias_size_in_bytes < 4096
 
 
+def test_servicegraphs_edge_update_compiles(one_chip):
+    """The service-graph emit's one step a push, at the default family
+    shapes and the k6 cell's 16-column bucket (four families: what every
+    shipped example runs): every state is donated, so the update is in
+    place on the chip too."""
+    import jax.numpy as jnp
+
+    from tempo_tpu.generator.processors import servicegraphs as sg
+    from tempo_tpu.registry import metrics as rm
+    from tempo_tpu.registry.registry import DEFAULT_HISTOGRAM_EDGES as edges
+
+    f32 = jnp.float32
+    vec = _shape((CAP,), f32, one_chip)
+    hist = rm.HistogramState(_shape((CAP, len(edges) + 1), f32, one_chip),
+                             vec, vec, edges)
+    compiled = sg._edge_update._jit.lower(
+        (rm.CounterState(vec), rm.CounterState(vec), hist, hist),
+        _shape((4, 16), f32, one_chip)).compile()
+    _fits_one_chip(compiled)
+    ma = compiled.memory_analysis()
+    assert ma.output_size_in_bytes - ma.alias_size_in_bytes < 4096
+
+
 def test_serving_mesh_step_compiles(topo):
     """(b) the step `mesh.enabled` dispatches, over the four described
     chips with the state split four ways over 'series'. With the data

@@ -12,6 +12,8 @@ from tempo_tpu.generator import remote_write as rw
 from tempo_tpu.model import proto_wire as pw
 from tempo_tpu.model.span_batch import (
     KIND_CLIENT,
+    KIND_CONSUMER,
+    KIND_PRODUCER,
     KIND_SERVER,
     STATUS_ERROR,
     SpanBatchBuilder,
@@ -146,13 +148,17 @@ def test_servicegraphs_concurrent_pushes_lose_no_edge(with_purge):
     """One tenant's pushes arrive on concurrent handler threads while the
     housekeeping thread purges stale series; every completed edge must
     land (the store, and the families' state rebind against each other
-    and against the purge's zeroing, are read-modify-write)."""
+    and against the purge's zeroing, are read-modify-write). A collector
+    thread snapshots under the state_lock all the while: every emit
+    DONATES the states it rebinds, and a reader outside the lock would
+    meet a deleted buffer."""
     import sys
     import threading
 
     reg = ManagedRegistry(now=FakeClock())
     p = ServiceGraphsProcessor(reg, ServiceGraphsConfig())
     n_threads, n_pushes = 6, 15
+    emits0 = emits_by_path()
 
     def batch(k: int):
         t = k.to_bytes(16, "big")
@@ -180,17 +186,38 @@ def test_servicegraphs_concurrent_pushes_lose_no_edge(with_purge):
                 evicted.append(reg.purge_stale())
 
         targets.append(purge)
+    stop = threading.Event()
+    snaps, errs = [], []
+
+    def collector():
+        while not stop.is_set():
+            try:
+                snaps.append(series_value(
+                    reg.collect(1), "traces_service_graph_request_total",
+                    client="frontend", server="backend") or 0.0)
+            except Exception as e:      # pragma: no cover - the regression
+                errs.append(repr(e))
+                return
+
     old = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:
         threads = [threading.Thread(target=t) for t in targets]
+        coll = threading.Thread(target=collector)
+        coll.start()
         for th in threads:
             th.start()
         for th in threads:
             th.join(timeout=60)
             assert not th.is_alive()
     finally:
+        stop.set()
+        coll.join(timeout=60)
         sys.setswitchinterval(old)
+    assert not errs, errs[:3]
+    assert snaps == sorted(snaps)       # a counter never steps back
+    assert emits_by_path() == {"fused": emits0["fused"] + n_threads * n_pushes,
+                               "family": emits0["family"]}
     samples = reg.collect(1)
     assert series_value(samples, "traces_service_graph_request_total",
                         client="frontend", server="backend") \
@@ -221,6 +248,192 @@ def test_servicegraphs_expiry_virtual_nodes():
     assert series_value(samples, "traces_service_graph_request_total",
                         client="web", server="mysql") == 1.0
     assert p.expired == 2
+
+
+def emits_by_path() -> dict:
+    from tempo_tpu.generator.processors.servicegraphs import EMITS
+
+    return {path: EMITS.value((path,)) for path in ("fused", "family")}
+
+
+def _edge_compiles() -> float:
+    from tempo_tpu.obs.jaxruntime import JIT_COMPILES
+
+    return JIT_COMPILES.value(("servicegraphs_edge_update",))
+
+
+def _edges(reg, n: int, seed: int = 0) -> list:
+    """`n` completed-edge tuples over 5 (client, server) pairs, so slots
+    repeat inside a batch; every third failed, every fourth messaging."""
+    it = reg.interner
+    r = np.random.default_rng(seed)
+    out = []
+    for j in range(n):
+        pair = int(r.integers(5))
+        msg = j % 4 == 1
+        out.append((it.intern(f"cli-{pair}"), it.intern(f"srv-{pair}"),
+                    "messaging_system" if msg else "",
+                    float(r.uniform(0.001, 20.0)), float(r.uniform(0.001, 20.0)),
+                    j % 3 == 0, float(r.uniform(0.0, 2.0)) if msg else 0.0))
+    return out
+
+
+def _sg_pair(messaging: bool, clock=None):
+    """Two processors over dense registries: the jitted step, and the
+    family-level calls a paged registry takes (steered here, in the test:
+    the program has no switch)."""
+    out = []
+    for fused in (True, False):
+        reg = ManagedRegistry(now=clock or FakeClock())
+        p = ServiceGraphsProcessor(reg, ServiceGraphsConfig(
+            wait_s=5.0, enable_messaging_system_latency_histogram=messaging))
+        assert p._fused
+        p._fused = fused
+        out.append((reg, p))
+    return out
+
+
+def _assert_same_edge_state(fused, family):
+    (reg_f, pf), (reg_e, pe) = fused, family
+    assert len(pf._families) == len(pe._families) >= 4
+    for k, (ff, fe) in enumerate(zip(pf._families, pe._families)):
+        sf, se = ff.state, fe.state
+        if k < 2:                       # the two counters
+            np.testing.assert_array_equal(sf.values, se.values)
+            continue
+        np.testing.assert_array_equal(sf.bucket_counts, se.bucket_counts)
+        np.testing.assert_array_equal(sf.counts, se.counts)
+        np.testing.assert_allclose(sf.sums, se.sums, rtol=1e-6)
+    got, want = (sorted((s.name, s.labels, s.value) for s in reg.collect(7))
+                 for reg in (reg_f, reg_e))
+    assert [g[:2] for g in got] == [w[:2] for w in want]
+    assert len(got) > 0
+    for (name, _, g), (_, _, w) in zip(got, want):
+        if name.endswith("_sum"):
+            assert g == pytest.approx(w, rel=1e-6)
+        else:
+            assert g == w
+
+
+@pytest.mark.parametrize("messaging", [False, True], ids=["plain", "messaging"])
+@pytest.mark.parametrize("n", [1, 8, 16, 17, 40])
+def test_servicegraphs_fused_step_equals_family_calls(messaging, n):
+    """The jitted, donating step and the family-level calls run the same
+    registry update functions: counts and buckets bit-equal, sums to f32
+    accumulation order, over batches that fill, spill and pad their
+    pow-2 bucket (padding rides slot -1), three emits deep."""
+    pair = _sg_pair(messaging)
+    e0 = emits_by_path()
+    for seed in range(3):
+        for reg, p in pair:
+            p._emit(_edges(reg, n, seed))
+    assert emits_by_path() == {"fused": e0["fused"] + 3,
+                               "family": e0["family"] + 3}
+    _assert_same_edge_state(*pair)
+    total = pair[0][1].total.state.values
+    assert float(total.sum()) == 3 * n
+
+
+@pytest.mark.parametrize("messaging", [False, True], ids=["plain", "messaging"])
+def test_servicegraphs_completed_and_expired_edges_ride_one_emit(messaging):
+    """A push that completes edges while its TTL ring expires others
+    (virtual nodes) pays ONE emit, on either path, and both paths agree."""
+    clock = FakeClock()
+    pair = _sg_pair(messaging, clock)
+
+    def push(spans):
+        for reg, p in pair:
+            p.push_batch(_mk_batch(spans, interner=reg.interner))
+
+    push([_span(1, service="api", kind=KIND_SERVER, parent=bytes([9]) * 8),
+          _span(2, service="web", kind=KIND_CLIENT, attrs={"db.system": "mysql"})])
+    clock.t += 10.0
+    e0 = emits_by_path()
+    t = bytes([7]) * 16
+    push([_span(3, service="frontend", kind=KIND_PRODUCER, trace=t,
+                dur_ns=3 * 10**8, status=STATUS_ERROR),
+          _span(4, service="backend", kind=KIND_CONSUMER, trace=t,
+                parent=bytes([3]) * 8, dur_ns=2 * 10**8, start=10**9 + 10**8)])
+    assert emits_by_path() == {"fused": e0["fused"] + 1,
+                               "family": e0["family"] + 1}
+    assert [p.expired for _, p in pair] == [2, 2]
+    _assert_same_edge_state(*pair)
+    samples = pair[0][0].collect(9)
+    for labels in (dict(client="user", server="api"),
+                   dict(client="web", server="mysql"),
+                   dict(client="frontend", server="backend",
+                        connection_type="messaging_system")):
+        assert series_value(samples, "traces_service_graph_request_total",
+                            **labels) == 1.0
+    if messaging:
+        assert series_value(
+            samples, "traces_service_graph_request_messaging_system_seconds_sum",
+            client="frontend", server="backend") == pytest.approx(0.1)
+        assert series_value(
+            samples, "traces_service_graph_request_messaging_system_seconds_count",
+            client="user", server="api") == 0.0
+
+
+def _pair_batch(reg, pairs: int, k: int):
+    """`pairs` client→server pairs, completed inside the push."""
+    spans = []
+    for j in range(pairs):
+        t = (k * 1000 + j).to_bytes(16, "big")
+        sid = bytes([j + 1]) * 8
+        spans += [dict(_span(0, service=f"c{j % 3}", kind=KIND_CLIENT, trace=t),
+                       span_id=sid),
+                  dict(_span(0, service=f"s{j % 3}", kind=KIND_SERVER, trace=t,
+                             parent=sid), span_id=bytes([j + 101]) * 8)]
+    return _mk_batch(spans, interner=reg.interner)
+
+
+def test_servicegraphs_one_dispatch_a_push():
+    """N pushes of one bucket shape: N fused emits, no family-level one,
+    and at most one compile a bucket shape (16 and 32 columns here)."""
+    reg = ManagedRegistry(now=FakeClock())
+    p = ServiceGraphsProcessor(reg, ServiceGraphsConfig())
+    e0, c0 = emits_by_path(), _edge_compiles()
+    n = 6
+    for pairs in (8, 20):
+        for k in range(n):
+            p.push_batch(_pair_batch(reg, pairs, k))
+    assert emits_by_path() == {"fused": e0["fused"] + 2 * n,
+                               "family": e0["family"]}
+    assert _edge_compiles() - c0 <= 2
+    c1 = _edge_compiles()
+    p.push_batch(_pair_batch(reg, 8, 99))
+    assert _edge_compiles() == c1           # steady state: no new trace
+    assert float(p.total.state.values.sum()) == n * 28 + 8
+    # the families' counter is on /metrics beside the span families
+    from tempo_tpu.obs.jaxruntime import RUNTIME
+    assert 'tempo_metrics_generator_servicegraphs_emits_total{path="fused"}' \
+        in RUNTIME.render()
+
+
+def test_servicegraphs_fused_step_on_sharded_states():
+    """Under the serving mesh a family's state is placed sharded over
+    'series' (`metrics.place_state`): the jitted step takes the states
+    as they are placed, the packed matrix replicated, and gives the
+    dense answer."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tempo_tpu.registry import metrics as rm
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    mesh = Mesh(np.array(jax.devices()[:4]), ("series",))
+    s1, s2 = NamedSharding(mesh, P("series")), NamedSharding(mesh, P("series", None))
+    dense, sharded = _sg_pair(True)
+    sharded[1]._fused = True
+    for fam in sharded[1]._families:
+        fam.state = rm.place_state(fam.state, s1, s2)
+    for seed in range(3):
+        for reg, p in (dense, sharded):
+            p._emit(_edges(reg, 17, seed))
+    assert len(sharded[1].total.state.values.sharding.device_set) == 4
+    assert len(sharded[1].client_hist.state.bucket_counts.sharding.device_set) == 4
+    _assert_same_edge_state(dense, sharded)
 
 
 def test_generator_instance_slack_filter():

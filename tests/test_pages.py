@@ -296,6 +296,37 @@ def test_servicegraphs_paged_bit_identical():
     assert run(True) == run(False)
 
 
+def test_servicegraphs_paged_emits_through_the_families():
+    """The layout decides the device path: the same pushes count under
+    `family` with the page pool on (arena scatters under the pool's
+    lock) and under `fused` on the dense layout, never compile the
+    dense step for a paged tenant, and give the same answers."""
+    from tempo_tpu.generator.processors.servicegraphs import (
+        EMITS, ServiceGraphsConfig, ServiceGraphsProcessor)
+    from tempo_tpu.obs.jaxruntime import JIT_COMPILES
+
+    def run(paged):
+        pool = _pool(page_rows=16, arena_slots=512) if paged else None
+        with P.use(pool):
+            reg = ManagedRegistry(
+                "t", RegistryOverrides(max_active_series=64),
+                now=lambda: 1000.0)
+            proc = ServiceGraphsProcessor(reg, ServiceGraphsConfig())
+        before = {k: EMITS.value((k,)) for k in ("fused", "family")}
+        compiles = JIT_COMPILES.value(("servicegraphs_edge_update",))
+        for n in (5, 5, 5, 12):
+            proc.push_batch(_sg_batch(reg, n))
+        grew = {k: EMITS.value((k,)) - v for k, v in before.items()}
+        return (grew, JIT_COMPILES.value(("servicegraphs_edge_update",))
+                - compiles, _collect_exact(reg))
+
+    grew_p, compiles_p, out_p = run(True)
+    grew_d, compiles_d, out_d = run(False)
+    assert grew_p == {"fused": 0, "family": 4} and compiles_p == 0
+    assert grew_d == {"fused": 4, "family": 0} and compiles_d <= 1
+    assert out_p == out_d and out_p
+
+
 def _sg_batch(reg, n=200):
     from tempo_tpu.model.span_batch import SpanBatchBuilder
 

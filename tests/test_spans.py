@@ -359,6 +359,21 @@ def test_fused_update_keeps_its_jit_name():
     assert any(name.startswith(pre) for pre in _layer_prefixes())
 
 
+def test_edge_update_stays_outside_the_fused_update_match():
+    """The service-graph step is a module of its own in a profile: no
+    layer file's prefix (`jit__fused_update` above all, the spanmetrics
+    kernel's roofline) may pick it up."""
+    from tempo_tpu.generator.processors import servicegraphs as sg
+    from tempo_tpu.registry import ManagedRegistry
+
+    p = sg.ServiceGraphsProcessor(ManagedRegistry("t"))
+    name = _module_name(sg._edge_update._jit.lower(
+        tuple(f.state for f in p._families), np.zeros((4, 16), np.float32)))
+    assert name == "jit__edge_update_impl"
+    assert "jit__fused_update" in _layer_prefixes()
+    assert not any(name.startswith(pre) for pre in _layer_prefixes())
+
+
 def test_search_mask_keeps_its_jit_name():
     from tempo_tpu.block.device_scan import _block_mask_kernel
 
