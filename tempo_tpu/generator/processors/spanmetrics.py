@@ -483,24 +483,43 @@ class SpanMetricsProcessor:
             sizes = np.concatenate([sizes, np.zeros(pad, np.float32)])
             weights = np.concatenate([weights, np.zeros(pad, np.float32)])
         step = self._mesh_fused_step(sm)
+        self._mesh_note_rows(sm, slots)
         batch = sm.put_batch(
             np.ascontiguousarray(slots, np.int32),
             np.asarray(dur_s, np.float32), np.asarray(sizes, np.float32),
             np.asarray(weights, np.float32))
         self._mesh_step_rebind(sm, step, batch)
 
+    def _mesh_note_rows(self, sm, slots: np.ndarray) -> None:
+        """Tell the mesh which shards own this batch's rows
+        (tempo_mesh_shard_rows_total)."""
+        sk = self.dd.counts if self.dd is not None else \
+            self.mom.data if self.mom is not None else None
+        sm.note_rows(slots, self.calls.table.capacity,
+                     sk.shape[0] if sk is not None else 0)
+
+    def _mesh_place_packed(self, mat: np.ndarray):
+        """The upload of one packed [4, bucket] f32 batch: the matrix
+        placed on the mesh (columns over 'data', a copy a 'series'
+        shard) and the bytes that cost the host link. The scheduler
+        calls this inside `sched.h2d`, so the closure below receives a
+        placed operand and `sched.enqueue` holds no transfer."""
+        sm = self._mesh
+        self._mesh_note_rows(sm, mat[0])
+        return sm.put_packed(mat), sm.link_bytes(mat)
+
     def _mesh_dispatch_packed(self, sm, mat: np.ndarray) -> None:
-        """Packed mesh dispatch: ONE [4, bucket] f32 H2D (columns
-        sharded over 'data'), one shard_map launch. Slot ids ride f32
-        exactly under the capacity < 2^24 gate the callers hold."""
+        """Packed mesh dispatch off the scheduler (a direct push): ONE
+        [4, bucket] f32 H2D (columns sharded over 'data'), one shard_map
+        launch. Slot ids ride f32 exactly under the capacity < 2^24 gate
+        the callers hold."""
         d = sm.data_shards
         if mat.shape[1] % d:
             pad = d - mat.shape[1] % d
             ext = np.zeros((4, pad), np.float32)
             ext[0] = -1.0
             mat = np.concatenate([mat, ext], axis=1)
-        step = self._mesh_fused_step(sm, packed=True)
-        self._mesh_step_rebind(sm, step, (sm.put_packed(mat),))
+        self._sched_dispatch_sharded_packed(self._mesh_place_packed(mat)[0])
 
     def _sched_dispatch_sharded(self, slots, dur_s, sizes, weights) -> None:
         """Merged-batch dispatch on the scheduler worker, serving-mesh
@@ -509,11 +528,14 @@ class SpanMetricsProcessor:
         window lands in one shard_map launch."""
         self._mesh_update(self._mesh, slots, dur_s, sizes, weights)
 
-    def _sched_dispatch_sharded_packed(self, mat) -> None:
+    def _sched_dispatch_sharded_packed(self, placed) -> None:
         """Packed-coalescer mesh dispatch: the merged window arrives as
-        the coalescer's ONE [4, bucket] f32 matrix — a single H2D feeds
-        every shard via one shard_map launch."""
-        self._mesh_dispatch_packed(self._mesh, mat)
+        the coalescer's ONE [4, bucket] f32 matrix, already on the mesh
+        (`_mesh_place_packed`, made by the scheduler inside `sched.h2d`)
+        — one shard_map launch feeds every shard."""
+        sm = self._mesh
+        self._mesh_step_rebind(sm, self._mesh_fused_step(sm, packed=True),
+                               (placed,))
 
     def _pipeline(self, sc):
         """The staging pipeline riding scheduler `sc`, or None when the
@@ -692,7 +714,9 @@ class SpanMetricsProcessor:
             pads=(-1.0, 0.0, 0.0, 0.0) if packed else (-1, 0.0, 0.0, 0.0),
             tenant=self.registry.tenant, pack=packed,
             align=sm.data_shards if sm is not None else 1,
-            shards=sm.data_shards if sm is not None else 0)
+            shards=sm.data_shards if sm is not None else 0,
+            place=self._mesh_place_packed
+            if packed and sm is not None else None)
 
     def needs_attr_columns(self) -> tuple[bool, bool]:
         """(span_attrs, res_attrs) this processor reads — owned HERE so a

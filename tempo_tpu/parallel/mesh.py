@@ -212,7 +212,11 @@ def sharded_serving_step(mesh: Mesh, edges: tuple, gamma: float,
     mom_shard = mom_rows // n_series_shards if mom_rows else 0
     n_sketch = (2 if dd_shard else 0) + (1 if mom_shard else 0)
 
-    def step(calls_v, h_buckets, h_sums, h_counts, size_v, *rest):
+    # the name is the step's handle in a profile (module
+    # `jit__fused_update_mesh_impl`): the chip benchmark's roofline reader
+    # finds the kernel by it, and tests/test_spans.py pins it
+    def _fused_update_mesh_impl(calls_v, h_buckets, h_sums, h_counts, size_v,
+                                *rest):
         sk = rest[:n_sketch]
         dd_counts = dd_zeros = mom_data = None
         if dd_shard:
@@ -314,7 +318,7 @@ def sharded_serving_step(mesh: Mesh, edges: tuple, gamma: float,
     # check_vma=False: the base-scatter branch's outputs ARE replicated
     # over 'data' (the axis has size 1 there), but without a psum the
     # static replication checker can't infer it
-    fn = _shard_map(step, mesh=mesh,
+    fn = _shard_map(_fused_update_mesh_impl, mesh=mesh,
                     in_specs=state_specs + batch_specs,
                     out_specs=state_specs, check_vma=False)
     # instrumented: the serving path's zero-steady-state-recompile gate

@@ -45,6 +45,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import threading
+import weakref
 
 import numpy as np
 
@@ -167,6 +168,18 @@ class ServingMesh:
         self._steps: dict[tuple, object] = {}
         self._combine: dict[tuple, object] = {}
         self._lock = threading.Lock()
+        # what the write path counts about itself (the tempo_mesh_*
+        # families read these at the scrape): real batch rows by the
+        # 'series' shard that owns them, on the series table and on the
+        # sketch plane; bytes the batch uploads cost the host link; and
+        # the processors that asked for this mesh and stayed on one
+        # device. The scheduler's thread, a shed push and a direct push
+        # may all write, hence the lock.
+        self._stats_lock = threading.Lock()
+        self.shard_rows = {plane: np.zeros(series, np.int64)
+                           for plane in ("series", "sketch")}
+        self.h2d_bytes = 0
+        self.unplaced = weakref.WeakSet()
 
     # -- write path --------------------------------------------------------
 
@@ -196,12 +209,24 @@ class ServingMesh:
                     mom_meta=mom_meta)
             return fn
 
+    def link_bytes(self, *arrays) -> int:
+        """What a batch upload costs the host link: the rows split over
+        'data' and every 'series' shard's device receives its own copy,
+        so the batch crosses once a series shard."""
+        return sum(int(a.nbytes) for a in arrays) * self.series_shards
+
+    def _note_h2d(self, *arrays) -> None:
+        n = self.link_bytes(*arrays)
+        with self._stats_lock:
+            self.h2d_bytes += n
+
     def put_batch(self, *arrays):
         """Host batch vectors → device, leading dim sharded over 'data'.
         Lengths must be a multiple of `data_shards` (the coalescer's
         `align` guarantees it for scheduled dispatches)."""
         import jax
 
+        self._note_h2d(*arrays)
         return tuple(jax.device_put(a, self.data_sharding) for a in arrays)
 
     def put_packed(self, mat: np.ndarray):
@@ -209,7 +234,29 @@ class ServingMesh:
         — the single-transfer batch upload."""
         import jax
 
+        self._note_h2d(mat)
         return jax.device_put(mat, self.packed_sharding)
+
+    def note_rows(self, slots: np.ndarray, capacity: int,
+                  sketch_rows: int) -> None:
+        """Count one batch's real rows (slot >= 0; padding carries -1)
+        by the 'series' shard that owns them: on the series table, and
+        on the sketch plane under its own slot→shard mapping (a slot
+        beyond the plane has no sketch row). Slots come off the table's
+        free list in order and a shard owns a contiguous slot range, so
+        this is where a part-filled table shows as one hot shard. One
+        bincount a plane a batch."""
+        s = self.series_shards
+        real = slots[slots >= 0].astype(np.int64)
+        series = np.bincount(real // (capacity // s), minlength=s)
+        sketch = None
+        if sketch_rows:
+            sketch = np.bincount(
+                real[real < sketch_rows] // (sketch_rows // s), minlength=s)
+        with self._stats_lock:
+            self.shard_rows["series"] += series
+            if sketch is not None:
+                self.shard_rows["sketch"] += sketch
 
     # -- frontend combine --------------------------------------------------
 
@@ -320,6 +367,10 @@ def place_spanmetrics_state(proc, sm: "ServingMesh | None" = None) -> bool:
     mom = getattr(proc, "mom", None)
     mom_rows = mom.data.shape[0] if mom is not None else 0
     if not sm.fits_state(proc.calls.table.capacity, dd_rows, mom_rows):
+        # on /metrics too (tempo_mesh_unplaced_processors): a mesh
+        # deployment that serves from one device must not read like one
+        # that serves from four
+        sm.unplaced.add(proc)
         _LOG.warning(
             "serving mesh: capacity %d / sketch rows %d/%d not divisible "
             "by series_shards %d — processor stays single-device",
@@ -359,6 +410,36 @@ RUNTIME.gauge_func(
     lambda: [] if _active is None else [((), float(_active.data_shards))],
     help="'data' axis size of the serving mesh: coalesced batch rows "
          "split this many ways per dispatch")
+
+
+def _shard_rows():
+    sm = _active
+    if sm is None:
+        return []
+    with sm._stats_lock:
+        return [((plane, str(i)), float(n))
+                for plane, rows in sm.shard_rows.items()
+                for i, n in enumerate(rows)]
+
+
+RUNTIME.counter_func(
+    "tempo_mesh_shard_rows_total", _shard_rows, labels=("plane", "shard"),
+    help="Real (non-padding) rows of the span-metrics batches dispatched "
+         "on the serving mesh, by the 'series' shard that owns their "
+         "slots: plane=\"series\" the series table, plane=\"sketch\" the "
+         "sketch plane (its own slot ranges). Even shares mean an even "
+         "mesh; one hot shard means the table is filled from slot 0 up")
+RUNTIME.counter_func(
+    "tempo_mesh_h2d_bytes_total",
+    lambda: [] if _active is None else [((), float(_active.h2d_bytes))],
+    help="Bytes the serving mesh's batch uploads cost the host link: "
+         "batch bytes times the 'series' shards that each receive a copy")
+RUNTIME.gauge_func(
+    "tempo_mesh_unplaced_processors",
+    lambda: [] if _active is None else [((), float(len(_active.unplaced)))],
+    help="Span-metrics processors that asked for the serving mesh and "
+         "stayed on one device (capacities not divisible by "
+         "series_shards); 0 on a healthy mesh")
 
 
 __all__ = ["MeshConfig", "ServingMesh", "configure", "active", "reset",

@@ -334,11 +334,12 @@ class _MergeGroup:
     one dispatch closure, one eventual padded tensor."""
 
     __slots__ = ("kernel", "pads", "dispatch", "jobs", "rows", "first_t",
-                 "pack", "align", "shards")
+                 "pack", "align", "shards", "place")
 
     def __init__(self, kernel: str, pads: tuple, dispatch: Callable,
                  first_t: float, pack: bool = False, align: int = 1,
-                 shards: int = 0) -> None:
+                 shards: int = 0,
+                 place: "Callable | None" = None) -> None:
         self.kernel = kernel
         self.pads = pads
         self.dispatch = dispatch
@@ -348,6 +349,7 @@ class _MergeGroup:
         self.pack = pack
         self.align = align
         self.shards = shards
+        self.place = place
 
 
 class DeviceScheduler:
@@ -547,7 +549,8 @@ class DeviceScheduler:
                     n_rows: int, dispatch: Callable,
                     pads: "Sequence | None" = None,
                     tenant: str = "", pack: bool = False,
-                    align: int = 1, shards: int = 0) -> Job:
+                    align: int = 1, shards: int = 0,
+                    place: "Callable | None" = None) -> Job:
         """Enqueue a coalescible row batch (live-ingest class).
 
         `arrays` are row-aligned host vectors (one per kernel argument
@@ -572,7 +575,11 @@ class DeviceScheduler:
         the mesh dispatch's data-shard count for observability (0 =
         non-mesh): mesh dispatches emit one occupancy sample per shard
         under the `shard` label, non-mesh batches keep the aggregate
-        under shard="".
+        under shard="". `place` (with `pack`, on a mesh) is the caller's
+        upload of the packed matrix: `place(matrix)` returns the placed
+        operand and the bytes it cost the host link. The scheduler calls
+        it inside `sched.h2d`, where it makes the one-device group's
+        `device_put`, and hands the operand to `dispatch`.
 
         Never blocks and never drops data: on a saturated queue the job
         executes inline on the caller (shed, counted) — ADMISSION control
@@ -585,7 +592,7 @@ class DeviceScheduler:
                   arrays=tuple(arrays), pads=pads, n_rows=int(n_rows),
                   dispatch=dispatch, tenant=tenant)
         if not self.cfg.enabled:
-            self._run_group(_group_of(job, pack, align, shards))
+            self._run_group(_group_of(job, pack, align, shards, place))
             return job
         if self.cfg.tuning == "auto":
             # arrival-rate accounting for the window tuner (outside
@@ -602,7 +609,7 @@ class DeviceScheduler:
                 if g is None:
                     g = self._groups[merge_key] = _MergeGroup(
                         kernel, pads, dispatch, job.enqueue_t, pack=pack,
-                        align=align, shards=shards)
+                        align=align, shards=shards, place=place)
                 g.jobs.append(job)
                 g.rows += job.n_rows
                 self.jobs_total["ingest"] += 1
@@ -617,7 +624,7 @@ class DeviceScheduler:
                     self._cond.notify_all()
                 return job
         # shed path: dispatch inline, outside the lock
-        self._run_group(_group_of(job, pack, align, shards))
+        self._run_group(_group_of(job, pack, align, shards, place))
         return job
 
     def run(self, fn: Callable, kernel: str = "fn",
@@ -945,16 +952,23 @@ class DeviceScheduler:
                     padded, waste = self._pad_chunk(g, chunk, rows, bucket)
                     dtypes = tuple(a.dtype.str for a in padded)
                     h2d_bytes = sum(int(a.nbytes) for a in padded)
-                    if hs is not None:
-                        hs.attrs["h2d_bytes"] = h2d_bytes
-                    if g.pack and not g.shards:
+                    if g.place is not None:
+                        # a packed mesh group: the sharded placement is
+                        # the upload, made here like the one-device
+                        # group's below, and h2d_bytes is what crosses
+                        # the link (a copy a 'series' shard)
+                        placed, h2d_bytes = g.place(padded[0])
+                        padded = [placed]
+                    elif g.pack and not g.shards:
                         # the packed matrix exists to be ONE H2D: made
                         # here, on one device, it is timed apart from
-                        # the enqueue. A mesh group's closure places its
-                        # own sharded operand, and unpacked operands stay
-                        # host arrays (some closures read them on the
-                        # host): their H2D is inside sched.enqueue.
+                        # the enqueue. Unpacked operands stay host
+                        # arrays (some closures read them on the host,
+                        # an unpacked mesh group's closure places its
+                        # own): their H2D is inside sched.enqueue.
                         padded = [jax.device_put(padded[0])]
+                    if hs is not None:
+                        hs.attrs["h2d_bytes"] = h2d_bytes
                 self._note_batch(g, len(chunk), rows, bucket, dtypes, waste)
                 td0 = time.perf_counter()
                 with tracing.span("sched.enqueue"):
@@ -1098,9 +1112,10 @@ class DeviceScheduler:
 
 
 def _group_of(job: Job, pack: bool = False, align: int = 1,
-              shards: int = 0) -> _MergeGroup:
+              shards: int = 0,
+              place: "Callable | None" = None) -> _MergeGroup:
     g = _MergeGroup(job.kernel, job.pads, job.dispatch, job.enqueue_t,
-                    pack=pack, align=align, shards=shards)
+                    pack=pack, align=align, shards=shards, place=place)
     g.jobs.append(job)
     g.rows = job.n_rows
     return g

@@ -158,6 +158,8 @@ def test_serving_mesh_step_compiles(topo):
     ).compile()
     _fits_one_chip(compiled)
     hlo = compiled.as_text()
+    # the name the mesh cell's roofline reader finds the step by
+    assert hlo.startswith("HloModule jit__fused_update_mesh_impl")
     assert not any(op in hlo for op in ("all-reduce", "all-gather",
                                         "all-to-all", "collective-permute"))
 

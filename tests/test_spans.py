@@ -356,7 +356,42 @@ def test_fused_update_keeps_its_jit_name():
         p.calls.state, p.latency.state, p.sizes.state, p.dd, p.mom,
         np.zeros((4, 64), np.float32)))
     assert name == "jit__fused_update_packed4_impl"
-    assert any(name.startswith(pre) for pre in _layer_prefixes())
+    # the one-device roofline's prefix, and not the mesh cell's
+    assert {pre for pre in _layer_prefixes() if name.startswith(pre)} == {
+        "jit__fused_update"}
+
+
+def test_mesh_fused_update_keeps_its_jit_name():
+    """The serving mesh's step (`jit(shard_map(...))`) is found in a
+    profile by the name of the function handed to `shard_map`: inside
+    the mesh cell's roofline prefix, and inside no other layer file's
+    but the one-device roofline's `jit__fused_update` (which reads the
+    one-device cell only, where this module never runs)."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tempo_tpu.parallel.mesh import make_mesh, sharded_serving_step
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    mesh = make_mesh(4, series_shards=4)
+    s1 = NamedSharding(mesh, P("series"))
+    s2 = NamedSharding(mesh, P("series", None))
+    f32 = np.float32
+    vec = jax.ShapeDtypeStruct((64,), f32, sharding=s1)
+    step = sharded_serving_step(mesh, (0.1, 1.0), 1.02, 1e-9, 64, 64,
+                                packed=True)
+    name = _module_name(step._jit.lower(
+        vec, jax.ShapeDtypeStruct((64, 3), f32, sharding=s2), vec, vec, vec,
+        jax.ShapeDtypeStruct((64, 8), f32, sharding=s2), vec,
+        jax.ShapeDtypeStruct((4, 64), f32,
+                             sharding=NamedSharding(mesh, P(None, "data")))))
+    assert name == "jit__fused_update_mesh_impl"
+    with open(os.path.join(REPO, "chipbench", "layers",
+                           "fused_update_roofline_pct.mesh4.json")) as f:
+        assert name.startswith(json.load(f)["reader"]["module"])
+    assert {pre for pre in _layer_prefixes() if name.startswith(pre)} == {
+        "jit__fused_update_mesh", "jit__fused_update"}
 
 
 def test_edge_update_stays_outside_the_fused_update_match():
