@@ -369,12 +369,12 @@ class GeneratorInstance:
                 with tracing.span("registry.purge"):
                     self.registry.purge_stale()
                 self._last_purge = self.now()
-            samples = self.registry.collect(ts_ms)
+            columns = self.registry.collect_columns(ts_ms)
             native = (self.registry.native_histograms(ts_ms)
                       if self.cfg.remote_write.send_native_histograms
                       else [])
-            self.remote_write.send(samples, native)
-            return len(samples)
+            self.remote_write.send(columns, native)
+            return sum(cols.n_series for cols in columns)
 
     # -- accounting --------------------------------------------------------
 

@@ -39,7 +39,8 @@ from typing import Iterable, Sequence
 import numpy as np
 
 from tempo_tpu.model import proto_wire as pw
-from tempo_tpu.registry.series import Sample
+from tempo_tpu.registry.registry import FamilyColumns
+from tempo_tpu.registry.series import Exemplar, Sample
 from tempo_tpu.utils import tracing
 
 MAX_LITERAL = (1 << 32) - 1
@@ -51,6 +52,9 @@ MAX_LITERAL = (1 << 32) - 1
 _RW_LOCK = threading.Lock()
 _RW_RETRIES: dict[str, int] = {}      # cause -> count
 _RW_STATS = {"sends": 0, "failed": 0}
+# TimeSeries written, by where their label blocks came from: `kept` from
+# a family's LabelBlocks, `built` where a block had to be encoded
+_RW_SERIES = {"kept": 0, "built": 0}
 
 
 def _note_retry(cause: str) -> None:
@@ -82,8 +86,19 @@ def _enc_label(name: str, value: str) -> bytes:
     return pw.enc_field_str(1, name) + pw.enc_field_str(2, value)
 
 
+def _enc_pair(name: str, value: str) -> bytes:
+    """One `repeated Label labels = 1` entry of a TimeSeries."""
+    return pw.enc_field_msg(1, _enc_label(name, value))
+
+
 def _enc_labels(labels: Sequence[tuple[str, str]]) -> bytes:
-    return b"".join(pw.enc_field_msg(1, _enc_label(n, v)) for n, v in sorted(labels))
+    return b"".join(_enc_pair(n, v) for n, v in sorted(labels))
+
+
+def _enc_exemplar(ex: Exemplar) -> bytes:
+    return pw.enc_field_msg(
+        3, pw.enc_field_msg(1, _enc_label("trace_id", ex.trace_id_hex))
+        + pw.enc_field_double(2, ex.value) + pw.enc_field_varint(3, ex.ts_ms))
 
 
 def _zigzag(v: int) -> int:
@@ -150,10 +165,7 @@ def encode_write_request(samples: Iterable[Sample],
         body = _enc_labels(s.labels) + pw.enc_field_msg(
             2, pw.enc_field_double(1, s.value) + pw.enc_field_varint(2, ts))
         if s.exemplar is not None:
-            ex = (pw.enc_field_msg(1, _enc_label("trace_id", s.exemplar.trace_id_hex))
-                  + pw.enc_field_double(2, s.exemplar.value)
-                  + pw.enc_field_varint(3, s.exemplar.ts_ms))
-            body += pw.enc_field_msg(3, ex)
+            body += _enc_exemplar(s.exemplar)
         out += pw.enc_field_msg(1, body)
     for labels, log2_counts, sum_, count, zeros, ts, *rest in native_histograms:
         offset = rest[0] if rest else 0
@@ -161,6 +173,153 @@ def encode_write_request(samples: Iterable[Sample],
             4, encode_native_histogram(log2_counts, count, zeros, sum_, ts, offset))
         out += pw.enc_field_msg(1, body)
     return bytes(out)
+
+
+def _objects(items: list) -> np.ndarray:
+    """[n] object array of the bytes in `items`, as they are (a fixed-width
+    string dtype on the way would strip trailing NUL bytes)."""
+    out = np.empty(len(items), object)
+    out[:] = items
+    return out
+
+
+_LEN = np.frompyfunc(len, 1, 1)
+
+
+class LabelBlocks:
+    """One family's encoded `repeated Label` bytes, a series' block built
+    on first sight and kept until the registry evicts its slot (`drop`).
+
+    A block is the slot's sorted pairs (its label values, the tenant's
+    external labels, `__name__`), split where `le` sorts in: a TimeSeries'
+    labels are then `before + [le pair] + after`. `key` is everything a
+    block is built from besides the slot's own values; the encoder makes a
+    new store when it differs."""
+
+    def __init__(self, key: tuple):
+        self.key = key
+        self._blocks: dict[int, tuple[bytes, bytes]] = {}
+        # an eviction between a build's read of the slot's labels and its
+        # store would hand the slot's next series the old block
+        self._lock = threading.Lock()
+        self._drops = 0
+
+    def drop(self, slots: np.ndarray) -> None:
+        with self._lock:
+            self._drops += 1
+            for slot in slots.tolist():
+                self._blocks.pop(slot, None)
+
+    def get(self, fam, slots: np.ndarray) -> tuple[list, int]:
+        """([S] (before, after) blocks, how many had to be built)."""
+        slot_list = slots.tolist()
+        with self._lock:
+            drops = self._drops
+            got = [self._blocks.get(s) for s in slot_list]
+        miss = [i for i, b in enumerate(got) if b is None]
+        if miss:
+            built = _build_blocks(fam, slots[miss])
+            for i, block in zip(miss, built):
+                got[i] = block
+            with self._lock:
+                if drops == self._drops:
+                    self._blocks.update(
+                        (slot_list[i], got[i]) for i in miss)
+        return got, len(miss)
+
+
+def _build_blocks(fam, slots: np.ndarray) -> list[tuple[bytes, bytes]]:
+    """`_MetricBase.labels_of`'s pairs for `slots`, encoded a label column
+    at a time: each distinct value of a column is encoded once."""
+    fixed = {**fam.registry.overrides.external_labels, "__name__": fam.name}
+    column = {name: j for j, name in enumerate(fam.label_names)}
+    keys = fam.table.slot_keys[slots]
+    before = after = _objects([b""] * len(slots))
+    for name in sorted(column.keys() | fixed.keys()):
+        if name in fixed:
+            pairs = _objects([_enc_pair(name, fixed[name])])
+        else:
+            ids, inverse = np.unique(keys[:, column[name]],
+                                     return_inverse=True)
+            pairs = _objects([
+                _enc_pair(name, value)
+                for value in fam.registry.interner.lookup_many(ids)])[inverse]
+        if name < "le":
+            before = before + pairs
+        else:
+            after = after + pairs
+    return list(zip(before.tolist(), after.tolist()))
+
+
+def _family_blocks(fam) -> "LabelBlocks | None":
+    """The family's store, made anew where its label names or the tenant's
+    external labels are no longer what the blocks were built with. None
+    where a label is itself named `le`: `sorted()` then orders that pair
+    against every bucket's by VALUE, and no split holds for all edges."""
+    external = fam.registry.overrides.external_labels
+    if "le" in external or "le" in fam.label_names:
+        return None
+    key = (fam.name, fam.label_names, tuple(sorted(external.items())))
+    if fam.label_blocks is None or fam.label_blocks.key != key:
+        fam.label_blocks = LabelBlocks(key)
+    return fam.label_blocks
+
+
+def _encode_family(cols: FamilyColumns) -> tuple[bytes, int]:
+    """(one family's TimeSeries, how many found their label blocks kept).
+    Byte for byte `encode_write_request(cols.samples())`."""
+    n_slots, n_kinds = cols.values.shape
+    blocks = _family_blocks(cols.family) if n_slots else None
+    if blocks is None:
+        return encode_write_request(cols.samples()), 0
+    got, n_built = blocks.get(cols.family, cols.slots)
+    before, after = (_objects(list(side)) for side in zip(*got))
+    le = _objects([b"" if le is None else _enc_pair("le", le)
+                   for _, le in cols.kinds])
+    # Sample{value, timestamp}: the same bytes but for the double
+    stamp = pw.enc_field_varint(2, cols.ts_ms)
+    sample = np.empty((n_slots * n_kinds, 11 + len(stamp)), np.uint8)
+    sample[:, :3] = 0x12, 9 + len(stamp), 0x09
+    sample[:, 3:11] = cols.values.astype("<f8").reshape(-1, 1).view(np.uint8)
+    sample[:, 11:] = np.frombuffer(stamp, np.uint8)
+    # one exemplar a slot a tick, whichever of its series carry it
+    exemplar = _objects([b"" if ex is None else _enc_exemplar(ex)
+                         for ex in cols.exemplars])
+    body = (_LEN(before) + _LEN(after))[:, None] + _LEN(le)[None, :] \
+        + sample.shape[1] + _LEN(exemplar)[:, None] * cols.carries
+    sizes, which = np.unique(body.astype(np.int64).reshape(-1),
+                             return_inverse=True)
+    head = _objects([b"\x0a" + pw.enc_varint(n) for n in sizes.tolist()])
+    series = np.empty((n_slots, n_kinds, 6), object)
+    series[:, :, 0] = head[which].reshape(n_slots, n_kinds)
+    series[:, :, 1] = before[:, None]
+    series[:, :, 2] = le[None, :]
+    series[:, :, 3] = after[:, None]
+    series[:, :, 4] = _objects(
+        sample.view(f"V{sample.shape[1]}").reshape(-1).tolist()
+    ).reshape(n_slots, n_kinds)
+    series[:, :, 5] = np.where(cols.carries, exemplar[:, None], _objects([b""]))
+    return (b"".join(series.reshape(-1).tolist())
+            + encode_write_request(cols.stale_samples()),
+            (n_slots - n_built) * n_kinds)
+
+
+def encode_columns(columns: Sequence[FamilyColumns],
+                   native_histograms: Iterable[tuple] = ()) -> bytes:
+    """A collection tick → WriteRequest bytes, the bytes of
+    `encode_write_request(samples of the columns, native_histograms)`."""
+    parts, kept, total = [], 0, 0
+    for cols in columns:
+        part, n_kept = _encode_family(cols)
+        parts.append(part)
+        kept += n_kept
+        total += cols.n_series
+    native_histograms = list(native_histograms)
+    parts.append(encode_write_request((), native_histograms))
+    with _RW_LOCK:
+        _RW_SERIES["kept"] += kept
+        _RW_SERIES["built"] += total - kept + len(native_histograms)
+    return b"".join(parts)
 
 
 @dataclasses.dataclass
@@ -225,14 +384,16 @@ class RemoteWriteClient:
                 0.0, max(retry_after * 0.1, self.cfg.backoff_s))
         return sleep_s
 
-    def send(self, samples: Sequence[Sample], native_histograms: Sequence[tuple] = ()) -> bool:
-        if not self.cfg.url or (not samples and not native_histograms):
+    def send(self, columns: Sequence[FamilyColumns],
+             native_histograms: Sequence[tuple] = ()) -> bool:
+        n_samples = sum(cols.n_series for cols in columns)
+        if not self.cfg.url or (not n_samples and not native_histograms):
             return True
-        with tracing.span("remote_write.encode", n_samples=len(samples)):
+        with tracing.span("remote_write.encode", n_samples=n_samples):
             payload = snappy_compress(
-                encode_write_request(samples, native_histograms))
+                encode_columns(columns, native_histograms))
         with tracing.span("remote_write.send", n_bytes=len(payload)):
-            return self._post(payload, len(samples))
+            return self._post(payload, n_samples)
 
     def _post(self, payload: bytes, n_samples: int) -> bool:
         """One remote-write request, retries and their sleeps included."""
@@ -302,6 +463,14 @@ RUNTIME.counter_func(
     "tempo_remote_write_sends_total",
     lambda: [((), float(_RW_STATS["sends"]))],
     help="Remote-write requests delivered (2xx)")
+RUNTIME.counter_func(
+    "tempo_remote_write_series_encoded_total",
+    lambda: [((k,), float(v)) for k, v in _RW_SERIES.items()],
+    help="TimeSeries written to remote-write payloads, by whether their "
+         "label blocks were kept from an earlier tick or had to be built "
+         "(a series' first tick, after an eviction, after the tenant's "
+         "external labels changed)",
+    labels=("labels",))
 RUNTIME.counter_func(
     "tempo_remote_write_failed_sends_total",
     lambda: [((), float(_RW_STATS["failed"]))],

@@ -51,6 +51,46 @@ class RegistryOverrides:
     disable_collection: bool = False
 
 
+@dataclasses.dataclass
+class FamilyColumns:
+    """One family's share of a collection tick, in columns. A slot's
+    series go out in the order of `kinds`, slots ascending, then one
+    staleness marker per evicted series: the order of `samples()` and of
+    the remote-write payload, which are both read from here."""
+
+    family: "_MetricBase"
+    ts_ms: int
+    slots: np.ndarray       # [S] active slots
+    # a slot's series: (sample-name suffix, `le` value or None)
+    kinds: tuple[tuple[str, "str | None"], ...]
+    values: np.ndarray      # [S, K] float64 (f32 state widened)
+    exemplars: list         # [S] the slot's last Exemplar, or None
+    carries: np.ndarray     # [S, K] bool: this series carries the exemplar
+    stale: list             # label sets of evicted series, a marker each
+
+    @property
+    def n_series(self) -> int:
+        return self.values.size + len(self.stale)
+
+    def stale_samples(self) -> list[Sample]:
+        return [Sample(self.family.name, labels, STALE_NAN, self.ts_ms,
+                       is_stale_marker=True) for labels in self.stale]
+
+    def samples(self) -> list[Sample]:
+        fam, ts = self.family, self.ts_ms
+        names = [fam.name + suffix for suffix, _ in self.kinds]
+        les = [() if le is None else (("le", le),) for _, le in self.kinds]
+        out: list[Sample] = []
+        for slot, vals, ex, carries in zip(
+                self.slots.tolist(), self.values.tolist(), self.exemplars,
+                self.carries.tolist()):
+            base = fam.labels_of(slot)
+            out.extend(
+                Sample(name, base + le, v, ts, exemplar=ex if c else None)
+                for name, le, v, c in zip(names, les, vals, carries))
+        return out + self.stale_samples()
+
+
 class _MetricBase:
     def __init__(self, registry: "ManagedRegistry", name: str,
                  label_names: Sequence[str], capacity: int):
@@ -62,6 +102,9 @@ class _MetricBase:
         self.exemplars: dict[int, Exemplar] = {}  # slot -> last exemplar
         self._stale_pending: list[tuple[tuple[tuple[str, str], ...], float]] = []
         self._ex_cursor = 0   # rotating exemplar-sampling window offset
+        # the remote-write encoder's per-slot label blocks (it creates
+        # and fills the store); this side only forgets evicted slots
+        self.label_blocks = None
         # processor-owned sidecar planes keyed to this family's slots
         # (the spanmetrics DDSketch) register here so the staleness purge
         # zeroes THEIR rows too — slot reuse must not inherit another
@@ -118,12 +161,31 @@ class _MetricBase:
         for slot in slots.tolist():
             self._stale_pending.append((self.labels_of(slot), self.registry.now()))
             self.exemplars.pop(slot, None)
+        if self.label_blocks is not None:
+            self.label_blocks.drop(slots)
 
-    def _drain_stale_markers(self, ts_ms: int) -> list[Sample]:
-        out = [Sample(self.name, labels, STALE_NAN, ts_ms, is_stale_marker=True)
-               for labels, _ in self._stale_pending]
+    def _columns(self, ts_ms: int, slots: np.ndarray, kinds: tuple,
+                 values: Sequence[np.ndarray], exemplars: list | None = None,
+                 carries: np.ndarray | None = None) -> FamilyColumns:
+        """`values`: [S] and [S, n] arrays, a column a kind between them.
+        Drains the pending markers."""
+        shape = (len(slots), len(kinds))
+        stale = [labels for labels, _ in self._stale_pending]
         self._stale_pending = []
-        return out
+        return FamilyColumns(
+            self, ts_ms, slots, kinds,
+            np.column_stack(values).astype(np.float64).reshape(shape),
+            [None] * len(slots) if exemplars is None else exemplars,
+            np.zeros(shape, bool) if carries is None else carries, stale)
+
+    def _slot_exemplars(self, slots: np.ndarray) -> tuple[list, np.ndarray]:
+        """([S] Exemplar or None, [S] bool has one). Pushes write the dict
+        meanwhile, so it is read a key at a time and never iterated."""
+        exemplars = [self.exemplars.get(s) for s in slots.tolist()]
+        return exemplars, np.array([e is not None for e in exemplars], bool)
+
+    def collect(self, ts_ms: int, snap: tuple | None = None) -> list[Sample]:
+        return self.columns(ts_ms, snap).samples()
 
     def share_table(self, other: "_MetricBase") -> None:
         """Adopt `other`'s series table so the families stay slot-aligned
@@ -178,12 +240,12 @@ class Counter(_MetricBase):
     def _snap(self) -> tuple:
         return (np.asarray(self.state.values),)
 
-    def collect(self, ts_ms: int, snap: tuple | None = None) -> list[Sample]:
+    def columns(self, ts_ms: int, snap: tuple | None = None) -> FamilyColumns:
         (vals,) = snap if snap is not None else self._snap()
-        out = [Sample(self.name, self.labels_of(s), float(vals[s]), ts_ms,
-                      exemplar=self.exemplars.get(s))
-               for s in self.table.active_slots().tolist()]
-        return out + self._drain_stale_markers(ts_ms)
+        slots = self.table.active_slots()
+        exemplars, has = self._slot_exemplars(slots)
+        return self._columns(ts_ms, slots, (("", None),), [vals[slots]],
+                             exemplars, has[:, None])
 
 
 class Gauge(_MetricBase):
@@ -224,11 +286,10 @@ class Gauge(_MetricBase):
     def _snap(self) -> tuple:
         return (np.asarray(self.state.values),)
 
-    def collect(self, ts_ms: int, snap: tuple | None = None) -> list[Sample]:
+    def columns(self, ts_ms: int, snap: tuple | None = None) -> FamilyColumns:
         (vals,) = snap if snap is not None else self._snap()
-        out = [Sample(self.name, self.labels_of(s), float(vals[s]), ts_ms)
-               for s in self.table.active_slots().tolist()]
-        return out + self._drain_stale_markers(ts_ms)
+        slots = self.table.active_slots()
+        return self._columns(ts_ms, slots, (("", None),), [vals[slots]])
 
 
 class Histogram(_MetricBase):
@@ -262,23 +323,26 @@ class Histogram(_MetricBase):
         return (np.asarray(self.state.bucket_counts),
                 np.asarray(self.state.sums), np.asarray(self.state.counts))
 
-    def collect(self, ts_ms: int, snap: tuple | None = None) -> list[Sample]:
+    def columns(self, ts_ms: int, snap: tuple | None = None) -> FamilyColumns:
         bc, sums, counts = snap if snap is not None else self._snap()
-        out: list[Sample] = []
+        slots = self.table.active_slots()
         edges = self.hist_edges()
-        for s in self.table.active_slots().tolist():
-            base = self.labels_of(s)
-            ex = self.exemplars.get(s)
-            cum = np.cumsum(bc[s])
-            out.append(Sample(self.name + "_count", base, float(counts[s]), ts_ms))
-            out.append(Sample(self.name + "_sum", base, float(sums[s]), ts_ms))
-            for i, e in enumerate(edges):
-                le = (("le", _fmt_le(e)),)
-                out.append(Sample(self.name + "_bucket", base + le, float(cum[i]),
-                                  ts_ms, exemplar=ex if ex and ex.value <= e else None))
-            out.append(Sample(self.name + "_bucket", base + (("le", "+Inf"),),
-                              float(cum[-1]), ts_ms, exemplar=ex))
-        return out + self._drain_stale_markers(ts_ms)
+        cum = np.cumsum(bc[slots], axis=1)
+        kinds = (("_count", None), ("_sum", None),
+                 *(("_bucket", _fmt_le(e)) for e in edges),
+                 ("_bucket", "+Inf"))
+        exemplars, has = self._slot_exemplars(slots)
+        # a bucket carries the slot's exemplar where its value fits under
+        # the edge, `+Inf` always, `_count` and `_sum` never
+        carries = np.zeros((len(slots), len(kinds)), bool)
+        ex_vals = np.array([e.value if e is not None else np.nan
+                            for e in exemplars], np.float64)
+        carries[:, 2:-1] = ex_vals[:, None] <= np.asarray(edges, np.float64)
+        carries[:, -1] = has
+        return self._columns(
+            ts_ms, slots, kinds,
+            [counts[slots], sums[slots], cum[:, :len(edges)], cum[:, -1]],
+            exemplars, carries)
 
 
 class NativeHistogram(_MetricBase):
@@ -303,16 +367,13 @@ class NativeHistogram(_MetricBase):
     def _snap(self) -> tuple:
         return (np.asarray(self.state.sums), np.asarray(self.state.counts))
 
-    def collect(self, ts_ms: int, snap: tuple | None = None) -> list[Sample]:
+    def columns(self, ts_ms: int, snap: tuple | None = None) -> FamilyColumns:
         # Scalar samples for visibility; the remote-write encoder additionally
         # reads `native_payload()` for real native-histogram protos.
         sums, counts = snap if snap is not None else self._snap()
-        out = []
-        for s in self.table.active_slots().tolist():
-            base = self.labels_of(s)
-            out.append(Sample(self.name + "_count", base, float(counts[s]), ts_ms))
-            out.append(Sample(self.name + "_sum", base, float(sums[s]), ts_ms))
-        return out + self._drain_stale_markers(ts_ms)
+        slots = self.table.active_slots()
+        return self._columns(ts_ms, slots, (("_count", None), ("_sum", None)),
+                             [counts[slots], sums[slots]])
 
     def hist_offset(self) -> int:
         return self.state.hist.offset
@@ -426,23 +487,25 @@ class ManagedRegistry:
     def discarded_series(self) -> int:
         return sum(mt.table.discarded for mt in self._metrics.values())
 
-    def collect(self, ts_ms: int | None = None) -> list[Sample]:
+    def collect_columns(self, ts_ms: int | None = None) -> list[FamilyColumns]:
         """The collection tick (`registry.go:206-256`): one synchronized
-        timestamp across all families, device state gathered once each."""
+        timestamp across all families, device state gathered once each,
+        handed on in columns (what the remote write encodes from)."""
         if self.overrides.disable_collection:
             return []
         ts = int(self.now() * 1000) if ts_ms is None else ts_ms
         # ONLY the device snapshots sit under the lock (they are what a
-        # donating push would invalidate); the per-sample formatting —
-        # the bulk of the tick at high cardinality — runs outside so
-        # ingest never stalls behind it
+        # donating push would invalidate); cutting them to the active
+        # slots runs outside so ingest never stalls behind it
         with tracing.span("registry.gather"), self.state_lock:
             snaps = [(mt, mt._snap()) for mt in self._metrics.values()]
-        out: list[Sample] = []
         with tracing.span("registry.format"):
-            for mt, snap in snaps:
-                out.extend(mt.collect(ts, snap))
-        return out
+            return [mt.columns(ts, snap) for mt, snap in snaps]
+
+    def collect(self, ts_ms: int | None = None) -> list[Sample]:
+        """The tick as one `Sample` a series, built from the columns."""
+        return [s for cols in self.collect_columns(ts_ms)
+                for s in cols.samples()]
 
     def purge_stale(self) -> int:
         """Evict idle series and zero their device rows; returns eviction

@@ -293,10 +293,18 @@ def test_rate_limiter_eviction_is_lossless():
 # -- satellite: remote-write retry behavior ---------------------------------
 
 
+def _one_series():
+    """A collection tick of one series: what `send` takes."""
+    from tempo_tpu.registry import ManagedRegistry
+
+    reg = ManagedRegistry("t")
+    reg.new_counter("m", ("a",)).inc(("b",))
+    return reg.collect_columns(0)
+
+
 def test_remote_write_honors_retry_after(faulty_remote_write):
     from tempo_tpu.generator.remote_write import (RemoteWriteClient,
                                                   RemoteWriteConfig)
-    from tempo_tpu.registry.series import Sample
 
     srv = faulty_remote_write
     srv.script.append((429, {"Retry-After": "0.05"}))
@@ -304,7 +312,7 @@ def test_remote_write_honors_retry_after(faulty_remote_write):
                                             backoff_s=0.01))
     sleeps: list[float] = []
     c._sleep = sleeps.append
-    ok = c.send([Sample(name="m", labels=(("a", "b"),), value=1.0, ts_ms=0)])
+    ok = c.send(_one_series())
     assert ok
     assert len(srv.requests) == 2
     assert c.retried_sends == 1 and c.failed_sends == 0
@@ -319,7 +327,6 @@ def test_remote_write_full_jitter_backoff(faulty_remote_write):
 
     from tempo_tpu.generator.remote_write import (RemoteWriteClient,
                                                   RemoteWriteConfig)
-    from tempo_tpu.registry.series import Sample
 
     srv = faulty_remote_write
     for _ in range(3):
@@ -329,7 +336,7 @@ def test_remote_write_full_jitter_backoff(faulty_remote_write):
     c._rng = random.Random(42)
     sleeps: list[float] = []
     c._sleep = sleeps.append
-    ok = c.send([Sample(name="m", labels=(("a", "b"),), value=1.0, ts_ms=0)])
+    ok = c.send(_one_series())
     assert ok and len(sleeps) == 3
     for i, s in enumerate(sleeps):
         assert 0.0 <= s <= 0.5 * (2 ** i)
@@ -339,14 +346,13 @@ def test_remote_write_full_jitter_backoff(faulty_remote_write):
 def test_remote_write_non_retryable_4xx_fails_fast(faulty_remote_write):
     from tempo_tpu.generator.remote_write import (RemoteWriteClient,
                                                   RemoteWriteConfig)
-    from tempo_tpu.registry.series import Sample
 
     srv = faulty_remote_write
     srv.script.append((400, {}))
     c = RemoteWriteClient(RemoteWriteConfig(url=srv.url, retries=3,
                                             backoff_s=0.01))
     c._sleep = lambda s: None
-    ok = c.send([Sample(name="m", labels=(("a", "b"),), value=1.0, ts_ms=0)])
+    ok = c.send(_one_series())
     assert not ok
     assert len(srv.requests) == 1             # no retry on a client error
     assert c.failed_sends == 1 and c.retried_sends == 0
@@ -359,5 +365,6 @@ def test_remote_write_obs_families_register():
     text = RUNTIME.render()
     for fam in ("tempo_remote_write_retries_total",
                 "tempo_remote_write_sends_total",
+                "tempo_remote_write_series_encoded_total",
                 "tempo_remote_write_failed_sends_total"):
         assert fam in text
