@@ -24,7 +24,7 @@ collection surface, while `/metrics` answers for discards, dispatch
 errors, the sampler and the jit compile counters.
 
 Not exercised here (so silence about them is not a pass): compaction,
-the paged layout, the Pallas tier, matview, the ingest WAL, the fleet.
+the paged layout, matview, the ingest WAL, the fleet.
 """
 
 from __future__ import annotations
@@ -525,7 +525,6 @@ def phase_collect(app, port: int, loads: dict, sink: Sink, size: dict) -> dict:
             "spans": n, "series_active": len(series), "edges": pairs,
             "latency_sum_rel_err": abs(lat_sum - want_sum) / want_sum,
             "quantile_worst_rel_err_vs_rank": worst,
-            "kernel_tier": proc._kernel_tier,
             "layout": app.generator.instances[tenant].state_layout,
             "on_mesh": proc._mesh is not None,
         }

@@ -19,7 +19,7 @@ orchestration roles.
 
 Layer map (mirrors SURVEY.md §1 for the reference):
 
-    ops/        sketch + hash kernels (JAX/XLA/Pallas)       <- TPU compute
+    ops/        sketch + hash kernels (JAX/XLA)              <- TPU compute
     model/      wire model, SpanBatch span tensors, interning
     registry/   metric series state on device (counter/gauge/histogram)
     generator/  metrics-generator service + processors

@@ -276,32 +276,6 @@ class Config:
                 "generator.spanmetrics.sketch selects the moments tier "
                 "but enable_quantile_sketch is false: no sketch plane "
                 "will be built and quantile() answers will be empty")
-        if sm.kernel not in ("xla", "pallas"):
-            warnings.append(
-                f"generator.spanmetrics.kernel {sm.kernel!r} unknown: use "
-                "'xla' (composed scatter, lowers everywhere) or 'pallas' "
-                "(single-pass ragged-page kernel; paged layout + TPU "
-                "backend) — serve time falls back to 'xla'")
-        if sm.kernel == "pallas" and not self.pages.enabled:
-            # warn, don't fail: the kernel falls back per-process with
-            # a single warning — the fallback contract tier-1 enforces
-            warnings.append(
-                "generator.spanmetrics.kernel 'pallas' needs the paged "
-                "layout (pages.enabled: true): the kernel IS the "
-                "page-table walker — serve time falls back to 'xla'; "
-                "non-TPU backends also fall back unless "
-                "pallas_interpret (debug parity only) is set")
-        if sm.pallas_interpret:
-            warnings.append(
-                "generator.spanmetrics.pallas_interpret is a debug/CI "
-                "knob: the Pallas interpreter is orders of magnitude "
-                "slower than XLA — never set it in production")
-        if sm.compact_state and not self.pages.enabled:
-            warnings.append(
-                "generator.spanmetrics.compact_state needs the paged "
-                "layout (pages.enabled: true) — serve time stays on f32 "
-                "state; see runbook 'Choosing the update kernel' for the "
-                "tier's documented tolerances")
         ta = self.generator.traceanalytics
         if ta.trace_idle_s <= 0:
             warnings.append(

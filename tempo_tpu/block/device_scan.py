@@ -1367,7 +1367,7 @@ class BlockScanPlane:
         return GridHandle(glabels, packed, main_shape,
                           (n_groups, n_steps)), None
 
-    # -- back-compat wrapper (bench/tests from round 3) ---------------------
+    # -- back-compat wrapper (tests from round 3) ---------------------------
 
     def query_range_grid(self, preds: Sequence, all_conditions: bool,
                          group: str | None, start_ns: int, end_ns: int,

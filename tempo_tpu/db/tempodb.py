@@ -76,8 +76,8 @@ class TempoDB:
         # read-plane routing counters: how many block scans took the fused
         # device path vs the host engine (tests + /metrics)
         self.plane_stats = {"fused_metric_blocks": 0, "host_metric_blocks": 0}
-        # device cold tier: compaction + sidecar-fold counters (tests,
-        # /metrics, and the bench `coldtier` stage all read these)
+        # device cold tier: compaction + sidecar-fold counters (tests
+        # and /metrics read these)
         self.compaction_stats = {
             "blocks": 0,             # input blocks through the device route
             "spans": 0,              # spans merged/deduped on device

@@ -59,7 +59,7 @@ class CheckpointMismatch(ValueError):
 # fingerprint: the config surface a checkpoint's state layout depends on
 # ---------------------------------------------------------------------------
 
-def overrides_fingerprint(inst) -> str:
+def overrides_fingerprint(inst, *, _compact_state: bool = False) -> str:
     """Stable digest of everything that shapes this tenant's series/plane
     layout. A checkpoint cut under different overrides (capacity, label
     dimensions, histogram edges, sketch tier/params) must not merge."""
@@ -81,9 +81,12 @@ def overrides_fingerprint(inst) -> str:
             "sketch_max_series": int(sm.sketch_max_series),
             "moments_k": int(sm.moments_k),
             "enable_target_info": bool(sm.enable_target_info),
-            # the compact tier changes plane DTYPES (int32 grids, bf16
-            # Kahan sums): cross-compact merges would silently truncate
-            "compact_state": bool(sm.compact_state),
+            # the compact-state tier (int32 grids, a bf16 sum pair) is
+            # gone; its key stays in the digest, because every checkpoint
+            # cut before carries it and those cut with `false` must keep
+            # restoring. `true` is digested only by `restore_instance`,
+            # to name the removed tier when it refuses such a checkpoint
+            "compact_state": _compact_state,
         },
     }
     if "trace-analytics" in inst.processors:
@@ -135,26 +138,14 @@ def _pad_slots(slots: np.ndarray) -> np.ndarray:
     return padded
 
 
-def _gather_paged(plane, slots: np.ndarray) -> np.ndarray:
-    got = plane.gather(_pad_slots(slots))[:slots.size]
-    return np.asarray(got).astype(np.float32) \
-        if got.dtype not in (np.float32, np.int32) else np.asarray(got)
-
-
 def _family_rows(mt, slots: np.ndarray) -> dict[str, np.ndarray]:
     """{role: [n(, width)] host rows} for the active slots. Caller holds
     the registry state lock (paged gathers ride shared donated arenas)."""
     kind = _family_kind(mt)
     if hasattr(mt, "planes"):            # paged family
-        out = {}
-        for role in _KIND_ROLES[kind]:
-            rows = _gather_paged(mt.planes[role], slots)
-            if kind == "histogram" and role == "sums" and rows.ndim == 2:
-                # compact tier: bf16 Kahan pair folds at the boundary,
-                # exactly like the collect snapshot
-                rows = (rows[:, 0] + rows[:, 1]).astype(np.float32)
-            out[role] = rows
-        return out
+        padded = _pad_slots(slots)
+        return {role: np.asarray(mt.planes[role].gather(padded)[:slots.size])
+                for role in _KIND_ROLES[kind]}
     st = mt.state
     if kind == "counter" or kind == "gauge":
         return {"values": np.asarray(st.values)[slots]}
@@ -204,18 +195,7 @@ def _family_restore(mt, slots: np.ndarray, rows: dict[str, np.ndarray]
     kind = _family_kind(mt)
     if hasattr(mt, "planes"):            # paged family
         for role in _KIND_ROLES[kind]:
-            vals = rows[role]
-            plane = mt.planes[role]
-            if kind == "histogram" and role == "sums" and plane.width == 2:
-                # compact pair plane: merge into the primary column (the
-                # compensation restarts at 0 — within the documented
-                # compact-tier tolerance)
-                pair = np.zeros((len(vals), 2), np.float32)
-                pair[:, 0] = vals
-                vals = pair
-            if kind == "counter" and getattr(mt, "compact", False):
-                vals = np.round(vals)
-            _plane_scatter(plane, slots, vals,
+            _plane_scatter(mt.planes[role], slots, rows[role],
                            op="set" if kind == "gauge" else "add")
         return
     st = mt.state
@@ -377,6 +357,13 @@ def restore_instance(inst, blob: bytes) -> dict:
     reg = inst.registry
     want_fp = overrides_fingerprint(inst)
     if meta.get("fingerprint") != want_fp:
+        if meta.get("fingerprint") == overrides_fingerprint(
+                inst, _compact_state=True):
+            raise CheckpointMismatch(
+                "checkpoint was cut under generator.spanmetrics."
+                "compact_state: true, a state tier that was removed: "
+                "its int32 grids and bf16 sum pairs do not merge into "
+                "f32 planes")
         raise CheckpointMismatch(
             f"overrides fingerprint {meta.get('fingerprint')} does not "
             f"match this instance's {want_fp} (tenant config changed "

@@ -93,8 +93,7 @@ def _announce_ready(port: int) -> None:
 
 
 # ---------------------------------------------------------------------------
-# parent-side spawn/reap (bench.py and the test harness share these — the
-# worker lifecycle must not drift between two copies)
+# parent-side spawn/reap (the test harness's `fleet_procs` fixture)
 # ---------------------------------------------------------------------------
 
 def _discard_pipe(pipe) -> None:

@@ -121,8 +121,6 @@ class Generator:
                     sm_patch["sketch"] = lim.generator.sketch
                 if lim.generator.sketch_moments_k:
                     sm_patch["moments_k"] = lim.generator.sketch_moments_k
-                if lim.generator.kernel:
-                    sm_patch["kernel"] = lim.generator.kernel
                 if sm_patch:
                     cfg.spanmetrics = dataclasses.replace(
                         cfg.spanmetrics, **sm_patch)

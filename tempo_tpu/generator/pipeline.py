@@ -153,11 +153,6 @@ class IngestPipeline:
             self._reap_locked()
             return len(self._inflight)
 
-    def overlap_ratio(self) -> float:
-        """Share of host staging wall spent while a device dispatch was
-        in flight — 0 is fully serialized, →1 is fully overlapped."""
-        return self.overlap_ns / self.decode_ns if self.decode_ns else 0.0
-
 
 # ---------------------------------------------------------------------------
 # obs: pipeline families in the process-wide runtime registry

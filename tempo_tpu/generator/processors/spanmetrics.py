@@ -64,31 +64,6 @@ class SpanMetricsConfig:
     # the overrides `generator.sketch` knob.
     sketch: str = "dd"
     moments_k: int = 12                       # moment count (2..16)
-    # update-kernel tier (runbook "Choosing the update kernel"): "xla"
-    # is the composed-scatter fused step — one scatter per plane role,
-    # lowers on every backend, the production default and the
-    # interpreter-mode/CPU fallback; "pallas" is the single-pass
-    # ragged-page kernel (ops/pallas_kernels.py) — one page-table walk
-    # updates the whole plane family. Needs the paged layout
-    # (`pages.enabled`) and a TPU backend; anything else falls back to
-    # "xla" with one warning. Per-tenant via the overrides
-    # `generator.kernel` knob.
-    kernel: str = "xla"
-    # debug/CI only: run the pallas tier in Pallas interpreter mode on
-    # non-TPU backends instead of falling back — orders of magnitude
-    # slower than XLA, exists purely for parity gates (the plane-fuzz
-    # differential arm and the bench interpret-parity check)
-    pallas_interpret: bool = False
-    # compact-state tolerance tier (paged layout only): calls/latency
-    # counts and the histogram/DDSketch bucket grids store as int32
-    # (per-dispatch deltas rounded to nearest — exact for unit/integer
-    # HT weights, ≤0.5 absolute per touched cell otherwise) and the
-    # latency sum stores as a [2]-wide bf16 Kahan pair (~1% relative
-    # tolerance; the pallas tier maintains the compensation column).
-    # Counts stay integer-exact to 2^31 where the f32 default degrades
-    # past 2^24. The default f32 tier stays bit-identical; tolerances
-    # are documented in the runbook and gated in bench + plane fuzz.
-    compact_state: bool = False
     sketch_rel_err: float = 0.01              # DDSketch relative-error budget
     sketch_min_s: float = 1e-6                # 1µs .. ~28h latency range
     sketch_max_s: float = 1e5
@@ -181,6 +156,11 @@ _fused_update_packed4 = instrumented_jit(
     _fused_update_packed4_impl, name="spanmetrics_fused_update",
     donate_argnums=(0, 1, 2, 3, 4))
 
+# the label every layout's batches carry in the scheduler's families
+# (`tempo_sched_*{kernel=...}`): dashboards and the mesh cell's judge
+# count batches under exactly this value
+_SCHED_KERNEL = "spanmetrics_fused_update"
+
 
 class SpanMetricsProcessor:
     def __init__(self, registry: ManagedRegistry, config: SpanMetricsConfig | None = None):
@@ -190,50 +170,14 @@ class SpanMetricsProcessor:
             _sanitize(d) for d in self.cfg.dimensions]
         self._labels = tuple(dims)
         cap = registry.overrides.max_active_series
-        # update-kernel tier: the requested name is validated once, then
-        # resolved BEFORE family creation (the compact-state decision
-        # below depends on it, and the arenas need their dtypes picked)
-        # against the pool-level layout guess; re-resolved after family
-        # creation once the tenant's ACTUAL layout is known. Per-call
-        # fallback is the resolve itself: an unlowerable request warns
-        # once process-wide and every dispatch rides xla.
-        self._kernel_req = self.cfg.kernel
-        if self._kernel_req not in ("xla", "pallas"):
-            _TIER_LOG.warning(
-                "spanmetrics %s: unknown kernel tier %r (use xla | "
-                "pallas) — falling back to xla", registry.tenant,
-                self._kernel_req)
-            self._kernel_req = "xla"
-        paged_pre = registry.pages is not None
-        self._resolve_tier(
-            paged=paged_pre,
-            mesh_active=paged_pre and registry.pages.mesh is not None)
-        # compact-state tier is a property of the PAGED planes, decided
-        # before family creation so the arenas get the right dtypes —
-        # and it REQUIRES the resolved pallas tier: only that kernel
-        # maintains the bf16 Kahan pair and rounds per-dispatch page
-        # deltas, so the documented tolerances hold. The composed-scatter
-        # fallback would accumulate sums in plain bf16 (unbounded
-        # relative error once a sum outgrows ~256x a delta) and round
-        # weights per row — silently worse than documented.
-        compact = bool(self.cfg.compact_state)
-        if compact and self._kernel_tier != "pallas":
-            _TIER_LOG.warning(
-                "spanmetrics %s: compact_state requires the pallas "
-                "kernel tier (resolved tier here: %s) — staying on f32 "
-                "state so the documented tolerances hold",
-                registry.tenant, self._kernel_tier)
-            compact = False
         self.calls = registry.new_counter("traces_spanmetrics_calls_total",
-                                          self._labels, compact=compact)
+                                          self._labels)
         self.latency = registry.new_histogram(
             "traces_spanmetrics_latency", self._labels,
-            edges=self.cfg.histogram_buckets, compact=compact)
+            edges=self.cfg.histogram_buckets)
         # size/ latency share the calls table so all three stay slot-aligned
         # (paged mode: the shared table's backing adopts their planes too).
         self.latency.share_table(self.calls)
-        # sizes stay f32 in the compact tier: byte sums overflow int32 at
-        # 2GB/series and are integer-valued anyway
         self.sizes = registry.new_counter("traces_spanmetrics_size_total", self._labels)
         self.sizes.share_table(self.calls)
         # paged layout (registry/pages.py): families above came back
@@ -241,20 +185,6 @@ class SpanMetricsProcessor:
         self._pool = registry.pages
         self._paged = self._pool is not None and \
             hasattr(self.calls, "planes")
-        if compact and not self._paged:
-            # the pool exists but this tenant stayed dense
-            # (capacity-indivisible): dense families ignored the flag
-            _TIER_LOG.warning(
-                "spanmetrics %s: compact_state ignored — tenant fell "
-                "back to the dense layout", registry.tenant)
-            compact = False
-        self._compact = compact
-        # re-resolve the kernel tier now that the tenant's actual layout
-        # is known (a capacity-indivisible tenant fell back to dense
-        # above even though the pool exists)
-        self._resolve_tier(
-            paged=self._paged,
-            mesh_active=self._paged and self._pool.mesh is not None)
         self._pdd = None
         self._pmom = None
         self._paged_steps: dict[bool, object] = {}
@@ -296,11 +226,10 @@ class SpanMetricsProcessor:
                 gamma, nb = sketches.dd_params(self.cfg.sketch_rel_err,
                                                self.cfg.sketch_min_s,
                                                self.cfg.sketch_max_s)
-                dd_dt = "int32" if self._compact else "float32"
-                ddc = PagedPlane(self._pool, dd_dt, nb, plane_rows,
+                ddc = PagedPlane(self._pool, "float32", nb, plane_rows,
                                  registry.tenant,
                                  role="traces_spanmetrics_latency/ddsketch")
-                ddz = PagedPlane(self._pool, dd_dt, 1, plane_rows,
+                ddz = PagedPlane(self._pool, "float32", 1, plane_rows,
                                  registry.tenant,
                                  role="traces_spanmetrics_latency/ddzeros")
                 self.calls.table.backing.add_plane(ddc, dd_rows)
@@ -351,19 +280,6 @@ class SpanMetricsProcessor:
         # through the single shard_map dispatch
         self._mesh = None
         self._mesh_checked = False
-
-    def _resolve_tier(self, *, paged: bool, mesh_active: bool) -> None:
-        """Resolve the update-kernel tier for the given layout and pick
-        the ledger/coalescer kernel name — distinct per tier so the
-        devtime cost model learns separate (kernel, bucket) coefficients
-        and the WindowTuner never mixes the two regimes' dispatch costs."""
-        from tempo_tpu.ops import pages as _oppages
-        self._kernel_tier = _oppages.resolve_kernel(
-            self._kernel_req, interpret=self.cfg.pallas_interpret,
-            mesh_active=mesh_active, paged=paged)
-        self._sched_kernel = ("spanmetrics_fused_update_pallas"
-                              if self._kernel_tier == "pallas"
-                              else "spanmetrics_fused_update")
 
     def name(self) -> str:
         return "span-metrics"
@@ -619,10 +535,7 @@ class SpanMetricsProcessor:
             pool.page_shift, packed,
             mesh_key=mesh_key, mesh=jmesh,
             series_shards=1 if mesh is None else mesh.series_shards,
-            mom_rows=mom_rows, mom_meta=mom_meta,
-            kernel=self._kernel_tier,
-            interpret=self.cfg.pallas_interpret,
-            compact=self._compact)
+            mom_rows=mom_rows, mom_meta=mom_meta)
 
     def _paged_update(self, slots, dur_s, sizes, weights) -> None:
         """One paged fused update: gather each row's physical page
@@ -710,7 +623,7 @@ class SpanMetricsProcessor:
                   np.asarray(sizes, np.float32),
                   np.asarray(weights, np.float32))
         return sc.submit_rows(
-            self._sched_kernel, self, arrays, len(slots), dispatch,
+            _SCHED_KERNEL, self, arrays, len(slots), dispatch,
             pads=(-1.0, 0.0, 0.0, 0.0) if packed else (-1, 0.0, 0.0, 0.0),
             tenant=self.registry.tenant, pack=packed,
             align=sm.data_shards if sm is not None else 1,
@@ -1261,8 +1174,6 @@ class SpanMetricsProcessor:
                     padded = np.full(_pad_len(idx.size), -1, np.int32)
                     padded[:idx.size] = slots[idx]
                     cg, zg = ddc.gather_dev(padded), ddz.gather_dev(padded)
-                    if self._compact:
-                        cg, zg = cg.astype("float32"), zg.astype("float32")
                     dd = sketches.DDSketch(cg, zg, gamma, minv)
                     vals[idx] = np.asarray(
                         sketches.dd_quantile(dd, q))[:idx.size]
@@ -1313,10 +1224,6 @@ class SpanMetricsProcessor:
             padded[:slots.size] = slots
             counts = ddc.gather_dev(padded)
             zeros = ddz.gather_dev(padded)
-            if self._compact:
-                # int32 grid upcasts at the read boundary (exact)
-                counts = counts.astype("float32")
-                zeros = zeros.astype("float32")
             vals = np.asarray(sketches.dd_quantile(
                 sketches.DDSketch(counts, zeros, gamma, minv), q))
         return {self.calls.labels_of(int(s)): float(vals[i])

@@ -246,7 +246,7 @@ class TraceAnalyticsProcessor:
         # run boundaries in ARRIVAL order: exporters emit a trace's spans
         # contiguously, so on the common path the runs already are the
         # per-trace groups and the stable sort + column gathers below are
-        # skipped entirely (the ingest-path cost the bench gate guards)
+        # skipped entirely
         bnd = np.flatnonzero(
             np.concatenate([[True], keys[1:] != keys[:-1], [True]]))
         run_keys = keys[bnd[:-1]]

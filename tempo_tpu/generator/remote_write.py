@@ -198,43 +198,38 @@ class LabelBlocks:
 
     def __init__(self, key: tuple):
         self.key = key
-        self._blocks: dict[int, tuple[bytes, bytes]] = {}
-        # an eviction between a build's read of the slot's labels and its
-        # store would hand the slot's next series the old block
-        self._lock = threading.Lock()
-        self._drops = 0
+        # slot -> (the slot's label-id row the block was built from,
+        # before, after). An eviction can fall anywhere between a tick's
+        # read of a slot's labels and its store, and the slot's next
+        # series must not inherit the block: a kept block counts only
+        # while the slot still holds the row it was built from
+        self._blocks: dict[int, tuple[bytes, bytes, bytes]] = {}
 
     def drop(self, slots: np.ndarray) -> None:
-        with self._lock:
-            self._drops += 1
-            for slot in slots.tolist():
-                self._blocks.pop(slot, None)
+        for slot in slots.tolist():
+            self._blocks.pop(slot, None)
 
     def get(self, fam, slots: np.ndarray) -> tuple[list, int]:
         """([S] (before, after) blocks, how many had to be built)."""
         slot_list = slots.tolist()
-        with self._lock:
-            drops = self._drops
-            got = [self._blocks.get(s) for s in slot_list]
-        miss = [i for i, b in enumerate(got) if b is None]
+        keys = fam.table.slot_keys[slots]
+        rows = [key.tobytes() for key in keys]
+        got = [self._blocks.get(s) for s in slot_list]
+        miss = [i for i, (block, row) in enumerate(zip(got, rows))
+                if block is None or block[0] != row]
         if miss:
-            built = _build_blocks(fam, slots[miss])
-            for i, block in zip(miss, built):
-                got[i] = block
-            with self._lock:
-                if drops == self._drops:
-                    self._blocks.update(
-                        (slot_list[i], got[i]) for i in miss)
-        return got, len(miss)
+            for i, block in zip(miss, _build_blocks(fam, keys[miss])):
+                got[i] = self._blocks[slot_list[i]] = (rows[i], *block)
+        return [block[1:] for block in got], len(miss)
 
 
-def _build_blocks(fam, slots: np.ndarray) -> list[tuple[bytes, bytes]]:
-    """`_MetricBase.labels_of`'s pairs for `slots`, encoded a label column
-    at a time: each distinct value of a column is encoded once."""
+def _build_blocks(fam, keys: np.ndarray) -> list[tuple[bytes, bytes]]:
+    """`_MetricBase.labels_of`'s pairs for slots holding the label-id rows
+    `keys`, encoded a label column at a time: each distinct value of a
+    column is encoded once."""
     fixed = {**fam.registry.overrides.external_labels, "__name__": fam.name}
     column = {name: j for j, name in enumerate(fam.label_names)}
-    keys = fam.table.slot_keys[slots]
-    before = after = _objects([b""] * len(slots))
+    before = after = _objects([b""] * len(keys))
     for name in sorted(column.keys() | fixed.keys()):
         if name in fixed:
             pairs = _objects([_enc_pair(name, fixed[name])])

@@ -12,8 +12,8 @@ dispatch records into, and the substrate two consumers build on:
   `/metrics`, `/status`, and — through `QueryStats.device_ns` — the
   qlog "query complete" line, so a read-cost investigation never needs
   a metrics join. The attribution invariant (tenant shares sum to the
-  batch wall, within float rounding) is what the bench soak stage gates
-  on.
+  batch wall, within float rounding) is held by
+  `tests/test_devtime.py::test_soak_smoke`.
 - **Prediction.** An online per-(kernel, bucket) **affine cost model**
   (cost ≈ a + b·rows) fit from the ledger stream with exponentially
   decayed least squares and winsorized residuals (one GC pause must not
@@ -349,8 +349,8 @@ class CostModel:
         """|predicted − observed-median| / observed-median for a warm
         pair — prediction accuracy against the TYPICAL dispatch cost
         (what the window tuner plans on), immune to the per-dispatch
-        GIL/scheduling jitter no shape model can predict. The bench
-        soak gates this ≤ 0.25 on warm pairs. None while cold."""
+        GIL/scheduling jitter no shape model can predict. None while
+        cold."""
         with self._lock:
             p = self._pairs.get((kernel, int(bucket)))
             if p is None or p.n < self.min_samples or p.med_y <= 0:

@@ -23,8 +23,8 @@ build):
   render time so hot paths that already keep plain dict counters pay
   ZERO extra cost per event — only new latency histograms touch the hot
   path, and those are one lock + one bisect per observation.
-- `Registry(enabled=False)` hands out no-op instruments: the bench
-  harness measures instrumentation overhead as (enabled - disabled).
+- `Registry(enabled=False)` hands out no-op instruments, so that
+  instrumentation overhead can be measured as (enabled - disabled).
 """
 
 from __future__ import annotations

@@ -23,8 +23,8 @@ byte-swapped on little-endian hosts — `ops/structure.py`'s
 `id_limbs` is native-endian and would rank ids wrongly here.
 
 `reference_merge_order` is the pure-Python oracle (explicit sorted()
-over byte keys + per-trace seen-set); the differential tests and the
-bench `coldtier` spot check diff the kernel against it row by row.
+over byte keys + per-trace seen-set); the differential tests diff the
+kernel against it row by row.
 
 The sidecar builder (`build_sidecar_arrays`) reuses the block-resident
 columns to produce the per-block mergeable summaries: a moments row

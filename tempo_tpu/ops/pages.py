@@ -26,7 +26,7 @@ paged-vs-dense differential arm).
 Every builder below memoizes its jitted step in a module-level cache
 keyed ONLY by static hyperparameters — page tables and arenas are plain
 operands, so two thousand tenants with the same config share one trace
-(the zero-steady-state-recompile gate in bench.py's pages stage).
+(`tests/test_pages.py::test_many_tenants_share_one_trace`).
 
 Host-side pool/plane management (allocation, eviction, refcounts) lives
 in `tempo_tpu.registry.pages`.
@@ -49,64 +49,6 @@ from jax import shard_map as _shard_map
 
 from tempo_tpu.obs.jaxruntime import instrumented_jit
 from tempo_tpu.ops import sketches
-
-
-# ---------------------------------------------------------------------------
-# kernel tier selection (spanmetrics.kernel: xla | pallas)
-# ---------------------------------------------------------------------------
-#
-# "xla" is the composed-scatter `_fused_body` below — one scatter per
-# plane role, each re-gathering the page table; it lowers everywhere and
-# is the interpreter-mode/CPU fallback. "pallas" is the single-pass
-# ragged-page kernel (`ops.pallas_kernels.paged_fused_update`): one
-# page-table walk per span block, all roles accumulated in VMEM, one
-# arena writeback per touched page. Mosaic only lowers on TPU, so
-# resolution is per-process: an unlowerable request falls back to "xla"
-# with ONE warning per distinct reason (the per-call fallback contract
-# tests/test_pallas_kernels.py enforces).
-
-import logging
-
-_KLOG = logging.getLogger("tempo_tpu.pages")
-_KERNEL_WARNED: set[str] = set()
-
-
-def _warn_once(reason: str) -> None:
-    if reason not in _KERNEL_WARNED:
-        _KERNEL_WARNED.add(reason)
-        _KLOG.warning("spanmetrics kernel tier 'pallas' unavailable: %s "
-                      "— falling back to the composed-scatter XLA path",
-                      reason)
-
-
-def reset_kernel_warnings() -> None:
-    """Test isolation: re-arm the warn-once fallback messages."""
-    _KERNEL_WARNED.clear()
-
-
-def resolve_kernel(requested: str, *, interpret: bool = False,
-                   mesh_active: bool = False, paged: bool = True) -> str:
-    """The kernel tier that will actually run for this process/tenant.
-
-    `interpret` (debug/CI only) lets CPU hosts run the Pallas kernel in
-    interpreter mode instead of falling back — the parity-gate switch,
-    never a production speedup."""
-    if requested != "pallas":
-        return "xla"
-    if not paged:
-        _warn_once("state is dense (the kernel IS the page-table walker; "
-                   "enable pages: to use it)")
-        return "xla"
-    if mesh_active:
-        _warn_once("serving-mesh arenas are sharded over 'series' and the "
-                   "pallas tier is single-device")
-        return "xla"
-    if not interpret and jax.default_backend() != "tpu":
-        _warn_once(f"backend {jax.default_backend()!r} cannot lower the "
-                   "Mosaic kernel (set spanmetrics.pallas_interpret for "
-                   "debug-parity runs)")
-        return "xla"
-    return "pallas"
 
 
 def translate(page_table: jax.Array, slots: jax.Array, page_shift: int,
@@ -191,14 +133,11 @@ def _add1(arena, table, slots, vals, page_shift):
     return arena.at[r].add(vals, mode="drop")
 
 
-def histogram_observe_step(edges: tuple, page_shift: int,
-                           compact: bool = False):
+def histogram_observe_step(edges: tuple, page_shift: int):
     """fn(a_sums, a_counts, ab[Rb,B+1], t_bucket, t_sums, t_counts,
     slots, values, weights) -> (a_sums, a_counts, ab) — classic
     histogram: bucket increments in the wide arena, sums/counts each in
-    their own width-1 role arena. `compact` expects int32 bucket/count
-    arenas and a [rows, 2] bf16 pair sums arena (primary column only on
-    this composed-scatter path)."""
+    their own width-1 role arena."""
     edges = tuple(edges)
 
     def build():
@@ -208,19 +147,13 @@ def histogram_observe_step(edges: tuple, page_shift: int,
             w = jnp.asarray(weights, jnp.float32)
             e = jnp.asarray(edges, jnp.float32)
             b = jnp.sum(v[:, None] > e[None, :], axis=1).astype(jnp.int32)
-            ab = _hist_scatter_stored(ab, t_bucket, slots, b, w, page_shift)
-            if compact:
-                r = translate(t_sums, slots, page_shift, a_sums.shape[0])
-                a_sums = a_sums.at[r, 0].add((v * w).astype(a_sums.dtype),
-                                             mode="drop")
-            else:
-                a_sums = _add1(a_sums, t_sums, slots, v * w, page_shift)
-            a_counts = _add1_stored(a_counts, t_counts, slots, w,
-                                    page_shift)
+            ab = _hist_scatter(ab, t_bucket, slots, b, w, page_shift)
+            a_sums = _add1(a_sums, t_sums, slots, v * w, page_shift)
+            a_counts = _add1(a_counts, t_counts, slots, w, page_shift)
             return a_sums, a_counts, ab
         return instrumented_jit(step, name="paged_histogram_update",
                                 donate_argnums=(0, 1, 2))
-    return _cached(("hist", edges, page_shift, compact), build)
+    return _cached(("hist", edges, page_shift), build)
 
 
 def native_hist_step(offset: int, page_shift: int):
@@ -391,42 +324,16 @@ def _moments_scatter(am, table, slots, dur_s, w, mom_meta: tuple,
     return am
 
 
-def _add1_stored(arena, table, slots, vals, page_shift):
-    """`_add1` under the arena's storage rule: int32 count arenas take
-    the per-row contribution rounded to nearest (the compact tier —
-    exact for unit/integer HT weights, ≤0.5 absolute per row
-    otherwise), f32 arenas take it as-is."""
-    if arena.dtype == jnp.int32:
-        vals = jnp.round(vals).astype(jnp.int32)
-    r = translate(table, slots, page_shift, arena.shape[0])
-    return arena.at[r].add(vals, mode="drop")
-
-
-def _hist_scatter_stored(arena2d, table, slots, buckets, w, page_shift):
-    if arena2d.dtype == jnp.int32:
-        w = jnp.round(w).astype(jnp.int32)
-    r = translate(table, slots, page_shift, arena2d.shape[0])
-    return arena2d.at[r, buckets].add(w, mode="drop")
-
-
 def _fused_body(arenas, tables, slots, dur_s, sizes, weights,
                 edges: tuple, gamma: float, min_value: float,
                 dd_rows: int, page_shift: int, mom_rows: int = 0,
-                mom_meta: "tuple | None" = None, compact: bool = False):
+                mom_meta: "tuple | None" = None):
     """One paged device step for all spanmetrics families. `arenas` /
     `tables` are role-aligned: (calls, hist_sums, hist_counts, sizes,
     hist_buckets[, dd_zeros, dd_counts][, moments]) — each plane
     scatters into its OWN role arena through its own indirection
     table. The dd / moments sidecars are tier-gated (either, both, or
-    neither may be present).
-
-    `compact` (the int32/bf16-pair state tier): count/bucket arenas are
-    int32 — per-row contributions round to nearest — and the latency sum
-    arena is a [rows, 2] bf16 Kahan pair; this composed-scatter path can
-    only feed its primary column (scatter-add cannot carry per-cell
-    compensation), so compact sums accumulate in plain bf16 here while
-    the Pallas tier maintains the pair. Both stay inside the documented
-    tolerance (runbook "Choosing the update kernel")."""
+    neither may be present)."""
     dd = bool(dd_rows)
     mom = bool(mom_rows)
     a_calls, a_hs, a_hc, a_sz, ab = arenas[:5]
@@ -438,17 +345,13 @@ def _fused_body(arenas, tables, slots, dur_s, sizes, weights,
         am, t_mom = arenas[-1], tables[-1]
     w = jnp.asarray(weights, jnp.float32)
     v = jnp.asarray(dur_s, jnp.float32)
-    a_calls = _add1_stored(a_calls, t_calls, slots, w, page_shift)
+    a_calls = _add1(a_calls, t_calls, slots, w, page_shift)
     # latency histogram
     e = jnp.asarray(edges, jnp.float32)
     b = jnp.sum(v[:, None] > e[None, :], axis=1).astype(jnp.int32)
-    ab = _hist_scatter_stored(ab, t_hb, slots, b, w, page_shift)
-    if compact:
-        r = translate(t_hs, slots, page_shift, a_hs.shape[0])
-        a_hs = a_hs.at[r, 0].add((v * w).astype(a_hs.dtype), mode="drop")
-    else:
-        a_hs = _add1(a_hs, t_hs, slots, v * w, page_shift)
-    a_hc = _add1_stored(a_hc, t_hc, slots, w, page_shift)
+    ab = _hist_scatter(ab, t_hb, slots, b, w, page_shift)
+    a_hs = _add1(a_hs, t_hs, slots, v * w, page_shift)
+    a_hc = _add1(a_hc, t_hc, slots, w, page_shift)
     a_sz = _add1(a_sz, t_sz, slots,
                  jnp.asarray(sizes, jnp.float32) * w, page_shift)
     out = (a_calls, a_hs, a_hc, a_sz, ab)
@@ -461,10 +364,10 @@ def _fused_body(arenas, tables, slots, dur_s, sizes, weights,
         idx = jnp.ceil(jnp.log(jnp.maximum(v, min_value) / min_value)
                        / log_gamma)
         idx = jnp.clip(idx, 0, nb - 1).astype(jnp.int32)
-        ad = _hist_scatter_stored(ad, t_ddc, dd_slots, idx,
-                                  jnp.where(is_zero, 0.0, w), page_shift)
-        a_ddz = _add1_stored(a_ddz, t_ddz, dd_slots,
-                             jnp.where(is_zero, w, 0.0), page_shift)
+        ad = _hist_scatter(ad, t_ddc, dd_slots, idx,
+                           jnp.where(is_zero, 0.0, w), page_shift)
+        a_ddz = _add1(a_ddz, t_ddz, dd_slots,
+                      jnp.where(is_zero, w, 0.0), page_shift)
         out += (a_ddz, ad)
     if mom:
         mom_slots = jnp.where(slots < mom_rows, slots, -1)
@@ -476,8 +379,7 @@ def _fused_body(arenas, tables, slots, dur_s, sizes, weights,
 def fused_step(edges: tuple, gamma: float, min_value: float, dd_rows: int,
                page_shift: int, packed: bool, mesh_key: "tuple | None" = None,
                mesh=None, series_shards: int = 1, mom_rows: int = 0,
-               mom_meta: "tuple | None" = None, kernel: str = "xla",
-               interpret: bool = False, compact: bool = False):
+               mom_meta: "tuple | None" = None):
     """The paged fused spanmetrics step, memoized per static meta.
 
     Signature (dd on):
@@ -499,21 +401,11 @@ def fused_step(edges: tuple, gamma: float, min_value: float, dd_rows: int,
     every series_shards. Page tables ride replicated (they are a few KB).
     Requires the mesh's 'data' axis == 1 (the serving default); `mesh_key`
     is the cache fingerprint for the mesh.
-
-    `kernel` ("xla" | "pallas") picks the device formulation: "xla" is
-    the composed-scatter body below, "pallas" the single-pass ragged-page
-    kernel (`ops.pallas_kernels.paged_fused_update` — page tables stacked
-    into one scalar-prefetch operand, every role updated in one VMEM
-    pass). Callers resolve the tier FIRST via `resolve_kernel` (the
-    pallas tier needs a TPU backend — or `interpret` for debug parity —
-    and no serving mesh); this builder trusts the resolved value.
-    `compact` is the int32/bf16-pair state tier (arenas must have been
-    created with the matching dtypes).
     """
     edges = tuple(edges)
     key = ("fused", edges, float(gamma), float(min_value), int(dd_rows),
            page_shift, bool(packed), mesh_key, int(series_shards),
-           int(mom_rows), mom_meta, kernel, bool(interpret), bool(compact))
+           int(mom_rows), mom_meta)
 
     def build():
         n_arenas = n_tables = 5 + (2 if dd_rows else 0) + \
@@ -535,38 +427,7 @@ def fused_step(edges: tuple, gamma: float, min_value: float, dd_rows: int,
             arenas, tables, slots, dur_s, sizes, weights = split(args)
             return _fused_body(arenas, tables, slots, dur_s, sizes,
                                weights, edges, gamma, min_value, dd_rows,
-                               page_shift, mom_rows, mom_meta, compact)
-
-        if kernel == "pallas":
-            assert mesh is None, "pallas tier is single-device"
-            from tempo_tpu.ops import pallas_kernels as pk
-            page_rows = 1 << page_shift
-
-            def pallas_step(*args):
-                arenas, tables, slots, dur_s, sizes, weights = split(args)
-                if packed:
-                    vals = args[-1][1:4]
-                else:
-                    vals = jnp.stack([
-                        jnp.asarray(dur_s, jnp.float32),
-                        jnp.asarray(sizes, jnp.float32),
-                        jnp.asarray(weights, jnp.float32)])
-                # one stacked scalar-prefetch operand: per-role tables
-                # padded to the series table's logical page count with -1
-                # (a padded entry reads "unbacked" → trash-page redirect)
-                p_pages = max(t.shape[0] for t in tables)
-                stacked = jnp.stack([
-                    jnp.pad(t, (0, p_pages - t.shape[0]),
-                            constant_values=-1) for t in tables])
-                return pk.paged_fused_update(
-                    stacked, slots, vals, arenas, page_rows=page_rows,
-                    edges=edges, gamma=gamma, min_value=min_value,
-                    dd_rows=dd_rows, mom_rows=mom_rows, mom_meta=mom_meta,
-                    compact=compact, interpret=interpret)
-
-            return instrumented_jit(
-                pallas_step, name="spanmetrics_fused_update_pallas",
-                donate_argnums=tuple(range(n_arenas)))
+                               page_shift, mom_rows, mom_meta)
 
         if mesh is None:
             return instrumented_jit(step, name="spanmetrics_fused_update",
@@ -604,8 +465,7 @@ def fused_step(edges: tuple, gamma: float, min_value: float, dd_rows: int,
                           for t, a in zip(tables, arenas))
             return _fused_body(arenas, ltabs, slots, dur_s,
                                sizes, weights, edges, gamma, min_value,
-                               dd_rows, page_shift, mom_rows, mom_meta,
-                               compact)
+                               dd_rows, page_shift, mom_rows, mom_meta)
 
         arena_specs = (P("series"),) * 4 + (P("series", None),)
         if dd_rows:

@@ -38,9 +38,8 @@ kernel per (span-bucket, trace-bucket) shape pair covers every cut:
 (JAX runs in 32-bit mode); comparisons are exact, never float-ranked.
 
 `reference_analysis` is the pure-Python oracle implementing the same
-contract span by span — the differential tests and the bench stage's
-spot check both diff the kernel against it, so the tiebreak rules above
-are load-bearing, not stylistic.
+contract span by span — the differential tests diff the kernel against
+it, so the tiebreak rules above are load-bearing, not stylistic.
 """
 
 from __future__ import annotations
@@ -222,7 +221,7 @@ def analyze(grp: np.ndarray, span_id: np.ndarray, parent_id: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# pure-Python oracle — the differential-test / bench-spot-check reference
+# pure-Python oracle — the differential tests' reference
 # ---------------------------------------------------------------------------
 
 def reference_analysis(grp, span_id, parent_id, end_ns, err

@@ -51,10 +51,6 @@ class GeneratorLimits:
     # moments rows while others keep the DDSketch plane
     sketch: str = ""
     sketch_moments_k: int = 0               # 0 = process default (moments_k)
-    # update-kernel tier: "" = the process default
-    # (generator.spanmetrics.kernel); "xla" | "pallas" override per
-    # tenant — per-tenant arenas share the pool, so tiers can mix
-    kernel: str = ""
     histogram_buckets: tuple[float, ...] = ()
     intrinsic_dimensions: dict[str, bool] = dataclasses.field(default_factory=dict)
     dimensions: tuple[str, ...] = ()

@@ -321,8 +321,9 @@ def sharded_serving_step(mesh: Mesh, edges: tuple, gamma: float,
     fn = _shard_map(_fused_update_mesh_impl, mesh=mesh,
                     in_specs=state_specs + batch_specs,
                     out_specs=state_specs, check_vma=False)
-    # instrumented: the serving path's zero-steady-state-recompile gate
-    # (bench multichip stage) reads the per-fn compile counters
+    # instrumented: compiles of the serving step are counted per fn
+    # (`tempo_jax_jit_compile_total`; the cells' judges refuse a run
+    # that compiled inside its window)
     from tempo_tpu.obs.jaxruntime import instrumented_jit
 
     return instrumented_jit(fn, name="spanmetrics_fused_update_mesh",

@@ -43,9 +43,9 @@ class _PagedBase(_MetricBase):
         self.planes: dict[str, PagedPlane] = {}
         self.table.backing = PageBacking(self.pool)
 
-    def _plane(self, role: str, width: int, dtype: str = "float32",
+    def _plane(self, role: str, width: int,
                limit: "int | None" = None) -> PagedPlane:
-        p = PagedPlane(self.pool, dtype, width, self.table.capacity
+        p = PagedPlane(self.pool, "float32", width, self.table.capacity
                        if limit is None else limit,
                        self.registry.tenant,
                        role=f"{self.name}/{role}")
@@ -63,16 +63,13 @@ class _PagedBase(_MetricBase):
 
     def _gather_full(self, plane: PagedPlane) -> np.ndarray:
         """Capacity-shaped host array with active rows filled — the shape
-        the dense `_snap`/`collect` pipeline already consumes. Compact
-        (int32) planes upcast at the snapshot boundary: integer counts
-        below 2^24 round-trip f32 exactly, so formatting matches the
-        dense layout sample-for-sample."""
+        the dense `_snap`/`collect` pipeline already consumes."""
         padded, n = self._padded_active()
         shape = (self.table.capacity,) if plane.width == 1 \
             else (self.table.capacity, plane.width)
         full = np.zeros(shape, np.float32)
         if n:
-            full[padded[:n]] = plane.gather(padded)[:n].astype(np.float32)
+            full[padded[:n]] = plane.gather(padded)[:n]
         return full
 
     def zero_evicted(self, padded_slots: np.ndarray) -> None:
@@ -93,25 +90,17 @@ class _PagedBase(_MetricBase):
 
 
 class PagedCounter(_PagedBase, Counter):
-    def __init__(self, registry, name, label_names, capacity,
-                 compact: bool = False):
+    def __init__(self, registry, name, label_names, capacity):
         self._init_paged(registry, name, label_names, capacity)
-        # compact tier: int32 rows — per-row contributions round to
-        # nearest (exact for unit/integer weights; the documented
-        # tolerance tier otherwise — runbook "Choosing the update kernel")
-        self.compact = compact
-        self.values = self._plane("values", 1,
-                                  dtype="int32" if compact else "float32")
+        self.values = self._plane("values", 1)
 
     def add_slots(self, slots: np.ndarray,
                   weights: np.ndarray | None = None) -> None:
         with self.registry.state_lock:
-            w = self._w(slots, weights)
-            if self.compact:
-                w = np.round(w).astype(np.int32)
             self.values.rebind(op.counter_add_step(self.pool.page_shift)(
                 self.values.data, self.values.device_map(),
-                np.ascontiguousarray(slots, np.int32), w))
+                np.ascontiguousarray(slots, np.int32),
+                self._w(slots, weights)))
 
     def _snap(self) -> tuple:
         return (self._gather_full(self.values),)
@@ -135,21 +124,13 @@ class PagedGauge(_PagedBase, Gauge):
 
 class PagedHistogram(_PagedBase, Histogram):
     def __init__(self, registry, name, label_names, capacity,
-                 edges: tuple[float, ...] = None, compact: bool = False):
+                 edges: tuple[float, ...] = None):
         from tempo_tpu.registry.registry import DEFAULT_HISTOGRAM_EDGES
         self._init_paged(registry, name, label_names, capacity)
         self.edges = tuple(DEFAULT_HISTOGRAM_EDGES if edges is None else edges)
-        # compact tier: bucket/count rows int32, the sum row a [2]-wide
-        # bf16 Kahan PAIR (running sum + compensation; the Pallas kernel
-        # maintains the compensation, the composed-scatter fallback
-        # accumulates into the primary column only)
-        self.compact = compact
-        self.buckets = self._plane("buckets", len(self.edges) + 1,
-                                   dtype="int32" if compact else "float32")
-        self.sums = self._plane("sums", 2 if compact else 1,
-                                dtype="bfloat16" if compact else "float32")
-        self.counts = self._plane("counts", 1,
-                                  dtype="int32" if compact else "float32")
+        self.buckets = self._plane("buckets", len(self.edges) + 1)
+        self.sums = self._plane("sums", 1)
+        self.counts = self._plane("counts", 1)
 
     def hist_edges(self) -> tuple:
         return self.edges
@@ -158,8 +139,7 @@ class PagedHistogram(_PagedBase, Histogram):
                       weights: np.ndarray | None = None) -> None:
         with self.registry.state_lock:
             a_sums, a_counts, ab = op.histogram_observe_step(
-                self.edges, self.pool.page_shift,
-                compact=self.compact)(
+                self.edges, self.pool.page_shift)(
                 self.sums.data, self.counts.data, self.buckets.data,
                 self.buckets.device_map(), self.sums.device_map(),
                 self.counts.device_map(),
@@ -170,17 +150,8 @@ class PagedHistogram(_PagedBase, Histogram):
             self.buckets.rebind(ab)
 
     def _snap(self) -> tuple:
-        if not self.compact:
-            return (self._gather_full(self.buckets),
-                    self._gather_full(self.sums),
-                    self._gather_full(self.counts))
-        # the pair plane folds to sum + compensation at the snapshot
-        padded, n = self._padded_active()
-        full = np.zeros((self.table.capacity,), np.float32)
-        if n:
-            pair = self.sums.gather(padded)[:n].astype(np.float32)
-            full[padded[:n]] = pair[:, 0] + pair[:, 1]
-        return (self._gather_full(self.buckets), full,
+        return (self._gather_full(self.buckets),
+                self._gather_full(self.sums),
                 self._gather_full(self.counts))
 
 

@@ -49,7 +49,7 @@ import numpy as np
 
 _LOG = logging.getLogger("tempo_tpu.pages")
 
-_DTYPE_BYTES = {"float32": 4, "int32": 4, "bfloat16": 2}
+_DTYPE_BYTES = {"float32": 4, "int32": 4}
 
 
 @dataclasses.dataclass
@@ -127,13 +127,12 @@ class _Arena:
             data = jax.device_put(
                 data, NamedSharding(pool.mesh.registry_mesh, spec))
         self.data = data
-        # physical page 0 is RESERVED as the trash page: the Pallas
-        # fused kernel's data-dependent BlockSpec index maps must name a
-        # real block for unbacked logical pages, and redirecting them to
-        # a page no tenant can ever own (written back unchanged, so it
-        # stays zero) keeps the dense "-1 drops" semantics without a
-        # host-side filter. The XLA kernels never see it: page tables
-        # only hold allocated ids (all ≥ 1) or -1.
+        # physical page 0 is RESERVED as the trash page: a page no
+        # tenant can ever own, which stays zero. The scatter kernels
+        # never see it (page tables only hold allocated ids, all ≥ 1,
+        # or -1, and -1 drops); a kernel whose block index maps must
+        # name a real block for an unbacked logical page would point
+        # them here. Arena shapes and `tempo_pages_total` count on it.
         self.free: list[int] = list(range(self.n_pages - 1, 0, -1))
         self.owners: list[str | None] = [None] * self.n_pages
 

@@ -214,10 +214,7 @@ class _MetricBase:
 
 
 class Counter(_MetricBase):
-    def __init__(self, registry, name, label_names, capacity,
-                 compact: bool = False):
-        # `compact` is the paged-layout int32/bf16 state tier; the dense
-        # layout has no compact storage — PagedCounter honors it
+    def __init__(self, registry, name, label_names, capacity):
         super().__init__(registry, name, label_names, capacity)
         self.state = m.counter_init(capacity)
 
@@ -296,8 +293,7 @@ class Histogram(_MetricBase):
     """Classic histogram family → `_count`/`_sum`/`_bucket{le=...}` series."""
 
     def __init__(self, registry, name, label_names, capacity,
-                 edges: tuple[float, ...] = DEFAULT_HISTOGRAM_EDGES,
-                 compact: bool = False):
+                 edges: tuple[float, ...] = DEFAULT_HISTOGRAM_EDGES):
         super().__init__(registry, name, label_names, capacity)
         self.state = m.histogram_init(capacity, edges)
 
@@ -443,10 +439,9 @@ class ManagedRegistry:
                     paged.PagedHistogram, paged.PagedNativeHistogram)
         return (Counter, Gauge, Histogram, NativeHistogram)
 
-    def new_counter(self, name: str, label_names: Sequence[str],
-                    compact: bool = False) -> Counter:
+    def new_counter(self, name: str, label_names: Sequence[str]) -> Counter:
         c = self._family_types()[0](self, name, label_names,
-                                    self._capacity_share(), compact=compact)
+                                    self._capacity_share())
         self._metrics[name] = c
         return c
 
@@ -457,11 +452,10 @@ class ManagedRegistry:
         return g
 
     def new_histogram(self, name: str, label_names: Sequence[str],
-                      edges: tuple[float, ...] = DEFAULT_HISTOGRAM_EDGES,
-                      compact: bool = False) -> Histogram:
+                      edges: tuple[float, ...] = DEFAULT_HISTOGRAM_EDGES
+                      ) -> Histogram:
         h = self._family_types()[2](self, name, label_names,
-                                    self._capacity_share(), edges,
-                                    compact=compact)
+                                    self._capacity_share(), edges)
         self._metrics[name] = h
         return h
 

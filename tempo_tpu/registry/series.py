@@ -210,4 +210,9 @@ class SeriesTable:
         return stale
 
     def active_slots(self) -> np.ndarray:
-        return np.flatnonzero(self.active)
+        # on a private copy: a push on another thread sets `active` while a
+        # collect reads it, and numpy's nonzero counts, allocates, then
+        # fills (its sparse path without a bound, the interpreter lock
+        # released): a slot activated in between is written past the end
+        # of the result, into the heap
+        return np.flatnonzero(self.active.copy())

@@ -2,8 +2,8 @@
 from config/env with deterministic seeds.
 
 Chaos engineering needs repeatable faults in PRODUCTION code paths, not
-test doubles: the `bench.py chaos` fault-matrix arm and the durability
-tests arm these points to prove the WAL / retry / handoff machinery
+test doubles: the durability tests (`tests/test_wal_faults.py`) arm
+these points to prove the WAL / retry / handoff machinery
 actually survives the failures it claims to. This generalizes the
 ad-hoc helpers in `tests/conftest.py` (forced-pressure scheduler,
 scripted remote-write endpoint): those fake a SPECIFIC dependency; a
@@ -193,8 +193,7 @@ def reset() -> None:
 
 
 class use:
-    """Context manager arming a spec list for a with-block (tests and
-    the chaos bench's parent-process arms)."""
+    """Context manager arming a spec list for a with-block (tests)."""
 
     def __init__(self, specs: list[FaultSpec], seed: int = 0) -> None:
         self.specs = specs

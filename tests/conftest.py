@@ -42,12 +42,6 @@ def _reset_device_scheduler():
     from tempo_tpu.registry import pages
 
     pages.reset()
-    # the pallas kernel-tier fallback warns ONCE per process per reason
-    # (the contract test_pallas_kernels.py::test_cpu_fallback_single_warning
-    # enforces); re-arm it so every test observes its own first warning
-    from tempo_tpu.ops import pages as ops_pages
-
-    ops_pages.reset_kernel_warnings()
     # the TraceQL quantile query tier follows the spanmetrics sketch
     # config at App build; reset so a moments-tier App doesn't leak
     # moment grids into later tests' evaluators
@@ -94,8 +88,7 @@ def _reset_device_scheduler():
 # The tier-1 suite runs under a hard 870s budget (ROADMAP verify line),
 # already pressured by the soak/pages/dryrun tests. Every test added
 # AFTER this guard landed must keep its call phase under the budget
-# below; the modules listed were grandfathered at introduction (their
-# wall cost is tracked by the bench accept gates instead). A new test
+# below; the modules listed were grandfathered at introduction. A new test
 # file — or any moments-tier test — that exceeds the budget fails the
 # whole suite, so slow tests surface in the PR that adds them instead
 # of silently eating the shared budget. Opt out (local debugging only)
@@ -181,7 +174,7 @@ _GRANDFATHERED_MODULES = frozenset({
     "test_mesh_serving.py", "test_microservices.py", "test_model.py",
     "test_multichip_dryrun.py", "test_native.py", "test_obs.py",
     "test_otlp_batch.py", "test_overload_smoke.py", "test_pages.py",
-    "test_pallas_kernels.py", "test_parallel.py", "test_plane_arith.py",
+    "test_parallel.py", "test_plane_arith.py",
     "test_plane_fuzz.py", "test_query_stats.py", "test_read_path.py",
     "test_read_plane.py", "test_registry.py", "test_ring.py",
     "test_sampling.py", "test_sched.py", "test_sketches.py",
@@ -233,8 +226,7 @@ def fleet_procs():
     is reaped on teardown regardless of test outcome: SIGTERM, bounded
     wait, SIGKILL fallback — a failing test must not leak generator
     processes into the rest of the suite. The lifecycle itself lives in
-    `tempo_tpu.fleet.worker.{spawn_worker,reap_workers}`, shared with
-    bench.py."""
+    `tempo_tpu.fleet.worker.{spawn_worker,reap_workers}`."""
     from tempo_tpu.fleet.worker import reap_workers, spawn_worker
 
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -50,6 +50,36 @@ def test_config_unknown_key_rejected():
         load_config(text="storage: {bukkit: x}")
 
 
+@pytest.mark.parametrize("where, key, value", [
+    ("config", "kernel", "xla"),
+    ("config", "pallas_interpret", "false"),
+    ("config", "compact_state", "false"),
+    ("override", "kernel", "pallas"),
+])
+def test_removed_update_kernel_options(where, key, value):
+    """The span-metrics update has one kernel formulation and one state
+    dtype. A YAML that still names a removed option fails to load,
+    naming the key; a per-tenant override that names one is passed over
+    like any unknown override key, beside a known key that applies."""
+    if where == "config":
+        with pytest.raises(ValueError, match=f"unknown config key: {key} "
+                                             "on SpanMetricsConfig"):
+            load_config(text=f"generator: {{spanmetrics: {{{key}: {value}}}}}")
+        return
+    from tempo_tpu.generator.generator import Generator
+    from tempo_tpu.generator.instance import GeneratorConfig
+    from tempo_tpu.overrides import Overrides
+
+    ov = Overrides()
+    ov.set_tenant_patch("t", {"generator": {key: value, "sketch": "moments"}})
+    assert not hasattr(ov.for_tenant("t").generator, key)
+    cfg = GeneratorConfig(processors=("span-metrics",))
+    cfg.registry.disable_collection = True
+    proc = Generator(cfg, overrides=ov).instance("t") \
+        .processors["span-metrics"]
+    assert proc.cfg.sketch == "moments" and not hasattr(proc.cfg, key)
+
+
 def test_config_warnings():
     cfg = load_config(text="ingester: {instance: {max_block_duration_s: 5}}")
     assert any("max_block_duration" in w for w in cfg.check())

@@ -233,7 +233,8 @@ def test_traceql_grid_update_compiles(one_chip):
         rows, _shape((65536,), jnp.float32, one_chip)).compile())
 
 
-def _paged_step(kernel: str, one_chip):
+def test_paged_xla_step_compiles(one_chip):
+    """(e) the paged layout's composed-scatter step (`pages.enabled`)."""
     import jax.numpy as jnp
 
     from tempo_tpu.ops import pages as op
@@ -248,23 +249,7 @@ def _paged_step(kernel: str, one_chip):
     tables = [_shape((CAP // PAGE_ROWS,), i32, one_chip)] * 5 \
         + [_shape((DD_ROWS // PAGE_ROWS,), i32, one_chip)] * 2
     step = op.fused_step(edges, gamma, cfg.sketch_min_s, DD_ROWS,
-                         PAGE_ROWS.bit_length() - 1, True, kernel=kernel)
-    return step._jit.lower(*arenas, *tables,
-                           _shape((4, BUCKET), f32, one_chip)).compile()
+                         PAGE_ROWS.bit_length() - 1, True)
+    _fits_one_chip(step._jit.lower(
+        *arenas, *tables, _shape((4, BUCKET), f32, one_chip)).compile())
 
-
-def test_paged_xla_step_compiles(one_chip):
-    """(e) the paged layout's composed-scatter step (`pages.enabled`)."""
-    _fits_one_chip(_paged_step("xla", one_chip))
-
-
-@pytest.mark.xfail(strict=True, reason=(
-    "refused by the v5e compiler, never run on a chip. Mosaic: 'cannot "
-    "statically prove that index in dimension 0 is a multiple of 1024' at "
-    "the slots_ref[pl.ds(base, blk)] load; with 1,024-aligned span chunks "
-    "and (1, n) slot blocks it then refuses 'infer-vector-layout: "
-    "unsupported shape cast' at tpu.reshape vector<1024xi1> -> "
-    "vector<1024x1xi1> (the [:, None] column broadcasts)"))
-def test_paged_pallas_step_compiles(one_chip):
-    """(f) the opt-in Pallas tier (`spanmetrics.kernel: pallas`)."""
-    _paged_step("pallas", one_chip)

@@ -6,7 +6,7 @@ outlier poisoning, nearest-bucket extrapolation), the WindowTuner's
 choices under an injected cost model (feasibility, latency minimization,
 static fallback, hard clamps), tuned-vs-static BIT-IDENTITY of drained
 state, the qlog/querystats device-seconds threading, the /status +
-/metrics surfaces, and the tier-1 smoke of the bench soak loop.
+/metrics surfaces, and the tier-1 smoke of the soak loop.
 """
 
 from __future__ import annotations
@@ -487,25 +487,18 @@ def test_sched_dispatch_span_emitted():
 # -- the tier-1 soak smoke --------------------------------------------------
 
 def test_soak_smoke():
-    """The bench soak loop in miniature: static + auto arms against a
+    """The soak loop in miniature: static + auto arms against a
     real App (distributor → ingester/generator, frontend reads, vulture
     canary over HTTP), gating the machinery — tuning goes active from a
     warm cost model, attribution sums, ledger populated, no tuning-loop
     recompiles, vulture writes read back. Arms are seconds, not
-    minutes, so the p99/throughput comparison is reported, not gated
-    (bench.py --stage=soak holds those)."""
-    import os
-    import sys
+    minutes, so the p99/throughput comparison is reported, not gated."""
+    from soak_harness import soak_run
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    import bench
-
-    out = bench._soak_run(n_tenants=12, warm_s=1.0, steady_s=2.0,
-                          spans_per_push=64, duty=0.6,
-                          read_every_s=0.5, vulture_every_s=1.0,
-                          smoke=True)
+    out = soak_run(n_tenants=12, warm_s=1.0, steady_s=2.0,
+                   spans_per_push=64, duty=0.6,
+                   read_every_s=0.5, vulture_every_s=1.0,
+                   smoke=True)
     assert out["soak_accept_ok"], out
     assert out["soak_tenants_attributed"] >= 12
     assert out["soak_tuned_window_ms"]       # tuner published a window

@@ -179,6 +179,32 @@ def test_restore_rejects_changed_label_layout():
         ck.restore_instance(_inst(), blob)
 
 
+# digests `overrides_fingerprint` gave `_inst()` at the last commit that
+# had the compact-state tier (485649c), by its `compact_state` value
+_FP_BEFORE_REMOVAL = {False: "2ac5926c598bfdd2", True: "d55b705f673e24cb"}
+
+
+@pytest.mark.parametrize("compact_state", [False, True])
+def test_checkpoint_cut_before_the_compact_tier_was_removed(compact_state):
+    """A checkpoint the old writer cut with `compact_state: false`
+    carries the digest this build computes, and restores bit-identically;
+    one cut with `true` is refused, by name, before any row is written."""
+    a = _inst()
+    _push(a, 1)
+    meta, arrays = ck._decode(ck.snapshot_instance(a))
+    assert meta["fingerprint"] == _FP_BEFORE_REMOVAL[False]
+    meta["fingerprint"] = _FP_BEFORE_REMOVAL[compact_state]
+    blob = ck._encode(meta, arrays)
+    b = _inst()
+    if compact_state:
+        with pytest.raises(ck.CheckpointMismatch, match="compact_state"):
+            ck.restore_instance(b, blob)
+        assert _samples(b) == {}
+    else:
+        ck.restore_instance(b, blob)
+        assert _samples(b) == _samples(a)
+
+
 # ---------------------------------------------------------------------------
 # placement + controller handoff (in-process fleet over one KVStore)
 # ---------------------------------------------------------------------------

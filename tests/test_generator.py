@@ -5,6 +5,7 @@ table-driven fixtures, plus remote-write wire checks."""
 import numpy as np
 import pytest
 
+from otlp_payload import make_otlp_payload
 from tempo_tpu.generator.instance import GeneratorConfig, GeneratorInstance
 from tempo_tpu.generator.processors.spanmetrics import SpanMetricsConfig, SpanMetricsProcessor
 from tempo_tpu.generator.processors.servicegraphs import ServiceGraphsConfig, ServiceGraphsProcessor
@@ -742,6 +743,26 @@ def test_label_blocks_follow_the_external_labels():
     assert _tick(reg, 3)[2] == {"kept": len(labels), "built": 0}
 
 
+def test_label_blocks_of_a_tick_encoded_in_the_middle_of_an_eviction():
+    """The encoder takes no registry lock, so a tick can be encoded after
+    `note_stale` dropped the evicted slots' blocks and before the table
+    forgets their label rows. What it builds then is the OLD series'
+    block, and the slot's next series must not find it kept. (The race
+    below meets this window by chance; here it is made.)"""
+    reg, proc, clock = _wire_world("dense")
+    _tick(reg, 1)
+    cols = reg.collect_columns(2)           # gathered before the purge ...
+    # ... and encoded inside it: a family's hooks run between its
+    # `note_stale` and the table's purge, `sizes` is the table's last
+    proc.sizes.evict_hooks.append(lambda padded: rw.encode_columns(cols))
+    clock.t += 1000
+    assert reg.purge_stale()
+    assert proc.calls.label_blocks._blocks  # blocks of series that are gone
+    proc.push_batch(_wire_batch(reg, range(1, 31), prefix="new"))
+    got, want, _ = _tick(reg, 3)
+    assert got == want
+
+
 def test_label_blocks_survive_evictions_racing_the_encoder():
     """An eviction between a build's read of a slot's labels and its store
     must not leave the old block for the slot's next series: a purger and
@@ -847,12 +868,11 @@ def test_columnar_tick_is_5x_the_per_sample_tick_on_4096_series():
 # SpanBatch staging path — same series table, same device states.
 
 def _fast_slow_pair(n_spans=4096):
-    import bench as _bench
     from tempo_tpu.generator.generator import Generator
     from tempo_tpu.generator.instance import GeneratorConfig
     from tempo_tpu.overrides import Overrides
 
-    payload = _bench._make_otlp_payload(n_spans, seed=3)
+    payload = make_otlp_payload(n_spans, seed=3)
 
     def mk():
         cfg = GeneratorConfig(processors=("span-metrics",))
@@ -926,7 +946,6 @@ def test_tee_recs_route_sharded_subset():
 
 
 def test_staged_fast_path_slack_filter_counts():
-    import bench as _bench
     from tempo_tpu.generator.generator import Generator
     from tempo_tpu.generator.instance import GeneratorConfig
     from tempo_tpu.overrides import Overrides
@@ -935,7 +954,7 @@ def test_staged_fast_path_slack_filter_counts():
     cfg.registry.disable_collection = True
     cfg.ingestion_time_range_slack_s = 30.0
     gen = Generator(cfg, overrides=Overrides())
-    payload = _bench._make_otlp_payload(512, seed=9)
+    payload = make_otlp_payload(512, seed=9)
     import time as _time
     inst = gen.instance("t")
     # make every span stale: pushes far in the "future" slide the window
@@ -950,7 +969,6 @@ def test_slack_drops_on_metrics_beside_the_distributors_reasons():
     `tempo_discarded_spans_total{reason="outside_slack"}`: ONE family,
     which the distributor (registered second on the single binary) adds
     its own reasons to."""
-    import bench as _bench
     import time as _time
     from tempo_tpu.generator.generator import Generator
     from tempo_tpu.generator.instance import GeneratorConfig
@@ -970,7 +988,7 @@ def test_slack_drops_on_metrics_beside_the_distributors_reasons():
                          labels=("reason",))
     inst = gen.instance("t")
     inst.now = lambda: _time.time() + 10_000     # every span is stale
-    gen.push_otlp("t", _bench._make_otlp_payload(64, seed=9))
+    gen.push_otlp("t", make_otlp_payload(64, seed=9))
     fam = parse_exposition(reg.render())["tempo_discarded_spans_total"]
     assert fam["type"] == "counter"
     assert fam["samples"] == {
@@ -989,12 +1007,11 @@ def test_donating_push_vs_concurrent_collection():
     quantile read moved inside the lock)."""
     import threading
 
-    import bench as _bench
     from tempo_tpu.generator.generator import Generator
     from tempo_tpu.generator.instance import GeneratorConfig
     from tempo_tpu.overrides import Overrides
 
-    payload = _bench._make_otlp_payload(2048, seed=8)
+    payload = make_otlp_payload(2048, seed=8)
     gen = Generator(GeneratorConfig(processors=("span-metrics",)),
                     overrides=Overrides())
     gen.push_otlp("t", payload)

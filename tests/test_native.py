@@ -5,6 +5,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
+from otlp_payload import make_otlp_payload
 from tempo_tpu import native
 from tempo_tpu.model.otlp import spans_from_otlp_proto
 from tempo_tpu.ops import hashing
@@ -160,9 +161,8 @@ def test_otlp_scan_mt_matches_sequential(monkeypatch):
 
     if not native.available():
         pytest.skip("native layer unavailable")
-    import bench as B
 
-    payload = B._make_otlp_payload(8192, n_services=13)
+    payload = make_otlp_payload(8192, n_services=13)
     monkeypatch.setattr(native, "_SCAN_MT_BYTES", 1)      # force MT
     mt = native.otlp_scan(payload)
     monkeypatch.setattr(native, "_SCAN_MT_BYTES", 1 << 60)  # force seq
@@ -182,9 +182,8 @@ def test_otlp_stage_mt_matches_serial(monkeypatch):
 
     if not native.available():
         pytest.skip("native layer unavailable")
-    import bench as B
 
-    payload = B._make_otlp_payload(8192, n_services=13)
+    payload = make_otlp_payload(8192, n_services=13)
     it_mt, it_s = StringInterner(), StringInterner()
     monkeypatch.setattr(native, "_SCAN_MT_BYTES", 1)
     monkeypatch.setattr(native, "_SCAN_THREADS", 4)   # force MT even on 1 cpu

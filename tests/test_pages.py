@@ -458,6 +458,142 @@ def test_paged_hll_and_log2_match_dense_sketches():
     np.testing.assert_array_equal(np.asarray(dd_d.zeros), np.asarray(az))
 
 
+# -- the fused step alone, against a numpy scatter -----------------------------
+#
+# Arenas of six physical pages of eight rows: page 0 is the trash page,
+# logical pages 0..2 sit on physical 1..3, logical page 3 is deliberately
+# UNBACKED and physical 4..5 were never handed out.
+
+OP_EDGES = (0.002, 0.008, 0.032, 0.128, 0.512)
+OP_PAGE_ROWS, OP_PAGE_SHIFT, OP_N_PHYS = 8, 3, 6
+OP_GAMMA, OP_MIN, OP_NB = 1.1, 1e-6, 32
+OP_MOM = (4, float(np.log(1e-6)), float(np.log(1e5)))
+
+
+def _op_arenas(dd: bool, mom: bool) -> list:
+    rows = OP_N_PHYS * OP_PAGE_ROWS
+    out = [np.zeros(rows, np.float32) for _ in range(4)]
+    out.append(np.zeros((rows, len(OP_EDGES) + 1), np.float32))
+    if dd:
+        out += [np.zeros(rows, np.float32),
+                np.zeros((rows, OP_NB), np.float32)]
+    if mom:
+        out.append(np.zeros((rows, OP_MOM[0] + 3), np.float32))
+    return out
+
+
+def _op_tables(n_roles: int, lpages: int = 4) -> list:
+    t = np.full(lpages, -1, np.int32)
+    t[:3] = [1, 2, 3]
+    return [t] * n_roles
+
+
+def _op_batch(seed: int, n: int = 32, lpages: int = 4) -> np.ndarray:
+    rng = np.random.default_rng(seed)
+    mat = np.empty((4, n), np.float32)
+    mat[0] = rng.integers(-1, lpages * OP_PAGE_ROWS, n)   # incl. discards
+    mat[1] = rng.lognormal(-3, 1.5, n)
+    mat[1, ::5] = 0.0                           # the sketches' zero rows
+    mat[2] = rng.integers(100, 5000, n)
+    mat[3] = rng.integers(1, 4, n)              # integer HT weights
+    return mat
+
+
+def _op_scatter_oracle(arenas: list, table: np.ndarray, mat: np.ndarray,
+                       dd_rows: int, mom_rows: int) -> None:
+    """One batch into float64 `arenas`, a row at a time: what the step's
+    docstrings say, written without the step's code."""
+    f32 = np.float32
+    calls, h_sums, h_counts, sizes, h_buckets = arenas[:5]
+    for s, v, size, w in zip(mat[0].astype(np.int64), mat[1], mat[2],
+                             mat[3]):
+        page = table[s >> OP_PAGE_SHIFT] if 0 <= s < len(table) * \
+            OP_PAGE_ROWS else -1
+        if page < 0:
+            continue                     # discard or unbacked: dropped
+        r = page * OP_PAGE_ROWS + (s & (OP_PAGE_ROWS - 1))
+        calls[r] += w
+        h_sums[r] += f32(v * w)
+        h_counts[r] += w
+        sizes[r] += f32(size * w)
+        h_buckets[r, int((v > np.asarray(OP_EDGES, f32)).sum())] += w
+        if dd_rows and s < dd_rows:
+            if v <= f32(OP_MIN):
+                arenas[5][r] += w
+            else:
+                idx = np.ceil(np.log(v / f32(OP_MIN)) / f32(np.log(OP_GAMMA)))
+                arenas[6][r, int(np.clip(idx, 0, OP_NB - 1))] += w
+        if mom_rows and s < mom_rows:
+            k, lo, hi = OP_MOM
+            z = np.log(np.clip(v, f32(np.exp(lo)), f32(np.exp(hi))))
+            x = np.clip((z - (lo + hi) / 2) / ((hi - lo) / 2), -1.0, 1.0)
+            row = arenas[-1][r]
+            row[:k + 1] += np.cos(np.arange(k + 1) * np.arccos(x)) * w
+            row[k + 1] = max(row[k + 1], z - lo)
+            row[k + 2] = max(row[k + 2], hi - z)
+
+
+@pytest.mark.parametrize("dd, mom", [(True, True), (True, False),
+                                     (False, True)],
+                         ids=["dd+moments", "dd", "moments"])
+def test_fused_step_matches_numpy_scatter(dd, mom):
+    from tempo_tpu.ops import pages as op
+
+    dd_rows = 2 * OP_PAGE_ROWS if dd else 0      # strict prefixes of the
+    mom_rows = 3 * OP_PAGE_ROWS if mom else 0    # 32-slot series table
+    step = op.fused_step(OP_EDGES, OP_GAMMA, OP_MIN, dd_rows, OP_PAGE_SHIFT,
+                         True, mom_rows=mom_rows,
+                         mom_meta=OP_MOM if mom else None)
+    got = _op_arenas(dd, mom)
+    want = [a.astype(np.float64) for a in got]
+    tabs = _op_tables(len(got))
+    for seed in range(3):
+        mat = _op_batch(seed)
+        got = step(*got, *tabs, mat)
+        _op_scatter_oracle(want, tabs[0], mat, dd_rows, mom_rows)
+    for role, (g, w) in enumerate(zip(got, want)):
+        g = np.asarray(g)
+        if role in (1, 3) or (mom and role == len(want) - 1):
+            # float sums: f32 accumulation against the f64 oracle
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5,
+                                       err_msg=f"role {role}")
+        else:
+            # counts under integer weights are exact
+            np.testing.assert_array_equal(g, w, err_msg=f"role {role}")
+        assert g[OP_PAGE_ROWS:4 * OP_PAGE_ROWS].any(), f"role {role} empty"
+        assert not g[:OP_PAGE_ROWS].any(), f"role {role} trash page"
+        assert not g[4 * OP_PAGE_ROWS:].any(), f"role {role} free pages"
+
+
+def test_fused_step_vec_route_equals_packed_route():
+    from tempo_tpu.ops import pages as op
+
+    meta = (OP_EDGES, OP_GAMMA, OP_MIN, 2 * OP_PAGE_ROWS, OP_PAGE_SHIFT)
+    tabs = _op_tables(7)
+    mat = _op_batch(7)
+    packed = op.fused_step(*meta, True)(*_op_arenas(True, False), *tabs, mat)
+    vec = op.fused_step(*meta, False)(
+        *_op_arenas(True, False), *tabs, mat[0].astype(np.int32), mat[1],
+        mat[2], mat[3])
+    assert np.asarray(packed[0]).any()
+    for role, (a, b) in enumerate(zip(packed, vec)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"role {role}")
+
+
+def test_fused_step_drops_discards_and_unbacked_pages():
+    from tempo_tpu.ops import pages as op
+
+    step = op.fused_step(OP_EDGES, OP_GAMMA, OP_MIN, 0, OP_PAGE_SHIFT, True)
+    mat = np.zeros((4, 16), np.float32)
+    mat[0, :8] = -1                               # discards
+    mat[0, 8:] = 3 * OP_PAGE_ROWS + np.arange(8)  # the unbacked page
+    mat[1], mat[2], mat[3] = 0.5, 100.0, 1.0
+    out = step(*_op_arenas(False, False), *_op_tables(5), mat)
+    for role, a in enumerate(out):
+        assert not np.asarray(a).any(), f"role {role} should be untouched"
+
+
 # -- obs / status surfaces ---------------------------------------------------
 
 def test_pool_status_and_obs_families_render():
@@ -473,7 +609,7 @@ def test_pool_status_and_obs_families_render():
         st = pool.status()
         assert st["allocated_total"] == 1
         # status reports USABLE pages: every arena reserves physical
-        # page 0 as the pallas kernel's trash page
+        # page 0 as the trash page
         assert st["arenas"][0]["pages"] == pool._arena_pages - 1
         assert st["arenas"][0]["reserved"] == 1
         assert st["top_tenant_bytes"][0]["tenant"] == "t9"

@@ -425,150 +425,6 @@ def test_fuzz_paged_vs_dense_differential():
 # into a reused row is exactly what this arm would catch), and (3) the
 # solver never falls back in steady state.
 
-# -- pallas-vs-xla kernel-tier differential arm -------------------------------
-#
-# ISSUE 11's write-plane gate: random op scripts (pushes with integer
-# HT weights, purges, evict-then-reuse) through the Pallas ragged-page
-# kernel (interpret mode — CPU containers cannot lower Mosaic) vs the
-# composed-scatter path, both over the PAGED layout. Contract (module
-# docstring of ops/pallas_kernels.py): integer-count planes and the
-# DDSketch quantile are BIT-identical under integer weights; float-sum
-# planes agree to f32 reduction-order tolerance. A third world runs the
-# int32/bf16-pair compact tier against a dense f32 reference and must
-# stay inside the tier's documented tolerances.
-
-def _kt_make_world(kernel: str, compact: bool = False, paged: bool = True):
-    from tempo_tpu.generator.processors.spanmetrics import (
-        SpanMetricsConfig, SpanMetricsProcessor)
-    from tempo_tpu.registry import pages as device_pages
-    from tempo_tpu.registry.registry import ManagedRegistry, RegistryOverrides
-
-    clock = [1000.0]
-    pool = device_pages.PagePool(device_pages.PagePoolConfig(
-        enabled=True, page_rows=16, arena_slots=1024)) if paged else None
-    with device_pages.use(pool):
-        reg = ManagedRegistry(
-            "k", RegistryOverrides(max_active_series=64,
-                                   stale_duration_s=50.0),
-            now=lambda: clock[0])
-        proc = SpanMetricsProcessor(reg, SpanMetricsConfig(
-            use_scheduler=False, sketch_max_series=32, sketch_rel_err=0.05,
-            kernel=kernel, pallas_interpret=(kernel == "pallas"),
-            compact_state=compact))
-    return clock, reg, proc
-
-
-_SUM_SUFFIXES = ("_sum", "_size_total")
-
-
-def _kt_compare(a, b, ctx, *, count_exact=True, count_abs=0.0,
-                sum_rtol=1e-6):
-    """Collect-sample comparison under the kernel-tier numerics
-    contract: count-family samples exact (or within `count_abs` for the
-    compact rounding tier), sum-family samples within `sum_rtol`."""
-    assert len(a) == len(b), ctx
-    for (na, la, va), (nb, lb, vb) in zip(a, b):
-        assert (na, la) == (nb, lb), f"{ctx}: series sets differ"
-        if na.endswith(_SUM_SUFFIXES):
-            assert abs(va - vb) <= sum_rtol * max(abs(va), 1e-9) + 1e-9, \
-                f"{ctx}: {na}{la} sum {va} vs {vb}"
-        elif count_exact:
-            assert va == vb, f"{ctx}: {na}{la} count {va} vs {vb}"
-        else:
-            assert abs(va - vb) <= count_abs, \
-                f"{ctx}: {na}{la} count {va} vs {vb} (tol {count_abs})"
-
-
-def test_fuzz_pallas_vs_xla_differential():
-    n_ops = max(int(os.environ.get("TEMPO_FUZZ_CASES", 40)) // 3, 10)
-    worlds = [_kt_make_world(k) for k in ("pallas", "xla")]
-    script = random.Random(SEED + 6)
-    for step in range(n_ops):
-        op = script.choice(["push", "push", "push", "purge", "collect",
-                            "quantile"])
-        seed = script.randrange(1 << 30)
-        n = script.choice([17, 64])
-        dt = script.choice([0.0, 5.0, 60.0])
-        weighted = script.random() < 0.5
-        ctx = f"seed={SEED} step={step} op={op}"
-        results = []
-        for clock, reg, proc in worlds:
-            rng = random.Random(seed)
-            clock[0] += dt
-            if op == "push":
-                wts = (np.random.default_rng(seed).integers(1, 4, n)
-                       .astype(np.float32) if weighted else None)
-                proc.push_batch(_pv_batch(reg, rng, n),
-                                sample_weights=wts)
-                results.append(reg.budget.used)
-            elif op == "purge":
-                results.append(reg.purge_stale())
-            elif op == "collect":
-                results.append(sorted(
-                    (s.name, s.labels, s.value)
-                    for s in reg.collect(step) if s.value == s.value))
-            else:
-                # DDSketch quantile rides integer-exact grid counts →
-                # bit-identical between kernel tiers
-                results.append(proc.quantile(rng.choice([0.5, 0.99])))
-        if op == "collect":
-            _kt_compare(results[0], results[1], ctx)
-        else:
-            assert results[0] == results[1], ctx
-    # deterministic evict-reuse coda (same shape as the paged-vs-dense
-    # arm): age everything out, repurge, repopulate — the pallas world's
-    # freed pages must recycle identically
-    for clock, reg, proc in worlds:
-        clock[0] += 1000.0
-        reg.purge_stale()
-        proc.push_batch(_pv_batch(reg, random.Random(SEED + 7), 64))
-    finals = [sorted((s.name, s.labels, s.value)
-                     for s in w[1].collect(10**6) if s.value == s.value)
-              for w in worlds]
-    _kt_compare(finals[0], finals[1], f"seed={SEED} final")
-    qq = [w[2].quantile(0.99) for w in worlds]
-    assert qq[0] == qq[1], f"seed={SEED} final quantile"
-
-
-def test_fuzz_compact_tier_within_tolerance():
-    """int32/bf16-pair state (pallas interpret) vs a dense f32 reference:
-    integer-weight pushes keep every count plane exact; a fractional-
-    weight push stays inside the ±0.5-per-dispatch rounding envelope;
-    bf16 Kahan sums hold 1% relative."""
-    script = random.Random(SEED + 8)
-    compact = _kt_make_world("pallas", compact=True)
-    ref = _kt_make_world("xla", paged=False)
-    frac_pushes = 0
-    n_pushes = 8
-    for step in range(n_pushes):
-        seed = script.randrange(1 << 30)
-        n = script.choice([17, 64])
-        fractional = step in (3, 6)
-        frac_pushes += fractional
-        for clock, reg, proc in (compact, ref):
-            rng = random.Random(seed)
-            wrng = np.random.default_rng(seed)
-            wts = (wrng.uniform(0.5, 2.5, n).astype(np.float32)
-                   if fractional
-                   else wrng.integers(1, 4, n).astype(np.float32))
-            proc.push_batch(_pv_batch(reg, rng, n), sample_weights=wts)
-    outs = [sorted((s.name, s.labels, s.value)
-                   for s in w[1].collect(1) if s.value == s.value)
-            for w in (compact, ref)]
-    # each fractional dispatch can shift a touched cell by ≤0.5
-    _kt_compare(outs[0], outs[1], f"seed={SEED} compact",
-                count_exact=False, count_abs=0.5 * frac_pushes + 1e-6,
-                sum_rtol=0.01)
-    # dd quantiles come off the (rounding-tolerance) int32 grid: compare
-    # against the reference within the sketch's own relative error class
-    qa = compact[2].quantile(0.99)
-    qb = ref[2].quantile(0.99)
-    assert set(qa) == set(qb)
-    for k, va in qa.items():
-        assert abs(va - qb[k]) <= 0.15 * max(abs(qb[k]), 1e-9) + 1e-9, \
-            f"seed={SEED} {k}: {va} vs {qb[k]}"
-
-
 # -- trace-analytics structural-plane differential arm ------------------------
 #
 # The write-plane gate for the structural tier (critical-path seconds,
