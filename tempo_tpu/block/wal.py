@@ -55,9 +55,12 @@ class WALBlock:
     def append(self, spans: Iterable[dict]) -> None:
         """Durably append a batch of flat span dicts as one segment file."""
         groups = bs.spans_by_trace(spans)
-        if not groups:
-            return
-        table = bs.traces_to_table(groups)
+        if groups:
+            self.append_table(bs.traces_to_table(groups))
+
+    def append_table(self, table) -> None:
+        """Durably append rows already in block order (`bs.block_schema()`)
+        as one segment file."""
         tmp = os.path.join(self.dir, f".{self._next_seg:07d}.tmp")
         with open(tmp, "wb") as f:
             pq.write_table(table, f, compression="zstd")

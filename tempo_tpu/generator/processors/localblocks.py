@@ -23,6 +23,7 @@ from typing import Callable, Iterator, Sequence
 import numpy as np
 
 from tempo_tpu.backend.raw import RawWriter, block_keypath
+from tempo_tpu.block.live_columns import ColumnSource
 from tempo_tpu.ingester.instance import InstanceConfig, TenantInstance
 from tempo_tpu.model.span_batch import SpanBatch
 from tempo_tpu.overrides.limits import IngestionLimits, Limits
@@ -75,12 +76,11 @@ class LocalBlocksProcessor:
 
     def push_batch(self, sb: SpanBatch) -> None:
         """Group the batch back by trace and append to live traces
-        (deterministic, `processor.go:155`)."""
-        by_id: dict[bytes, list[dict]] = {}
-        for s in sb.to_span_dicts():
-            by_id.setdefault(s["trace_id"], []).append(s)
-        for tid, spans in by_id.items():
-            self.inst.push_trace(tid, spans)
+        (deterministic, `processor.go:155`): each trace keeps a column
+        slice of the batch, no span dicts."""
+        valid = sb.valid[: sb.n]
+        self.inst.push_columns(
+            ColumnSource(sb), None if valid.all() else np.flatnonzero(valid))
 
     # -- background ticks --------------------------------------------------
 

@@ -18,6 +18,7 @@ from typing import Callable, Sequence
 import numpy as np
 
 from tempo_tpu.backend.raw import RawWriter, block_keypath
+from tempo_tpu.block.live_columns import ColumnSource
 from tempo_tpu.ingester.instance import InstanceConfig, TenantInstance
 from tempo_tpu.obs import Registry
 from tempo_tpu.overrides import Overrides
@@ -155,21 +156,24 @@ class Ingester:
     def push_staged(self, tenant: str, view) -> dict[str, str]:
         """Staged-view push (the decode-once distributor tee): this
         replica's traces arrive as a row-index slice over the shared
-        columnar staging (`model.otlp_batch.StagedView`) — live-trace
-        groups come straight off the trace-id column and span dicts
-        convert from the staged columns, with events/links restored from
-        the staging's one lazy payload pass. No per-replica protobuf
-        re-decode. Same return contract as `push_otlp`:
-        {trace_id_hex: reason} for rejected traces only."""
+        columnar staging (`model.otlp_batch.StagedView`), and the live
+        store keeps them so: the rows group by the trace-id column and
+        each trace gets a column slice of the push
+        (`block.live_columns`). No per-replica protobuf re-decode and no
+        span dict before a read or a cut asks for one. Same return
+        contract as `push_otlp`: {trace_id_hex: reason} for rejected
+        traces only."""
         with tracing.span_for_tenant("ingester.push", tenant,
                                      n_spans=view.n):
-            inst = self.instance(tenant)
-            out: dict[str, str] = {}
-            for tid, rows in view.trace_groups():
-                reason = inst.push_trace(tid, view.to_span_dicts(rows))
-                if reason:
-                    out[tid.hex()] = reason
-            return out
+            staged = view.staged
+            if not staged.has_span_attrs:
+                raise ValueError(
+                    "staged without span attrs: the live store would drop "
+                    "attributes (stage with include_span_attrs=True)")
+            refused = self.instance(tenant).push_columns(
+                ColumnSource(staged.batch()[0], staged),
+                None if view.is_full else view.rows)
+            return {tid.hex(): reason for tid, reason in refused.items()}
 
     # -- cut/flush machinery ----------------------------------------------
 

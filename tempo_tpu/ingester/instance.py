@@ -22,6 +22,7 @@ from typing import Callable, Sequence
 
 from tempo_tpu.backend.local import LocalBackend
 from tempo_tpu.backend.meta import BlockMeta, read_block_meta
+from tempo_tpu.block.live_columns import ColumnSource, cut_table
 from tempo_tpu.block.reader import BackendBlock
 from tempo_tpu.block.wal import WALBlock, rescan_blocks
 from tempo_tpu.block.writer import write_block
@@ -30,7 +31,9 @@ from tempo_tpu.overrides.limits import Limits
 from tempo_tpu.utils.livetraces import (
     ERR_LIVE_TRACES_EXCEEDED,
     ERR_TRACE_TOO_LARGE,
+    LIVE_SPANS,
     LiveTraceStore,
+    segment_spans,
 )
 
 PUSH_ERRORS = (ERR_LIVE_TRACES_EXCEEDED, ERR_TRACE_TOO_LARGE)
@@ -91,6 +94,25 @@ class TenantInstance:
                 self.discarded[err] = self.discarded.get(err, 0) + 1
             return err
 
+    def push_columns(self, source: ColumnSource,
+                     rows) -> dict[bytes, str]:
+        """Append `rows` of a staged push (None: all of them), a column
+        slice a trace; returns {trace_id: PushErrorReason} for the traces
+        that were refused."""
+        groups = source.trace_groups(rows)
+        out: dict[bytes, str] = {}
+        kept = 0
+        with self.lock:
+            for tid, seg, size in groups:
+                err = self.live.push_columns(tid, seg, size)
+                if err:
+                    self.discarded[err] = self.discarded.get(err, 0) + 1
+                    out[tid] = err
+                else:
+                    kept += len(seg)
+        LIVE_SPANS.inc(kept, ("columns",))
+        return out
+
     def cut_complete_traces(self, immediate: bool = False) -> int:
         """Idle/aged live traces → head WAL block (`CutCompleteTraces`)."""
         with self.lock:
@@ -106,8 +128,9 @@ class TenantInstance:
             # as the reference appends every cut trace and flushes the
             # head block once: a segment per trace costs milliseconds
             # each, which no real trace rate survives
-            self.head.append([s for lt in cut
-                              for s in sort_spans(combine_spans(lt.spans))])
+            table = cut_table(cut)
+            if table is not None:
+                self.head.append_table(table)
             return len(cut)
 
     def head_bytes(self) -> int:
@@ -214,10 +237,11 @@ class TenantInstance:
         parts: list[list[dict]] = []
         with self.lock:
             lt = self.live.traces.get(trace_id)
-            if lt:
-                parts.append(list(lt.spans))
+            live = lt.snapshot() if lt else None
             heads = [b for b in ([self.head] if self.head else [])] + list(self.completing)
             complete = list(self.complete.values())
+        if live:
+            parts.append(segment_spans(live))
         for wb in heads:
             spans = wb.find_trace_by_id(trace_id)
             if spans:
@@ -233,11 +257,13 @@ class TenantInstance:
     def all_recent_traces(self) -> list[tuple[bytes, list[dict]]]:
         """Snapshot of live + WAL data as (trace_id, spans) groups, for
         vectorized search over an in-memory ColumnView."""
-        by_id: dict[bytes, list[dict]] = {}
         with self.lock:
-            for tid, lt in self.live.traces.items():
-                by_id.setdefault(tid, []).extend(lt.spans)
+            live = [(tid, lt.snapshot())
+                    for tid, lt in self.live.traces.items()]
             heads = [b for b in ([self.head] if self.head else [])] + list(self.completing)
+        # column segments turn into dicts here, outside the lock
+        by_id: dict[bytes, list[dict]] = {
+            tid: segment_spans(segs) for tid, segs in live}
         for wb in heads:
             for s in wb.iter_spans():
                 by_id.setdefault(s["trace_id"], []).append(s)

@@ -455,25 +455,14 @@ class StagedView:
         out_sizes[:len(self.rows)] = sizes[self.rows]
         return out, out_sizes
 
-    def trace_groups(self) -> list[tuple[bytes, list[int]]]:
-        """(exact trace-id bytes, row indices) in first-seen order — the
-        ingester's live-trace grouping straight off the columns."""
-        spans = self.staged.spans
-        rows = self.row_indices()
-        tids = spans["trace_id"]
-        tls = spans["tid_len"]
-        groups: dict[bytes, list[int]] = {}
-        for i in rows.tolist():
-            tid = bytes(tids[i])[:int(tls[i])]
-            groups.setdefault(tid, []).append(i)
-        return list(groups.items())
-
     def to_span_dicts(self, rows: "np.ndarray | list[int] | None" = None
                       ) -> list[dict]:
         """Wire-parity span dicts for this view's rows (or a sub-slice):
         the shape `spans_from_otlp_proto` yields, with exact id byte
         lengths restored from the staged records and events/links merged
-        from the lazy payload pass."""
+        from the lazy payload pass. For a read of live traces held as
+        column slices, and the tee's bytes fallback; the staged push
+        itself makes none."""
         st = self.staged
         if not st.has_span_attrs:
             raise ValueError(

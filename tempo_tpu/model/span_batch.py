@@ -206,11 +206,11 @@ class SpanBatch:
     def to_span_dicts(self, rows: "np.ndarray | None" = None) -> list[dict]:
         """Valid rows as flat span dicts (the WAL/storage span form).
 
-        The bridge from the device-friendly SoA back to durable storage —
-        used by the localblocks processor, whose job is persistence
-        (`modules/generator/processor/localblocks/processor.go:151`) and
-        by the ingester's staged-view push. `rows` restricts the
-        conversion to a view's row subset (order preserved)."""
+        The bridge from the device-friendly SoA back to span dicts, for
+        a READ of live traces the local-blocks processor holds as column
+        slices of this batch (`block.live_columns`; the push and the cut
+        no longer come here). `rows` restricts the conversion to a row
+        subset (order preserved)."""
         it = self.interner
         out = []
         k_has = self.span_attr_key.shape[1] > 0

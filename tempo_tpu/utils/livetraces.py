@@ -14,17 +14,49 @@ import dataclasses
 import time
 from typing import Callable, Iterable
 
+from tempo_tpu.obs.jaxruntime import RUNTIME
+
 ERR_LIVE_TRACES_EXCEEDED = "live_traces_exceeded"
 ERR_TRACE_TOO_LARGE = "trace_too_large"
+
+LIVE_SPANS = RUNTIME.counter(
+    "tempo_ingester_live_spans_total",
+    "Spans appended to live traces (the ingester's and the local-blocks "
+    "processor's stores), by the form the store keeps them in: columns = "
+    "a row slice of a staged push's columns; dicts = span dicts",
+    labels=("form",))
 
 
 @dataclasses.dataclass
 class LiveTrace:
+    """`segments` in arrival order: a list of span dicts (consecutive
+    dict pushes share one), or a column slice of a staged push, an object
+    with `to_span_dicts()` (`block.live_columns.ColumnSegment`)."""
     trace_id: bytes
-    spans: list = dataclasses.field(default_factory=list)
+    segments: list = dataclasses.field(default_factory=list)
     bytes: int = 0
     first_append: float = 0.0
     last_append: float = 0.0
+
+    @property
+    def spans(self) -> list[dict]:
+        """Every span as a dict; column segments convert on demand."""
+        return segment_spans(self.segments)
+
+    def snapshot(self) -> list:
+        """The segments as they stand (take it under the store's lock:
+        a later dict push extends the last list), for `segment_spans`
+        once the lock is released."""
+        return [list(seg) if isinstance(seg, list) else seg
+                for seg in self.segments]
+
+
+def segment_spans(segments: Iterable) -> list[dict]:
+    """A live trace's segments as span dicts, in arrival order."""
+    out: list[dict] = []
+    for seg in segments:
+        out.extend(seg if isinstance(seg, list) else seg.to_span_dicts())
+    return out
 
 
 class LiveTraceStore:
@@ -45,6 +77,31 @@ class LiveTraceStore:
         """Append spans to a live trace. Returns an error reason or None."""
         spans = list(spans)
         sz = size_bytes if size_bytes is not None else _approx_size(spans)
+        lt = self._admit(trace_id, sz)
+        if isinstance(lt, str):
+            return lt
+        if lt.segments and isinstance(lt.segments[-1], list):
+            lt.segments[-1].extend(spans)
+        else:
+            lt.segments.append(spans)
+        LIVE_SPANS.inc(len(spans), ("dicts",))
+        return None
+
+    def push_columns(self, trace_id: bytes, segment,
+                     size_bytes: int) -> str | None:
+        """Append a column slice of a staged push (anything with `len()`
+        and `to_span_dicts()`) under the limits `push` enforces. The
+        caller counts the spans into `LIVE_SPANS` (one increment a push,
+        not one a trace)."""
+        lt = self._admit(trace_id, size_bytes)
+        if isinstance(lt, str):
+            return lt
+        lt.segments.append(segment)
+        return None
+
+    def _admit(self, trace_id: bytes, sz: int) -> "LiveTrace | str":
+        """The live trace `sz` more bytes go to, its bookkeeping done, or
+        the reason they may not."""
         lt = self.traces.get(trace_id)
         # Both limit checks run before any store mutation, so a rejected
         # first push leaves no empty LiveTrace behind.
@@ -59,11 +116,10 @@ class LiveTraceStore:
                 return ERR_LIVE_TRACES_EXCEEDED
             lt = self.traces[trace_id] = LiveTrace(
                 trace_id, first_append=self.now())
-        lt.spans.extend(spans)
         lt.bytes += sz
         lt.last_append = self.now()
         self.total_bytes += sz
-        return None
+        return lt
 
     def cut(self, idle_s: float = 0.0, max_age_s: float = 0.0,
             immediate: bool = False) -> list[LiveTrace]:
