@@ -100,6 +100,11 @@ class Generator:
             "tempo_metrics_generator_collect_duration_seconds",
             "One tenant collection tick: device-state gather through "
             "remote-write send")
+        self.collect_round_duration = reg.histogram(
+            "tempo_metrics_generator_collect_round_duration_seconds",
+            "One collection round over every tenant (each tenant's "
+            "collect and tick, one after the other); observed when the "
+            "round collected at least one tenant")
 
     def instance(self, tenant: str) -> GeneratorInstance:
         with self._lock:
@@ -617,7 +622,8 @@ class Generator:
         """One collection tick for every tenant (registry → remote write)."""
         with self._lock:
             insts = list(self.instances.values())
-        total = 0
+        total = collected = 0
+        t_round = time.perf_counter()
         # the collector holds the interpreter for seconds at a time:
         # every span that overlaps this tick is labelled collect="met"
         with tracing.collecting():
@@ -639,11 +645,15 @@ class Generator:
                         total += inst.collect_and_push()
                         self.collect_duration.observe(
                             time.perf_counter() - t0)
+                        collected += 1
                     with tracing.span_for_tenant("generator.tick",
                                                  inst.tenant):
                         inst.tick()
                 finally:
                     inst.untrack()
+        if collected:
+            self.collect_round_duration.observe(
+                time.perf_counter() - t_round)
         return total
 
     def start(self) -> None:

@@ -407,9 +407,17 @@ class PageBacking:
                 if len(arenas[aid].free) < want:
                     self.pool.alloc_failures += 1
                     return False
-            for plane, lpage in need:
-                if not plane.alloc(lpage):  # pragma: no cover — prechecked
-                    return False
+            if need:
+                from tempo_tpu.utils import tracing
+                # a series' first sighting on a fresh page: the pages and
+                # the upload of every page table they change, under the
+                # pool's lock (the next dispatch would pay the upload)
+                with tracing.span("pages.alloc"):
+                    for plane, lpage in need:
+                        if not plane.alloc(lpage):  # pragma: no cover
+                            return False            # — prechecked
+                    for plane, _ in need:
+                        plane.device_map()
             for plane, limit in self.planes:
                 if slot < limit:
                     plane.refcnt[slot >> shift] += 1

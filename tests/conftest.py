@@ -125,6 +125,20 @@ _BUDGET_OVERRIDES = {
     # the read plane's grid over a 1M-span block: ~3 s alone, 6 s seen
     # beside five busy workers (the other compile tests stay under 3 s)
     "tests/test_chip_compile.py::test_read_plane_metrics_grid_compiles": 30.0,
+    # tests/test_tenants_cell.py: two served Apps one after the other (24
+    # tenants on the paged layout, then the same pushes on the dense one:
+    # 12 s of boots and 2 x 24 collects with quantiles), 24 s read warm;
+    # and the cell's `run.py --rehearsal` as a child process, whose 3 s
+    # collection interval the set-up and the judge each wait out twice,
+    # 30 s read warm. Both compile on an empty cache beside five workers
+    "tests/test_tenants_cell.py::"
+    "test_served_paged_tenants_equal_the_oracle_and_the_dense_layout": 90.0,
+    "tests/test_tenants_cell.py::"
+    "test_the_cell_rehearses_to_its_end_on_the_cpu": 120.0,
+    # compiles the moments zeroing and update kernels on an empty cache:
+    # 10.1 s read cold beside five workers and the rehearsal's child
+    # process above (PR 33's whole run), under 10 s before it
+    "tests/test_moments.py::test_moments_zero_slots_resets_to_empty": 25.0,
     # The suite's compile cache now lives inside the checkout (PR 22:
     # the program reads and writes nothing around its checkout), so the
     # driver's fresh checkout starts with it EMPTY and whichever guarded

@@ -233,8 +233,12 @@ def test_traceql_grid_update_compiles(one_chip):
         rows, _shape((65536,), jnp.float32, one_chip)).compile())
 
 
-def test_paged_xla_step_compiles(one_chip):
-    """(e) the paged layout's composed-scatter step (`pages.enabled`)."""
+@pytest.mark.parametrize("arena_slots", [ARENA_SLOTS, 2 * ARENA_SLOTS])
+def test_paged_xla_step_compiles(one_chip, arena_slots):
+    """(e) the paged layout's composed-scatter step (`pages.enabled`), at
+    the shipped arena and at `multitenant-zipf-256`'s 262,144 slots. The
+    arena is what the compiler chews on: the step carries a temporary as
+    large as the DDSketch arena (`_fits_one_chip` counts it)."""
     import jax.numpy as jnp
 
     from tempo_tpu.ops import pages as op
@@ -242,10 +246,10 @@ def test_paged_xla_step_compiles(one_chip):
     cfg, gamma, nb = _meta()
     edges = tuple(cfg.histogram_buckets)
     f32, i32 = jnp.float32, jnp.int32
-    row = _shape((ARENA_SLOTS,), f32, one_chip)
+    row = _shape((arena_slots,), f32, one_chip)
     arenas = [row, row, row, row,
-              _shape((ARENA_SLOTS, len(edges) + 1), f32, one_chip),
-              row, _shape((ARENA_SLOTS, nb), f32, one_chip)]
+              _shape((arena_slots, len(edges) + 1), f32, one_chip),
+              row, _shape((arena_slots, nb), f32, one_chip)]
     tables = [_shape((CAP // PAGE_ROWS,), i32, one_chip)] * 5 \
         + [_shape((DD_ROWS // PAGE_ROWS,), i32, one_chip)] * 2
     step = op.fused_step(edges, gamma, cfg.sketch_min_s, DD_ROWS,

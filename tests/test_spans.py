@@ -42,7 +42,7 @@ SPAN_NAMES = frozenset({
     "generator.drain", "generator.tick",
     "spanmetrics.push", "servicegraphs.push", "localblocks.push",
     "traceanalytics.push",
-    "registry.purge", "registry.gather", "registry.format",
+    "registry.purge", "registry.gather", "registry.format", "pages.alloc",
     "remote_write.encode", "remote_write.send",
     "sched.wait", "sched.dispatch", "sched.h2d", "sched.enqueue",
     "wal.append", "wal.replay", "rpc.push",
@@ -392,6 +392,29 @@ def test_mesh_fused_update_keeps_its_jit_name():
         assert name.startswith(json.load(f)["reader"]["module"])
     assert {pre for pre in _layer_prefixes() if name.startswith(pre)} == {
         "jit__fused_update_mesh", "jit__fused_update"}
+
+
+def test_paged_fused_update_keeps_its_jit_name():
+    """The page pool's one-device step: inside the many-tenant cell's
+    roofline prefix and the one-device roofline's `jit__fused_update`
+    (the same work by the same `costs.fused_update_bytes`; that one reads
+    the dense cell only, where this module never runs), and not inside
+    the mesh cell's."""
+    from tempo_tpu.ops import pages as op
+
+    f32 = np.float32
+    row, table = np.zeros(128, f32), np.zeros(2, np.int32)
+    arenas = [row, row, row, row, np.zeros((128, 3), f32), row,
+              np.zeros((128, 8), f32)]
+    step = op.fused_step((0.1, 1.0), 1.02, 1e-9, 64, 6, True)
+    name = _module_name(step._jit.lower(*arenas, *[table] * 7,
+                                        np.zeros((4, 64), f32)))
+    assert name == "jit__fused_update_paged_impl"
+    with open(os.path.join(REPO, "chipbench", "layers",
+                           "fused_update_roofline_pct.tenants.json")) as f:
+        assert name.startswith(json.load(f)["reader"]["module"])
+    assert {pre for pre in _layer_prefixes() if name.startswith(pre)} == {
+        "jit__fused_update_paged", "jit__fused_update"}
 
 
 def test_edge_update_stays_outside_the_fused_update_match():
