@@ -28,10 +28,12 @@ from tempo_tpu.block.wal import WALBlock, rescan_blocks
 from tempo_tpu.block.writer import write_block
 from tempo_tpu.model.combine import combine_spans, sort_spans
 from tempo_tpu.overrides.limits import Limits
+from tempo_tpu.utils import tracing
 from tempo_tpu.utils.livetraces import (
     ERR_LIVE_TRACES_EXCEEDED,
     ERR_TRACE_TOO_LARGE,
     LIVE_SPANS,
+    LiveTrace,
     LiveTraceStore,
     segment_spans,
 )
@@ -60,6 +62,26 @@ class LocalBlockEntry:
 
 
 class TenantInstance:
+    """One tenant's live traces, head WAL block and local blocks.
+
+    Two locks, taken in this order and never the other way round:
+
+    - `sweep_lock`, held by `cut_complete_traces` from the hand-over of
+      the cut traces to the end of the segment's write, and by
+      `cut_block_if_ready`: two sweeps of one instance never interleave,
+      and a head block is never sealed while a segment is on its way
+      into it. A push never takes it.
+    - `lock`, the one a push takes: it covers the live store and the
+      bookkeeping (`head`, `cutting`, `completing`, `complete`) and is
+      never held through a table build or a write.
+
+    `cutting` is the traces a sweep has taken out of `live` and whose
+    segment is being built and written: in neither `live` nor (yet) any
+    segment, so the reads look there too. None when no sweep is between
+    its two holds of `lock`. Nobody appends to a trace once it is cut
+    (spans that arrive for its id start a new live trace), so readers
+    use what they snapshot under `lock` after releasing it."""
+
     def __init__(self, tenant: str, wal_dir: str, local_dir: str,
                  cfg: InstanceConfig | None = None,
                  limits: Limits | None = None,
@@ -80,7 +102,9 @@ class TenantInstance:
         self.head_created = 0.0
         self.completing: list[WALBlock] = []     # cut, awaiting completion
         self.complete: dict[str, LocalBlockEntry] = {}
+        self.cutting: list[LiveTrace] | None = None
         self.lock = threading.RLock()
+        self.sweep_lock = threading.Lock()
         self.discarded: dict[str, int] = {}
 
     # -- write path --------------------------------------------------------
@@ -114,23 +138,35 @@ class TenantInstance:
         return out
 
     def cut_complete_traces(self, immediate: bool = False) -> int:
-        """Idle/aged live traces → head WAL block (`CutCompleteTraces`)."""
-        with self.lock:
-            cut = self.live.cut(idle_s=self.cfg.trace_idle_s,
-                                max_age_s=self.cfg.trace_live_s,
-                                immediate=immediate)
-            if not cut:
-                return 0
-            if self.head is None:
-                self.head = WALBlock(self.wal_dir, self.tenant)
-                self.head_created = self.now()
-            # ONE segment (one parquet file, one fsync pair) per sweep,
-            # as the reference appends every cut trace and flushes the
-            # head block once: a segment per trace costs milliseconds
-            # each, which no real trace rate survives
-            table = cut_table(cut)
-            if table is not None:
-                self.head.append_table(table)
+        """Idle/aged live traces → head WAL block (`CutCompleteTraces`).
+        `lock` is held while the traces are taken and published as
+        `cutting`, and again to withdraw them; the table build and the
+        write run with only `sweep_lock` held, so pushes go on."""
+        with self.sweep_lock:
+            with self.lock, tracing.span("instance.cut_locked"):
+                cut = self.live.cut(idle_s=self.cfg.trace_idle_s,
+                                    max_age_s=self.cfg.trace_live_s,
+                                    immediate=immediate)
+                if not cut:
+                    return 0
+                if self.head is None:
+                    self.head = WALBlock(self.wal_dir, self.tenant)
+                    self.head_created = self.now()
+                head = self.head
+                self.cutting = cut
+            try:
+                # ONE segment (one parquet file, one fsync pair) per sweep,
+                # as the reference appends every cut trace and flushes the
+                # head block once: a segment per trace costs milliseconds
+                # each, which no real trace rate survives
+                table = cut_table(cut)
+                if table is not None:
+                    head.append_table(table)
+            finally:
+                # a failed write loses what it lost under the lock; it
+                # must not leave the traces published for ever
+                with self.lock, tracing.span("instance.cut_locked"):
+                    self.cutting = None
             return len(cut)
 
     def head_bytes(self) -> int:
@@ -141,8 +177,9 @@ class TenantInstance:
 
     def cut_block_if_ready(self, immediate: bool = False) -> WALBlock | None:
         """Seal the head block when over age/size (`CutBlockIfReady`);
-        returns the sealed WAL block to enqueue for completion."""
-        with self.lock:
+        returns the sealed WAL block to enqueue for completion. Waits for
+        a sweep in flight: its segment belongs in the block it seals."""
+        with self.sweep_lock, self.lock:
             if self.head is None:
                 return None
             age = self.now() - self.head_created
@@ -232,16 +269,23 @@ class TenantInstance:
     # -- read path ---------------------------------------------------------
 
     def find_trace_by_id(self, trace_id: bytes) -> list[dict] | None:
-        """Combine across live + head + completing + complete blocks
-        (the recent-data side of `Querier.FindTraceByID`)."""
+        """Combine across live + cutting + head + completing + complete
+        blocks (the recent-data side of `Querier.FindTraceByID`). All of
+        them are snapshot under ONE hold of `lock` and read after it, so
+        a trace a sweep is writing is found in `cutting`, in the new
+        segment, or (between the write's end and the sweep's second hold)
+        in both: `combine_spans` keeps one span a span id."""
         parts: list[list[dict]] = []
         with self.lock:
             lt = self.live.traces.get(trace_id)
             live = lt.snapshot() if lt else None
-            heads = [b for b in ([self.head] if self.head else [])] + list(self.completing)
+            cutting = self.cutting or ()
+            heads = self._wal_blocks()
             complete = list(self.complete.values())
         if live:
             parts.append(segment_spans(live))
+        parts.extend(segment_spans(lt.segments) for lt in cutting
+                     if lt.trace_id == trace_id)
         for wb in heads:
             spans = wb.find_trace_by_id(trace_id)
             if spans:
@@ -255,20 +299,28 @@ class TenantInstance:
         return sort_spans(combine_spans(*parts))
 
     def all_recent_traces(self) -> list[tuple[bytes, list[dict]]]:
-        """Snapshot of live + WAL data as (trace_id, spans) groups, for
-        vectorized search over an in-memory ColumnView."""
+        """Snapshot of live + cutting + WAL data as (trace_id, spans)
+        groups, for vectorized search over an in-memory ColumnView."""
         with self.lock:
             live = [(tid, lt.snapshot())
                     for tid, lt in self.live.traces.items()]
-            heads = [b for b in ([self.head] if self.head else [])] + list(self.completing)
+            cutting = self.cutting or ()
+            heads = self._wal_blocks()
         # column segments turn into dicts here, outside the lock
         by_id: dict[bytes, list[dict]] = {
             tid: segment_spans(segs) for tid, segs in live}
+        for lt in cutting:
+            by_id.setdefault(lt.trace_id, []).extend(
+                segment_spans(lt.segments))
         for wb in heads:
             for s in wb.iter_spans():
                 by_id.setdefault(s["trace_id"], []).append(s)
         return [(tid, sort_spans(combine_spans(spans)))
                 for tid, spans in by_id.items()]
+
+    def _wal_blocks(self) -> list[WALBlock]:
+        """The head block and those awaiting completion (under `lock`)."""
+        return ([self.head] if self.head else []) + list(self.completing)
 
     def complete_blocks(self) -> list[BackendBlock]:
         with self.lock:

@@ -147,6 +147,37 @@ def test_localblocks_lifecycle_and_query(tmp_path):
     assert sum(s.histogram.count for s in res.results()) == 20
 
 
+def test_a_ticks_write_does_not_block_push_batch(tmp_path, monkeypatch):
+    """`cut_tick` holds the processor's instance lock to take the cut
+    traces, not through the segment's write: a `push_batch` that arrives
+    while `append_table` is in flight returns before it ends."""
+    import threading
+
+    from tests.test_cut_lock import WAIT_S, _HeldWrite
+
+    write = _HeldWrite(monkeypatch)
+    p = LocalBlocksProcessor("t1", LocalBlocksConfig(data_dir=str(tmp_path)))
+    p.push_batch(build_batch(20))
+    tick = threading.Thread(target=p.cut_tick, args=(True,), daemon=True)
+    tick.start()
+    try:
+        assert write.entered.wait(WAIT_S)
+        push = threading.Thread(target=p.push_batch,
+                                args=(build_batch(5, t0_s=T0 + 30),),
+                                daemon=True)
+        push.start()
+        push.join(WAIT_S)
+        assert not push.is_alive() and tick.is_alive()
+        # the 20 traces being written; the 5 live ones carry ids of theirs
+        assert len(p.inst.cutting) == 20 and len(p.inst.live) == 5
+        assert len(p.inst.all_recent_traces()) == 20
+    finally:
+        write.release()
+    tick.join(WAIT_S)
+    assert not tick.is_alive() and p.inst.cutting is None
+    assert len(p.inst.complete_blocks()) == 1 and len(p.inst.live) == 5
+
+
 def test_generator_instance_localblocks_wiring(tmp_path):
     clock = [T0]
     cfg = GeneratorConfig(

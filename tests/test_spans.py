@@ -37,7 +37,7 @@ SPAN_NAMES = frozenset({
     "api.push",
     "distributor.admit", "distributor.decode", "distributor.PushSpans",
     "distributor.GeneratorTee",
-    "ingester.push", "ingester.cut",
+    "ingester.push", "ingester.cut", "instance.cut_locked",
     "generator.Push", "generator.resolve", "generator.collect",
     "generator.drain", "generator.tick",
     "spanmetrics.push", "servicegraphs.push", "localblocks.push",
