@@ -9,6 +9,8 @@ threads, checking every answer against a numpy oracle built from
 
     python chip_smoke.py                      # one chip, the real size
     python chip_smoke.py --chips 4            # serving mesh vs one device, only
+    python chip_smoke.py --recover            # kill -9 under the durable cell's
+        # load and restart: the server is a CHILD here (see `recover`)
     JAX_PLATFORMS=cpu python chip_smoke.py --size rehearsal   # a rehearsal
         # of the control flow at a toy size, with or without a chip:
         # never prints "ok": true, always exits 1
@@ -24,7 +26,8 @@ collection surface, while `/metrics` answers for discards, dispatch
 errors, the sampler and the jit compile counters.
 
 Not exercised here (so silence about them is not a pass): compaction,
-the paged layout, matview, the ingest WAL, the fleet.
+the paged layout, matview; the ingest WAL and the fleet only by
+`--recover`.
 """
 
 from __future__ import annotations
@@ -34,11 +37,13 @@ import http.client
 import json
 import os
 import shutil
+import subprocess
 import sys
 import tempfile
 import threading
 import time
 import traceback
+import types
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, HTTPServer
 
@@ -854,12 +859,277 @@ def four_chips(args, size: dict, workdir: str) -> None:
     say(phase="mesh_vs_single", tenants=compared, wall_seconds=walls)
 
 
+# ---------------------------------------------------------------------------
+# --recover: kill -9 under the durable cell's load, restart, compare
+# ---------------------------------------------------------------------------
+
+RECOVER_CELL = "k6-write-wal.steady"
+RECOVER_SECONDS = 20.0      # of the cell's closed loop before the kill -9
+# the kill falls this long before the load generator's window closes:
+# every client is mid-push, and few connects are refused after it
+KILL_BEFORE_END_S = 0.5
+STOP_WAIT_S = 600.0         # the last SIGTERM's grace
+MEMBERS: list = []          # every child started: none may outlive us
+
+
+class Member:
+    """One `tempo_tpu.fleet.worker` child from a written yaml: the chip is
+    the child's, this process never starts JAX."""
+
+    def __init__(self, yaml_path: str, log_path: str, wait_s: float) -> None:
+        t0 = time.monotonic()
+        self.log = open(log_path, "ab")
+        MEMBERS.append(self)
+        self.proc = subprocess.Popen(
+            [sys.executable, "-m", "tempo_tpu.fleet.worker", "--config",
+             yaml_path], stdout=subprocess.PIPE, stderr=self.log, cwd=REPO)
+        self.ready = None
+        threading.Thread(target=self._read, daemon=True).start()
+        while self.ready is None and time.monotonic() - t0 < wait_s \
+                and self.proc.poll() is None:
+            time.sleep(0.05)
+        self.ready_s = time.monotonic() - t0
+        if self.ready is None:
+            self.kill()
+            with open(log_path, "rb") as f:
+                tail = f.read()[-3000:].decode(errors="replace")
+            raise SmokeFailure(f"the member was not ready after "
+                               f"{self.ready_s:.1f} s (rc "
+                               f"{self.proc.returncode}): {tail}")
+        self.port = self.ready["port"]
+
+    def _read(self) -> None:
+        for line in self.proc.stdout:      # the ready line, then drained
+            try:
+                doc = json.loads(line)
+            except ValueError:
+                continue
+            if isinstance(doc, dict) and doc.get("ready"):
+                self.ready = doc
+
+    def kill(self) -> None:
+        if self.proc.poll() is None:
+            self.proc.kill()
+        self.proc.wait(timeout=30)
+
+    def terminate(self, wait_s: float) -> float | None:
+        """SIGTERM; seconds to its exit, None if it had to be killed."""
+        t0 = time.monotonic()
+        self.proc.terminate()
+        try:
+            self.proc.wait(timeout=wait_s)
+            return time.monotonic() - t0
+        except subprocess.TimeoutExpired:
+            self.kill()
+            return None
+
+
+def member_yaml(config: dict, workdir: str, sink_url: str) -> str:
+    """The deployment `chipbench.lib.boot` boots in process, written as a
+    yaml for a child: the example's file under the same moves into
+    `workdir` and the configuration's overrides
+    (`tests/test_wal_cell.py` holds the two to one `Config`)."""
+    import yaml
+
+    from chipbench import lib
+
+    limits_path = os.path.join(workdir, "overrides.yaml")
+    with open(limits_path, "w") as f:
+        yaml.safe_dump({"overrides": {t: config["tenant_limits"]
+                                      for t in config["tenants"]}}, f)
+    with open(os.path.join(REPO, config["example_yaml"])) as f:
+        doc = yaml.safe_load(f)
+    doc = lib.merged(lib.merged(doc, {
+        "server": {"http_listen_port": 0},
+        "storage": {"local_path": os.path.join(workdir, "blocks"),
+                    "wal_path": os.path.join(workdir, "wal")},
+        "per_tenant_override_config": limits_path,
+        "generator": {
+            "remote_write": {"url": sink_url},
+            "localblocks": {"data_dir": os.path.join(workdir, "localblocks")}},
+        "usage_stats_enabled": False}), config.get("yaml_overrides", {}))
+    path = os.path.join(workdir, "member.yaml")
+    with open(path, "w") as f:
+        yaml.safe_dump(doc, f)
+    return path
+
+
+def recover(args) -> int:
+    """The durable single binary (`k6-single-binary-wal`) under its cell's
+    traffic, killed with SIGKILL mid-push and restarted over the same
+    directories. The cell's own mix does what it does in the cell, with
+    the server a child: its set-up (canaries, prefill), RECOVER_SECONDS
+    of its closed loop from its load generator, `kill -9`, its check of
+    the log on disk (every push that got its 2xx is there, and besides
+    them only pushes in flight at the kill), the restart (boot restore,
+    WAL replay), one collect a tenant against the numpy oracle over
+    every push the log holds, SIGTERM, its check of guarantee 4."""
+    from unittest import mock
+
+    from chipbench import lib, reference_wal
+    from chipbench import run as bench_run
+    from chipbench.mixes import otlp_push, otlp_push_wal
+
+    rehearsal = args.size != "real"
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cell = next(w for w in bench["workloads"] if w["name"] == RECOVER_CELL)
+    with open(os.path.join(REPO, next(
+            c["file"] for c in bench["configs"]
+            if c["name"] == cell["config"]))) as f:
+        config = json.load(f)
+    traffic = lib.load_json("traffic", cell["traffic"] + ".json")
+    if rehearsal:
+        config = lib.merged(config, config.get("rehearsal", {}))
+        traffic = lib.merged(traffic, traffic.get("rehearsal", {}))
+    seconds = 6.0 if rehearsal else RECOVER_SECONDS
+    out_dir = os.path.join(REPO, "chiprun_out", "recover")
+    os.makedirs(out_dir, exist_ok=True)
+    t_start = time.monotonic()
+    ctx = types.SimpleNamespace(
+        args=types.SimpleNamespace(seconds=seconds), seed=args.seed,
+        cell=cell, config=config, traffic=traffic, rehearsal=rehearsal,
+        workdir=tempfile.mkdtemp(prefix="chip-recover-"), n_child=0,
+        clock=lambda: round(time.monotonic() - t_start, 3))
+    ctx.run_child = lambda spec, go=None: bench_run.run_child(ctx, spec, go)
+
+    def boot_member(config: dict, workdir: str, sink_url: str):
+        ctx.yaml = member_yaml(config, workdir, sink_url)
+        member = Member(ctx.yaml, os.path.join(out_dir, "member1.err"), 900)
+        say(phase="booted", ready_s=round(member.ready_s, 2),
+            ready=member.ready)
+        return member, None, member.port
+
+    class ChildMix(otlp_push_wal.Mix):
+        """The cell's mix with the server in a child. The child's
+        scheduler is out of reach: a collect is the flush barrier."""
+
+        def setup(self) -> None:
+            self.place_log()
+            with mock.patch.object(otlp_push, "boot", boot_member):
+                otlp_push.Mix.setup(self)
+
+        def drain(self, what: str) -> None:
+            for tenant in self.tenants:
+                self.collect_sums(tenant)
+
+    mix = ChildMix(ctx)
+    mix.setup()
+    check(all(otlp_push.acked(d) for d in mix.sent),
+          "a push of the set-up was refused")
+    first = ctx.app
+    killer = threading.Timer(seconds - KILL_BEFORE_END_S, first.kill)
+    res = ctx.run_child(dict(mix.child_spec(), seconds=seconds),
+                        killer.start)                # SIGKILL: nothing drains
+    mix.note(res)
+    unanswered = [d for d in res["done"] if not otlp_push.acked(d)]
+    say(phase="killed", pushes=len(mix.sent) - len(unanswered),
+        acknowledged=sum(map(otlp_push.acked, mix.sent)),
+        unanswered=len(unanswered), first_errors=res["errors"][:4])
+    check(first.proc.returncode == -9, "the member was gone before the kill")
+    check(unanswered and all(d["status"] == -1 for d in unanswered),
+          "a push was answered and refused")
+
+    # the log as the kill left it: every acknowledged push is there; a
+    # record that matches no push that returned was in flight, one a client
+    complaints: list = []
+    logs = mix.check_log(complaints, in_flight=traffic["clients"])
+    check(not complaints, f"the log: {complaints[:5]}")
+    columns = {tenant: [reference_wal.span_columns(arrays, strings)
+                        for _, records in reference_wal.read_tenant(
+                            mix.tenant_dir(tenant))[0]
+                        for _, _, arrays, strings in records]
+               for tenant in mix.tenants}
+    n_logged = sum(len(cols) for cols in columns.values())
+    spans_logged = sum(len(c["span_id"]) for cols in columns.values()
+                       for c in cols)
+
+    t0 = time.monotonic()
+    second = Member(ctx.yaml, os.path.join(out_dir, "member2.err"), 1800)
+    ctx.app, ctx.port = second, second.port
+    m = lib.scrape(second.port)
+    dead = lib.metric_sum(m, "tempo_wal_dead_letters_total")
+    replayed = lib.metric_sum(m, "tempo_wal_replayed_batches_total")
+    replay_s = lib.metric_sum(m, "tempo_span_duration_seconds_sum",
+                              span="wal.replay")
+    compiled = {dict(ls).get("fn", "?"): v for (name, ls), v in m.items()
+                if name == "tempo_jax_jit_compile_total" and v}
+    say(phase="restarted", restart_to_ready_s=round(second.ready_s, 2),
+        ready=second.ready, replayed_batches=replayed,
+        replay_s=round(replay_s, 3), replayed_spans=spans_logged,
+        replay_spans_per_s=round(spans_logged / replay_s, 1)
+        if replay_s else None, compiled=compiled, dead_letters=dead)
+    check(second.ready.get("platform") == first.ready.get("platform"),
+          f"the restart came up on {second.ready.get('platform')}, the "
+          f"first on {first.ready.get('platform')}")
+    check(replayed == n_logged,
+          f"{replayed:g} records replayed, the log holds {n_logged}")
+    check(not dead, "the replay wrote dead letters")
+
+    # the oracle over every logged push: counts exact, the float sum and
+    # the quantiles as the cell's judge holds them
+    report = {}
+    for tenant, cols in columns.items():
+        got = mix.collect_sums(tenant)
+        col = {k: np.concatenate([c[k] for c in cols])
+               for k in ("service", "name", "kind", "status", "start_ns",
+                         "end_ns")}
+        dur_s = ((col["end_ns"] - col["start_ns"]) / 1e9).astype(np.float32)
+        want_sum = float(dur_s.astype(np.float64).sum())
+        rel = abs(got.get("traces_spanmetrics_latency_sum", 0.0)
+                  - want_sum) / want_sum
+        edges = sum(len(c["kind"]) // traffic["push"][1] for c in cols)
+        for what, want in (("traces_spanmetrics_calls_total", len(dur_s)),
+                           ("traces_spanmetrics_latency_count", len(dur_s)),
+                           ("traces_service_graph_request_total", edges)):
+            if got.get(what, 0.0) != want:
+                complaints.append(f"{tenant}: {what} {got.get(what)} after "
+                                  f"the replay, {want} in the log")
+        if rel > traffic["latency_sum_rtol"]:
+            complaints.append(f"{tenant}: latency_sum off by {rel:.3g}")
+        if got["series"] < traffic["min_series"]:
+            complaints.append(f"{tenant}: {got['series']} series")
+        worst = mix.check_sketch(tenant, {
+            "svc": np.asarray([int(s[4:]) for s in col["service"]]),
+            "name": np.asarray([int(s[3:]) for s in col["name"]]),
+            "kind": col["kind"], "status": col["status"]}, dur_s, complaints)
+        report[tenant] = {"logged_spans": len(dur_s), "edges": edges,
+                          "series": got["series"],
+                          "latency_sum_rel_err": rel,
+                          "sketch_worst_rel_err_vs_rank": worst}
+    say(phase="compared", since_restart_s=round(time.monotonic() - t0, 2),
+        oracle=report, complaints=complaints[:10])
+    stop_s = second.terminate(15.0 if rehearsal else STOP_WAIT_S)
+    faults = mix.stop_faults(logs)
+    say(phase="stopped", sigterm_to_exit_s=stop_s and round(stop_s, 2),
+        guarantee_4_faults=faults)
+    shutil.rmtree(ctx.workdir, ignore_errors=True)
+    check(not complaints, f"the replayed state differs: {complaints[:5]}")
+    check(stop_s is not None and not faults,
+          f"the clean stop: {stop_s} s, {faults}")
+    ok = not rehearsal and second.ready.get("platform") == "tpu"
+    # every acknowledged push was in the log and the log was replayed
+    # whole: the checks above would have raised
+    say(ok=ok, rehearsal=rehearsal, acknowledged_spans_lost=0,
+        device=second.ready)
+    return 0 if ok else 1
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--size", choices=sorted(SIZES), default="real")
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--recover", action="store_true",
+                    help="kill -9 the durable single binary (a child "
+                         "process) under its cell's load and restart it")
     args = ap.parse_args()
+    if args.recover:
+        try:
+            return recover(args)     # before JAX: the chip is the child's
+        finally:
+            for member in MEMBERS:
+                member.kill()
 
     import tempo_tpu  # noqa: F401  (fails here in a bare directory)
     import jax

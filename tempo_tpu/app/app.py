@@ -759,12 +759,17 @@ class App:
             self.grpc_server.stop(grace=1).wait(2)
         if self.distributor:
             self.distributor.forwarders.shutdown()  # drain queued tees
-        if self.ingester:
-            self.ingester.shutdown()
         if self.fleet is not None:
             # BEFORE generator shutdown: the drain + shutdown checkpoints
-            # must see the instances (restart-without-data-loss path)
+            # must see the instances (restart-without-data-loss path).
+            # And before the ingester's: its flush completes every head
+            # block (minutes at a few million live spans), the
+            # checkpoints take seconds, and a stop that is killed after
+            # its grace period must have cut them and truncated the
+            # ingest WAL by then
             self.fleet.shutdown()
+        if self.ingester:
+            self.ingester.shutdown()
         if self.generator:
             self.generator.shutdown()
         if self.frontend:

@@ -88,8 +88,8 @@ def make_kv_server(port: int = 0, host: str = "127.0.0.1"
     return srv
 
 
-def _announce_ready(port: int) -> None:
-    print(json.dumps({"ready": True, "port": port}), flush=True)
+def _announce_ready(port: int, **more) -> None:
+    print(json.dumps({"ready": True, "port": port, **more}), flush=True)
 
 
 # ---------------------------------------------------------------------------
@@ -240,7 +240,12 @@ def main(argv: list[str] | None = None) -> int:
         for lc in app._lifecyclers:
             lc.desc.addr = app._advertise()
             lc.heartbeat()
-    _announce_ready(bound)
+    # the device this member serves from: a restart that came up on the
+    # CPU because the chip was still held must not read like one that
+    # got it (chip_smoke.py --recover)
+    import jax
+    dev = jax.devices()[0]
+    _announce_ready(bound, platform=dev.platform, device_kind=dev.device_kind)
     # SIGTERM must run the graceful path: App.shutdown cuts the
     # shutdown checkpoints the restart/handoff protocol depends on
     stop = threading.Event()

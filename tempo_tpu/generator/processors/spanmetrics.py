@@ -659,12 +659,15 @@ class SpanMetricsProcessor:
     def _staged_dims(self):
         if self._dims_arr is None:
             it = self.registry.interner
-            self._dims_arr = np.asarray(
-                [self._DIM_CODES[d] for d in self.cfg.intrinsic_dimensions],
-                np.int32)
             self._kind_lut = np.asarray(it.intern_many(_KIND_STRS), np.int32)
             self._status_lut = np.asarray(it.intern_many(_STATUS_STRS),
                                           np.int32)
+            # the mark goes last: a tenant's first pushes race here, and
+            # a thread that finds it set takes all three as built (two
+            # that both build them intern the same strings to the same ids)
+            self._dims_arr = np.asarray(
+                [self._DIM_CODES[d] for d in self.cfg.intrinsic_dimensions],
+                np.int32)
         return self._dims_arr, self._kind_lut, self._status_lut
 
     def push_staged(self, spans: np.ndarray, slack_lo: int,

@@ -492,19 +492,29 @@ class _TenantWal:
         can never both claim the same delta (replay order would
         misalign the implicit string ids)."""
         with self._lock:
-            now = self.now()
-            seq = self.next_seq
-            if interner is not None:
-                cur = self._seg_interner() \
-                    if self._seg_interner is not None else None
-                if cur is not interner:
-                    if self._f is not None:
-                        self._close_segment()   # new id space: rotate
-                    self._seg_interner = weakref.ref(interner)
-            if self._f is not None and (
-                    self._seg_bytes >= self.cfg.segment_max_bytes
-                    or now - self._seg_opened > self.cfg.segment_max_age_s):
+            # A batch-mode leader fsyncs with the lock released, and a
+            # rotation has to wait that out, which gives the lock up
+            # too. So the wait comes FIRST and everything is decided
+            # again after it: an appender that had read next_seq before
+            # the wait wrote a seq another appender took meanwhile, into
+            # the segment that one opened, after closing it under it.
+            while True:
+                now = self.now()
+                new_ids = interner is not None and (
+                    self._seg_interner is None
+                    or self._seg_interner() is not interner)
+                rotate = self._f is not None and (
+                    new_ids         # new id space: rotate
+                    or self._seg_bytes >= self.cfg.segment_max_bytes
+                    or now - self._seg_opened > self.cfg.segment_max_age_s)
+                if not (rotate and self._syncing):
+                    break
+                self._sync_cv.wait(timeout=1.0)
+            if rotate:
                 self._close_segment()
+            if new_ids:
+                self._seg_interner = weakref.ref(interner)
+            seq = self.next_seq
             if self._f is None:
                 self._open_segment(seq)
             if isinstance(payload, (bytes, bytearray)):
@@ -534,7 +544,12 @@ class _TenantWal:
             STATS["appended_batches"] += 1
             STATS["appended_bytes"] += len(frame)
             if self.cfg.fsync == "batch":
-                self._sync_to(ticket)
+                # the whole wait, leader (lock released around the
+                # fsync) and follower (on the condition) alike: what is
+                # left of `wal.append`'s self time is lock wait + encode
+                # + write, and `wal.sync` is the disk
+                with tracing.span("wal.sync"):
+                    self._sync_to(ticket)
             elif self.cfg.fsync == "interval" and \
                     now - self._last_fsync >= self.cfg.fsync_interval_s:
                 self._fsync()

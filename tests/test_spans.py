@@ -45,7 +45,7 @@ SPAN_NAMES = frozenset({
     "registry.purge", "registry.gather", "registry.format", "pages.alloc",
     "remote_write.encode", "remote_write.send",
     "sched.wait", "sched.dispatch", "sched.h2d", "sched.enqueue",
-    "wal.append", "wal.replay", "rpc.push",
+    "wal.append", "wal.sync", "wal.replay", "rpc.push",
     "frontend.Search", "frontend.QueryRange",
     "querier.SearchBlock", "querier.QueryRangeBlock",
     "fleet.handoff", "fleet.checkpoint", "fleet.restore",
