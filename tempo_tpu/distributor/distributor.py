@@ -24,6 +24,7 @@ from tempo_tpu.native import token_for   # native fnv batch; numpy fallback
 from tempo_tpu.obs import Registry
 from tempo_tpu.overrides import Overrides
 from tempo_tpu.ring import InstanceDesc, Ring, do_batch
+from tempo_tpu.utils import turn as turn_mod
 from tempo_tpu.utils.livetraces import _approx_size
 
 # discard reasons (mirroring the reference's discard metric reasons,
@@ -53,6 +54,9 @@ def _never_committed(e: BaseException) -> bool:
 
 
 class IngesterClient(Protocol):
+    # a service object of THIS process sets in_process = True; a send to
+    # any other client waits for the network, and the push gives its turn
+    # through the distributor up before it (`Distributor._client`)
     def push(self, tenant: str,
              traces: Sequence[tuple[bytes, list[dict]]]) -> list[str | None]: ...
 
@@ -135,6 +139,10 @@ class Distributor:
         self.generator_clients = generator_clients or {}
         self.limiter = RateLimiter(now=now)
         self.backpressure = IngestBackpressure()
+        # pushes are served ONE AT A TIME, whatever receiver they came
+        # through (utils/turn.py): taken at the top of `push_otlp` and
+        # `push_spans`, given back on every way out
+        self.turn = turn_mod.Turn()
         # graceful-overload sampling stage (runs on the staged decode-once
         # path BEFORE grouping/replication; see distributor/sampler.py) —
         # replaceable with one carrying an injected fraction_source
@@ -230,17 +238,26 @@ class Distributor:
         `raw_recs` is the receiver's native SpanRec scan of the same bytes
         (passed along so the tee does not scan twice)."""
         from tempo_tpu.utils import tracing
-        t0 = time.perf_counter()
-        try:
-            with tracing.span_for_tenant("distributor.PushSpans", tenant,
-                                         n_spans=len(spans)):
-                return self._push_spans(tenant, spans, size_bytes, raw_otlp,
-                                        raw_recs)
-        finally:
-            self.push_duration.observe(time.perf_counter() - t0)
+        with self.turn.served(tenant):
+            t0 = time.perf_counter()
+            try:
+                with tracing.span_for_tenant("distributor.PushSpans", tenant,
+                                             n_spans=len(spans)):
+                    return self._push_spans(tenant, spans, size_bytes,
+                                            raw_otlp, raw_recs)
+            finally:
+                self.push_duration.observe(time.perf_counter() - t0)
 
     def push_otlp(self, tenant: str, raw: bytes,
                   recs: "np.ndarray | None" = None) -> dict[str, int]:
+        """`_push_otlp` in this push's turn: admission, decode and the
+        push itself run alone; the wait for the turn is the span
+        `distributor.turn`."""
+        with self.turn.served(tenant):
+            return self._push_otlp(tenant, raw, recs)
+
+    def _push_otlp(self, tenant: str, raw: bytes,
+                   recs: "np.ndarray | None") -> dict[str, int]:
         """The COLUMNAR PushTraces path: raw OTLP wire bytes in, no span
         dicts anywhere in the distributor. The native scan's fixed columns
         drive vectorized validation, data-quality, usage attribution,
@@ -333,6 +350,19 @@ class Distributor:
         spans, recs2 = got
         return self.push_spans(tenant, spans, size_bytes=len(raw),
                                raw_otlp=raw, raw_recs=recs2)
+
+    def _client(self, clients, inst: InstanceDesc):
+        """The client one send goes to. Only a service object of this
+        process (`in_process`: the Ingester, the Generator) is pushed to
+        inside the turn. Any other client's send is a round trip on the
+        network, up to its 30 s timeout (`rpc.py`, `grpcplane/client.py`):
+        the push gives its turn up before the first of them and does not
+        take it again, so the sends of a `target: distributor` process
+        overlap as they did before there was a turn."""
+        client = clients[inst.id]
+        if not getattr(client, "in_process", False):
+            turn_mod.give_up()
+        return client
 
     def _admit(self, tenant: str, lim, sz: int, n_spans) -> None:
         """Admission shared by every push path: process-wide backpressure
@@ -468,7 +498,7 @@ class Distributor:
                                     len(tid_hex) // 2))
 
         def send_ing(inst: InstanceDesc, items: list[int]) -> None:
-            client = self.ingester_clients[inst.id]
+            client = self._client(self.ingester_clients, inst)
             fn = getattr(client, "push_otlp", None)
             if fn is not None:
                 for tid_hex, reason in (fn(tenant, payload_for(items))
@@ -514,7 +544,7 @@ class Distributor:
                 return recs[vrows[pick[inverse]]]
 
             def send_gen(inst: InstanceDesc, items: list[int]) -> None:
-                client = self.generator_clients[inst.id]
+                client = self._client(self.generator_clients, inst)
                 if getattr(client, "accepts_local_trust", False):
                     # in-process generator (explicit marker — never
                     # inferred): these bytes already passed this process's
@@ -580,6 +610,7 @@ class Distributor:
                         if last_owner == inst.id:
                             # same owner still refusing: brief jittered
                             # pause before the ring view names a new one
+                            turn_mod.give_up()
                             time.sleep(0.05 * (1 + attempt)
                                        * (0.5 + random.random()))
                         last_owner = inst.id
@@ -763,7 +794,7 @@ class Distributor:
                                     len(tid_hex) // 2))
 
         def send_ing(inst: InstanceDesc, items: list[int]) -> None:
-            client = self.ingester_clients[inst.id]
+            client = self._client(self.ingester_clients, inst)
             got = client.push_staged(tenant, staged.view(rows_for(items)))
             for tid_hex, reason in (got or {}).items():
                 i = _item_of(tid_hex)
@@ -785,7 +816,7 @@ class Distributor:
 
         # generator tee (RF1, best-effort, staged views)
         def send_gen(inst: InstanceDesc, items: list[int]) -> None:
-            client = self.generator_clients[inst.id]
+            client = self._client(self.generator_clients, inst)
             view = staged.view(rows_for(items))
             if client.push_staged_view(tenant, view) is not None:
                 return
@@ -856,6 +887,9 @@ class Distributor:
             # the bus) — running either in parallel would persist or count
             # every span twice.
             from tempo_tpu.ingest.encoding import produce_traces
+            # the produce is all this push has left, and with a Kafka
+            # bus it waits for the brokers' acknowledgement
+            turn_mod.give_up()
             produce_traces(self.bus, tenant, groups, tokens)
             self.metrics["traces_pushed_total"] += len(groups)
             return errs
@@ -898,7 +932,7 @@ class Distributor:
         item_reason: dict[int, str] = {}
 
         def send(inst: InstanceDesc, items: list[int]) -> None:
-            client = self.ingester_clients[inst.id]
+            client = self._client(self.ingester_clients, inst)
             res = client.push(tenant, [groups[i] for i in items])
             for i, reason in zip(items, res or ()):
                 if reason:
@@ -961,7 +995,7 @@ class Distributor:
         from tempo_tpu.model.otlp import encode_spans_otlp, slice_otlp_payload
 
         def send(inst: InstanceDesc, items: list[int]) -> None:
-            client = self.generator_clients[inst.id]
+            client = self._client(self.generator_clients, inst)
             if recs is not None:
                 wis = [wi_by_id.get(id(s))
                        for i in items for s in groups[i][1]]

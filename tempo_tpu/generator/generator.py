@@ -29,6 +29,8 @@ class Generator:
     # the distributor's in-process tee may pass trusted=True to push_otlp
     # (bytes validated by its own scan); see GeneratorClient protocol
     accepts_local_trust = True
+    # and pushes to it inside its turn; see IngesterClient
+    in_process = True
 
     def __init__(self, cfg: GeneratorConfig | None = None,
                  overrides: Overrides | None = None,

@@ -46,6 +46,7 @@ from tempo_tpu.obs.jaxruntime import RUNTIME, instrumented_jit
 from tempo_tpu.registry import metrics as rm
 from tempo_tpu.registry.registry import DEFAULT_HISTOGRAM_EDGES, ManagedRegistry
 from tempo_tpu.sched import bucket_rows
+from tempo_tpu.utils import turn
 
 _PEER_ATTRS = ("peer.service", "db.name", "db.system", "messaging.system",
                "net.peer.name")  # `servicegraphs.go:287-343` heuristics
@@ -253,8 +254,9 @@ class ServiceGraphsProcessor:
         # spanmetrics dispatch discipline), or one side's rebind drops
         # the other's and a reader meets a donated buffer. The slot
         # resolve rides inside so a purge cannot free a slot between its
-        # resolve and its update
-        with self.registry.state_lock:
+        # resolve and its update. The wait for it is the device's, so a
+        # push gives its turn through the distributor up before it waits
+        with turn.waiting_for(self.registry.state_lock):
             slots = np.full(cap, -1, np.int32)
             slots[:n] = self.total.resolve_slots(rows)
             packed[0] = slots

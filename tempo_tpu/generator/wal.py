@@ -53,7 +53,7 @@ import zlib
 
 import numpy as np
 
-from tempo_tpu.utils import faults, tracing
+from tempo_tpu.utils import faults, tracing, turn
 
 _LOG = logging.getLogger("tempo_tpu.generator.wal")
 
@@ -547,11 +547,16 @@ class _TenantWal:
                 # the whole wait, leader (lock released around the
                 # fsync) and follower (on the condition) alike: what is
                 # left of `wal.append`'s self time is lock wait + encode
-                # + write, and `wal.sync` is the disk
+                # + write, and `wal.sync` is the disk. A push served by
+                # the distributor gives its turn up here, before the
+                # wait: the turn is the interpreter's, the wait is not,
+                # and a push that follows can then share the fsync
+                turn.give_up()
                 with tracing.span("wal.sync"):
                     self._sync_to(ticket)
             elif self.cfg.fsync == "interval" and \
                     now - self._last_fsync >= self.cfg.fsync_interval_s:
+                turn.give_up()          # the disk's wait, as above
                 self._fsync()
             return self._seg_first, seq
 

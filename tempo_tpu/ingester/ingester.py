@@ -49,6 +49,9 @@ class _FlushOp:
 
 
 class Ingester:
+    # pushed to inside the distributor's turn; see IngesterClient
+    in_process = True
+
     def __init__(self, data_dir: str,
                  flush_writer: RawWriter | None = None,
                  cfg: IngesterConfig | None = None,

@@ -36,7 +36,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SPAN_NAMES = frozenset({
     "api.push",
     "distributor.admit", "distributor.decode", "distributor.PushSpans",
-    "distributor.GeneratorTee",
+    "distributor.GeneratorTee", "distributor.turn",
     "ingester.push", "ingester.cut", "instance.cut_locked",
     "generator.Push", "generator.resolve", "generator.collect",
     "generator.drain", "generator.tick",
@@ -290,8 +290,8 @@ def test_one_served_push_yields_the_expected_spans(tmp_path):
         app.shutdown()
     request_tree = {"api.push", "distributor.admit", "distributor.decode",
                     "distributor.PushSpans", "ingester.push",
-                    "distributor.GeneratorTee", "generator.Push",
-                    "spanmetrics.push", "generator.resolve",
+                    "distributor.GeneratorTee", "distributor.turn",
+                    "generator.Push", "spanmetrics.push", "generator.resolve",
                     "servicegraphs.push", "localblocks.push"}
     dispatch_tree = {"sched.dispatch", "sched.h2d", "sched.enqueue"}
     counts = got["tempo_span_duration_seconds_count"]
