@@ -18,6 +18,29 @@
   span's start or end or began in between (`collecting()`), else `clear`:
   the collector holds the interpreter for seconds, so the `clear` rows
   are a path's own cost and the `met` rows are the stall;
+- a span with no parent on its own thread (the root of a thread's tree:
+  `api.push` on a request thread, `sched.dispatch` on the scheduler's,
+  `ingester.cut`, `generator.collect`, `generator.tick`) reads a CPU
+  clock too (`_cpu_clock`: user + system time of THIS thread), outside
+  the wall clock's pair, and adds its CPU time to the same row as
+  `tempo_span_cpu_seconds{span,collect}`, whose `_count` counts those
+  root spans. A span under a same-thread parent reads no CPU clock: the
+  read is a system call (5.7 us on the chip's sealed host, two a span
+  were a twentieth of a cell's rate) and a thread's roots already sum
+  to all the CPU its spans cover. Under one interpreter lock a wall
+  clock reads the neighbours too; the two clocks split a root span into
+  WORK = CPU: the span's Python plus the native code it runs on its own
+  thread, with or without the interpreter lock (numpy kernels, snappy,
+  the calling thread's part of `pq.write_table`), and WAIT = duration -
+  CPU: queued for the interpreter lock, for another lock, for the disk,
+  a socket or the device. Work that OTHER threads do for the span
+  (Arrow's pool, XLA's) is on nobody's span: it is in
+  `process_cpu_seconds_total` (registered here too) and in no
+  `tempo_span_cpu_seconds` row. Where the host's thread clock is a
+  coarse ticker (10 ms steps on the chip's sealed host) a span's CPU is
+  right only as a mean over many spans, a span of milliseconds can read
+  more CPU than duration, and WAIT can come out negative: trust the
+  second-scale spans and the process counter there;
 - this part takes no lock another thread takes and draws no random
   bytes: every thread adds to rows of its own, summed at the scrape.
 
@@ -110,6 +133,7 @@ class SelfTraceConfig:
 # -- the always-on part ------------------------------------------------------
 
 _clock = time.perf_counter_ns          # tests inject another
+_cpu_clock = time.thread_time_ns       # user + system of THIS thread
 
 # The collect mark: [collection ticks running now, ticks begun so far].
 # Written by the collector under `_collect_lock`; a span reads the pair at
@@ -133,7 +157,8 @@ def collecting():
 
 
 # Span rows: {thread ident: {span name: (clear row, met row)}}, a row
-# being [count, duration ns, self ns, duration buckets, self buckets].
+# being [count, duration ns, self ns, duration buckets, self buckets,
+# CPU ns, spans that read the CPU clock].
 # A thread writes only under its own ident (the OS hands a dead thread's
 # ident to a new one, which then carries its rows on: the table is as
 # large as the most threads alive at once), so no row has two writers.
@@ -144,12 +169,13 @@ _EDGES_NS = tuple(int(e * 1e9) for e in _BUCKETS_S)
 
 def _new_row() -> list:
     n = len(_EDGES_NS) + 1
-    return [0, 0, 0, [0] * n, [0] * n]
+    return [0, 0, 0, [0] * n, [0] * n, 0, 0]
 
 
 def span_rows() -> dict[tuple[str, str], list]:
     """{(span, collect): [count, duration ns, self ns, duration buckets,
-    self buckets]} summed over the threads: what `/metrics` renders."""
+    self buckets, CPU ns, spans that read the CPU clock]} summed over the
+    threads: what `/metrics` renders."""
     out: dict[tuple[str, str], list] = {}
     for per_thread in list(_rows.values()):
         for name, pair in list(per_thread.items()):
@@ -160,6 +186,8 @@ def span_rows() -> dict[tuple[str, str], list]:
                 agg[0] += row[0]
                 agg[1] += row[1]
                 agg[2] += row[2]
+                agg[5] += row[5]
+                agg[6] += row[6]
                 for i, c in enumerate(row[3]):
                     agg[3][i] += c
                 for i, c in enumerate(row[4]):
@@ -172,10 +200,15 @@ def reset_span_rows() -> None:
     _rows.clear()
 
 
-def _family(total_at: int, buckets_at: int):
+def _family(total_at: int, buckets_at: "int | None" = None,
+            count_at: int = 0):
+    """A family's rows off the span rows, those it counted nothing in
+    left out; with no buckets kept for it (the CPU family) it renders
+    `+Inf` alone."""
     def rows():
-        return [(key, row[buckets_at], row[total_at] / 1e9, row[0])
-                for key, row in span_rows().items()]
+        return [(key, () if buckets_at is None else row[buckets_at],
+                 row[total_at] / 1e9, row[count_at])
+                for key, row in span_rows().items() if row[count_at]]
     return rows
 
 
@@ -191,6 +224,18 @@ RUNTIME.histogram_func(
          "covered by child spans opened on the same thread; the self "
          "times of a tree sum to its root's duration",
     labels=("span", "collect"), buckets=_BUCKETS_S)
+RUNTIME.histogram_func(
+    "tempo_span_cpu_seconds", _family(5, count_at=6),
+    help="CPU seconds (user + system) of the span's thread between the "
+         "span's start and end, for spans with no parent on their thread "
+         "(the others read no CPU clock): its work; mean duration less "
+         "mean CPU is its wait (the interpreter lock, another lock, disk, "
+         "socket, device), which a coarse host clock can turn negative "
+         "for spans of milliseconds",
+    labels=("span", "collect"))
+RUNTIME.counter_func(
+    "process_cpu_seconds_total", lambda: [((), time.process_time())],
+    help="Total user and system CPU time spent in seconds.")
 
 
 class _Span:
@@ -200,7 +245,7 @@ class _Span:
     __slots__ = ("trace_id", "span_id", "parent_span_id", "name",
                  "start_ns", "end_ns", "attrs", "status_code",
                  "_tracer", "_parent", "_token", "_thread", "_ann",
-                 "_t0", "_child_ns", "_met", "_epoch")
+                 "_t0", "_child_ns", "_c0", "_met", "_epoch")
 
     def __init__(self, tracer: "Tracer | None", name: str,
                  attrs: dict) -> None:
@@ -218,15 +263,21 @@ class _Span:
         self._token = _current_span.set(self)
         if self._tracer.exports:
             self._tracer._begin(self, parent)
-        self._thread = threading.get_ident()
+        self._thread = ident = threading.get_ident()
         self._met, self._epoch = _collect[0] > 0, _collect[1]
         self._ann = TraceAnnotation(self.name)
         self._ann.__enter__()
+        # the CPU clock is a system call: read at the root of a thread's
+        # tree alone, and outside the wall clock's pair
+        self._c0 = _cpu_clock() \
+            if parent is None or parent._thread != ident else None
         self._t0 = _clock()
         return self
 
     def __exit__(self, etype, exc, tb) -> None:
         dur = _clock() - self._t0
+        c0 = self._c0
+        cpu = None if c0 is None else _cpu_clock() - c0
         self._ann.__exit__(etype, exc, tb)
         _current_span.reset(self._token)
         ident = self._thread
@@ -249,6 +300,9 @@ class _Span:
         row[2] += self_ns
         row[3][bisect.bisect_left(_EDGES_NS, dur)] += 1
         row[4][bisect.bisect_left(_EDGES_NS, self_ns)] += 1
+        if cpu is not None:
+            row[5] += cpu
+            row[6] += 1
         if isinstance(exc, Exception):
             self.status_code = 2
             self.attrs["error.message"] = str(exc)[:200]

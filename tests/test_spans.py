@@ -3,6 +3,9 @@ label on `/metrics`, with nothing configured.
 
 - self time is duration less same-thread children, on an injected clock;
   a span closed on another thread than its parent adds to no parent;
+- the root of a thread's tree, and no span under it, reads a thread CPU
+  clock (an injected one) beside the wall clock; its CPU lands in the
+  row the duration lands in, and the real clocks tell a wait from work;
 - `collect` flips to `met` for a span that straddles a collection tick;
 - with no exporter a span takes no shared lock and draws no random bytes;
 - one served push yields exactly the expected span names, once each, and
@@ -12,6 +15,7 @@ label on `/metrics`, with nothing configured.
   pinned, so a refactor fails here instead of nulling a metric there.
 """
 
+import contextlib
 import contextvars
 import glob
 import json
@@ -72,6 +76,31 @@ def clock(monkeypatch):
     return c
 
 
+class _CpuClock:
+    """thread_time_ns that moves only when told to, a clock a thread as
+    the real one is: `tick` moves the calling thread's alone. `reads`
+    counts the calls: each is a system call on the real clock."""
+
+    def __init__(self) -> None:
+        self.t: dict = {}
+        self.reads = 0
+
+    def __call__(self) -> int:
+        self.reads += 1
+        return self.t.get(threading.get_ident(), 7_000)
+
+    def tick(self, ns: int) -> None:
+        ident = threading.get_ident()
+        self.t[ident] = self.t.get(ident, 7_000) + ns
+
+
+@pytest.fixture
+def cpu(monkeypatch):
+    c = _CpuClock()
+    monkeypatch.setattr(tracing, "_cpu_clock", c)
+    return c
+
+
 def _row(name: str, collect: str = "clear") -> list:
     return tracing.span_rows()[(name, collect)]
 
@@ -125,6 +154,122 @@ def test_span_closed_on_another_thread_adds_to_no_parent(clock):
     finally:
         tr.shutdown()
     assert _row("adoptee")[:3] == [1, 3, 3]
+
+
+@pytest.mark.parametrize("collect", ["clear", "met"])
+def test_cpu_lands_in_the_row_the_duration_lands_in(clock, cpu, collect):
+    """[..., CPU ns, spans that read the CPU clock] at the row's end,
+    under the `collect` value the duration got; no other row is made,
+    and the child, under a parent on its thread, reads no CPU clock."""
+    with tracing.span("worked"):
+        clock.tick(10)
+        cpu.tick(4)
+        with tracing.span("worked.child"):
+            clock.tick(5)
+            cpu.tick(1)
+            if collect == "met":
+                with tracing.collecting():
+                    pass
+    assert set(tracing.span_rows()) == {("worked", collect),
+                                        ("worked.child", collect)}
+    row = _row("worked", collect)
+    assert row[:3] == [1, 15, 10] and row[5:] == [5, 1]
+    assert _row("worked.child", collect)[5:] == [0, 0]
+    assert cpu.reads == 2
+
+
+def test_only_the_root_of_a_threads_tree_reads_the_cpu_clock(clock, cpu):
+    """A three-deep tree costs two reads of the CPU clock, not eight: the
+    root's CPU is all the CPU its tree had, and the wall clock's columns
+    are as they were without a CPU clock."""
+    for _ in range(2):
+        with tracing.span("root"):
+            cpu.tick(5)
+            clock.tick(50)              # a wait: wall moves, CPU does not
+            with tracing.span("mid"):
+                cpu.tick(7)
+                with tracing.span("leaf"):
+                    cpu.tick(11)
+                    clock.tick(11)
+                with tracing.span("leaf"):
+                    cpu.tick(13)
+                cpu.tick(17)
+            cpu.tick(19)
+    assert cpu.reads == 4
+    assert _row("root")[5:] == [144, 2]
+    assert _row("mid")[5:] == _row("leaf")[5:] == [0, 0]
+    assert _row("root")[:3] == [2, 122, 100]
+    assert _row("mid")[:3] == [2, 22, 0] and _row("leaf")[:3] == [4, 22, 22]
+
+
+def test_child_on_another_thread_reads_its_own_cpu(clock, cpu):
+    """A span whose parent is on another thread is the root of its own
+    thread's tree: it reads that thread's clock, and what it burnt there
+    was never on its parent's."""
+    with tracing.span("parent"):
+        ctx = contextvars.copy_context()
+
+        def work():
+            with tracing.span("beside"):
+                cpu.tick(100)
+                with tracing.span("beside.child"):
+                    cpu.tick(10)
+
+        t = threading.Thread(target=lambda: ctx.run(work))
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+        cpu.tick(1)
+    assert _row("beside")[5:] == [110, 1]
+    assert _row("beside.child")[5:] == [0, 0]
+    assert _row("parent")[5:] == [1, 1]
+
+
+def _cpu_step_ns() -> int:
+    """The step of the real thread CPU clock: nanoseconds on most hosts,
+    10 ms where the kernel keeps thread times by a ticker (the chip's
+    sealed host). The real-clock cases give their limits that much room."""
+    a = time.thread_time_ns()
+    end = time.perf_counter() + 0.1
+    while time.perf_counter() < end:
+        b = time.thread_time_ns()
+        if b != a:
+            return b - a
+    return 100_000_000
+
+
+def test_a_wait_reads_as_duration_and_no_cpu():
+    """The real clocks: a span parked on a lock another thread holds for
+    50 ms has the duration and none of the CPU."""
+    step = _cpu_step_ns()
+    lock = threading.Lock()
+    lock.acquire()
+    threading.Timer(0.05, lock.release).start()
+    with tracing.span("parked"):
+        assert lock.acquire(timeout=10)
+    row = _row("parked")
+    assert row[1] >= 50e6 and 0 <= row[5] < 10e6 + step and row[6] == 1
+
+
+def test_work_reads_as_cpu():
+    """The real clocks: a span around a 30 ms busy loop (ten steps of a
+    coarser clock) has its duration as CPU, within 30%. The machine is
+    shared, so the best of five."""
+    step = _cpu_step_ns()
+    busy_s = max(0.03, 10 * step / 1e9)
+    best = 0.0
+    for _ in range(5):
+        tracing.reset_span_rows()
+        with tracing.span("busy"):
+            end = time.perf_counter() + busy_s
+            while time.perf_counter() < end:
+                pass
+        row = _row("busy")
+        assert row[1] >= busy_s * 1e9 and row[5] <= row[1] + step + 20_000
+        best = max(best, row[5] / row[1])
+        if best >= 0.7:
+            break
+    assert best >= 0.7
 
 
 def test_collect_label_met_only_when_a_tick_overlaps(clock):
@@ -232,12 +377,16 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def _span_samples(text: str) -> dict:
-    """{family: {(span, collect): value}} of the two span families'
-    `_sum` and `_count` samples in a /metrics body."""
+WALL_FAMILIES = ("tempo_span_duration_seconds", "tempo_span_self_seconds")
+CPU_FAMILY = "tempo_span_cpu_seconds"
+
+
+def _span_samples(text: str, families=WALL_FAMILIES) -> dict:
+    """{family: {(span, collect): value}} of the span families' `_sum`
+    and `_count` samples in a /metrics body."""
     fams = parse_exposition(text)
     out: dict = {}
-    for fam in ("tempo_span_duration_seconds", "tempo_span_self_seconds"):
+    for fam in families:
         assert fams[fam]["type"] == "histogram"
         for (name, labels), v in fams[fam]["samples"].items():
             if name.endswith("_bucket"):
@@ -247,11 +396,11 @@ def _span_samples(text: str) -> dict:
     return out
 
 
-def test_one_served_push_yields_the_expected_spans(tmp_path):
-    """One OTLP push through the served App, nothing configured: every
-    layer of the write path shows once on /metrics, and the self times
-    sum to the durations of the roots (the request and the one coalesced
-    dispatch it caused on the scheduler's thread)."""
+@contextlib.contextmanager
+def _served_push(tmp_path):
+    """A `target: all` App served over HTTP, nothing configured; yields
+    `push()` (one OTLP push of four spans, through to the device) and
+    `metrics()` (the /metrics body)."""
     from tempo_tpu import sched
     from tempo_tpu.app import App
     from tempo_tpu.app.api import serve
@@ -269,42 +418,97 @@ def test_one_served_push_yields_the_expected_spans(tmp_path):
     app = App(cfg)
     srv = serve(app, block=False)
     base = f"http://127.0.0.1:{port}"
-    try:
-        assert not tracing.tracer().exports
+
+    def push() -> None:
         t0 = int((time.time() - 3) * 1e9)
         payload = encode_spans_otlp([dict(
             trace_id=bytes([i + 1]) * 16, span_id=bytes([i + 1]) * 8,
             name="op", service="svc", kind=2, status_code=0,
             start_unix_nano=t0, end_unix_nano=t0 + 10**6,
             res_attrs={"service.name": "svc"}) for i in range(4)])
-        tracing.reset_span_rows()
         req = urllib.request.Request(
             f"{base}/v1/traces", data=payload,
             headers={"Content-Type": "application/x-protobuf"})
         urllib.request.urlopen(req, timeout=60).close()
         sched.flush()
+
+    def metrics() -> str:
         with urllib.request.urlopen(f"{base}/metrics", timeout=30) as r:
-            got = _span_samples(r.read().decode())
+            return r.read().decode()
+
+    try:
+        assert not tracing.tracer().exports
+        yield push, metrics
     finally:
         srv.shutdown()
         app.shutdown()
-    request_tree = {"api.push", "distributor.admit", "distributor.decode",
-                    "distributor.PushSpans", "ingester.push",
-                    "distributor.GeneratorTee", "distributor.turn",
-                    "generator.Push", "spanmetrics.push", "generator.resolve",
-                    "servicegraphs.push", "localblocks.push"}
-    dispatch_tree = {"sched.dispatch", "sched.h2d", "sched.enqueue"}
+
+
+REQUEST_TREE = {"api.push", "distributor.admit", "distributor.decode",
+                "distributor.PushSpans", "ingester.push",
+                "distributor.GeneratorTee", "distributor.turn",
+                "generator.Push", "spanmetrics.push", "generator.resolve",
+                "servicegraphs.push", "localblocks.push"}
+DISPATCH_TREE = {"sched.dispatch", "sched.h2d", "sched.enqueue"}
+
+
+def test_one_served_push_yields_the_expected_spans(tmp_path):
+    """One OTLP push through the served App, nothing configured: every
+    layer of the write path shows once on /metrics, and the self times
+    sum to the durations of the roots (the request and the one coalesced
+    dispatch it caused on the scheduler's thread)."""
+    with _served_push(tmp_path) as (push, metrics):
+        tracing.reset_span_rows()
+        push()
+        got = _span_samples(metrics())
     counts = got["tempo_span_duration_seconds_count"]
     assert counts == {(n, "clear"): 1.0
-                      for n in request_tree | dispatch_tree}
+                      for n in REQUEST_TREE | DISPATCH_TREE}
     assert got["tempo_span_self_seconds_count"] == counts
     dur = got["tempo_span_duration_seconds_sum"]
     self_s = got["tempo_span_self_seconds_sum"]
     roots = dur[("api.push", "clear")] + dur[("sched.dispatch", "clear")]
     assert abs(sum(self_s.values()) - roots) <= 1e-6 * len(counts)
     # and per tree
-    assert abs(sum(self_s[(n, "clear")] for n in request_tree)
-               - dur[("api.push", "clear")]) <= 1e-6 * len(request_tree)
+    assert abs(sum(self_s[(n, "clear")] for n in REQUEST_TREE)
+               - dur[("api.push", "clear")]) <= 1e-6 * len(REQUEST_TREE)
+
+
+def test_cpu_family_and_the_process_counter_are_on_metrics(tmp_path):
+    """The CPU family has the two roots of a served push (the request on
+    its thread, the dispatch on the scheduler's) and no span under them,
+    each counted once; a root has no more CPU than duration (two clocks:
+    the CPU clock's step may part them); and `process_cpu_seconds_total`
+    is there, upstream's name, and does not fall between two scrapes."""
+    with _served_push(tmp_path) as (push, metrics):
+        tracing.reset_span_rows()
+        push()
+        first = metrics()
+        second = metrics()
+    got = _span_samples(first, WALL_FAMILIES + (CPU_FAMILY,))
+    counts = got["tempo_span_duration_seconds_count"]
+    assert set(counts) == {(n, "clear")
+                           for n in REQUEST_TREE | DISPATCH_TREE}
+    assert got["tempo_span_self_seconds_count"] == counts
+    assert got[CPU_FAMILY + "_count"] == {("api.push", "clear"): 1.0,
+                                          ("sched.dispatch", "clear"): 1.0}
+    dur = got["tempo_span_duration_seconds_sum"]
+    slack = _cpu_step_ns() / 1e9 + 2e-5
+    for key, cpu in got[CPU_FAMILY + "_sum"].items():
+        assert 0.0 <= cpu <= dur[key] * 1.01 + slack
+    assert "tempo_span_self_cpu_seconds" not in parse_exposition(first)
+    # the CPU family keeps no buckets: `+Inf` alone
+    buckets = [dict(labels)["le"] for (name, labels) in parse_exposition(
+        first)[CPU_FAMILY]["samples"] if name.endswith("_bucket")]
+    assert set(buckets) == {"+Inf"}
+    fams = [parse_exposition(t)["process_cpu_seconds_total"]
+            for t in (first, second)]
+    assert fams[0]["type"] == "counter"
+    a, b = (f["samples"][("process_cpu_seconds_total", ())] for f in fams)
+    assert 0.0 < a <= b
+    # every span under the rows is one of the frozen names, as ever
+    assert len(SPAN_NAMES) == 39
+    assert {n for n, _ in counts} <= SPAN_NAMES
 
 
 def test_span_label_values_are_a_frozen_list():
