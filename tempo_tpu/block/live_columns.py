@@ -1,16 +1,17 @@
-"""Live traces as column slices of the pushed batch, and the WAL segment
+"""Live traces as the chunks of the pushed batches, and the WAL segment
 cut from those columns.
 
 A staged push reaches the live store as columns (`SpanBatch`, with the
 staging's exact id lengths and its lazy events/links pass where the push
-came through `StagedIngest`). The store keeps them as they are: a live
-trace holds SEGMENTS, and a segment from a staged push is `(source,
-rows)`, a row slice of that push's columns. Every other route (Jaeger,
-Zipkin, gRPC, replay, the tests) hands the store span dicts, which stay
-the other kind of segment. Both kinds meet in `cut_table`, which builds
-the one arrow table a sweep appends to the WAL: the reference ingester
-likewise keeps a live trace's pushed bytes undecoded until the cut
-(`modules/ingester/instance.go` `PushBytes`).
+came through `StagedIngest`). The store keeps them as they are: the push
+enters it as ONE `ColumnChunk`, its rows grouped by exact trace id, each
+trace's slot beside them (`utils.livetraces.LiveTraceStore.push_chunk`).
+Every other route (Jaeger, Zipkin, gRPC, replay, the tests) hands the
+store span dicts, kept per trace as segments, where a staged push's rows
+for the same trace join as a `ColumnSegment`. Both kinds meet in
+`cut_table`, which builds the one arrow table a sweep appends to the WAL:
+the reference ingester likewise keeps a live trace's pushed bytes
+undecoded until the cut (`modules/ingester/instance.go` `PushBytes`).
 
 `cut_table` equals, column for column and row for row, what
 `schema.traces_to_table(spans_by_trace(sort_spans(combine_spans(...))))`
@@ -31,13 +32,15 @@ from tempo_tpu.model.combine import combine_spans, sort_spans
 from tempo_tpu.model.interner import INVALID_ID
 from tempo_tpu.model.span_batch import (
     ATTR_BOOL, ATTR_DOUBLE, ATTR_INT, ATTR_STRING, SpanBatch)
-from tempo_tpu.utils.livetraces import segment_spans
+from tempo_tpu.utils.livetraces import (
+    CUT_SPANS, TraceSet, key_of, key_trace_id, segment_spans)
 
 
 class ColumnSource:
-    """One staged push as the live store keeps it, shared by the segments
-    of every trace it fed. `staged` (a `StagedIngest`) carries what the
-    SpanBatch pads away: id byte lengths, events and links."""
+    """One staged push as the live store keeps it, shared by its chunk and
+    by the column segments cut from it. `staged` (a `StagedIngest`)
+    carries what the SpanBatch pads away: id byte lengths, events and
+    links."""
 
     __slots__ = ("batch", "staged")
 
@@ -61,14 +64,13 @@ class ColumnSource:
         return (np.minimum(recs["sid_len"][rows], 8),
                 np.minimum(recs["pid_len"][rows], 8))
 
-    def trace_groups(self, rows: "np.ndarray | None"
-                     ) -> list[tuple[bytes, "ColumnSegment", int]]:
-        """`rows` (None: every row of the batch) grouped by exact trace
-        id, first-seen order kept: one (trace id, segment, approximate
-        bytes) a trace. The bytes are `livetraces._approx_size` of the
-        same spans as dicts, 200 + 32 x attrs a span, counted off the
-        attr-key columns in one pass (a key a span repeats counts each
-        time; a dict keeps it once).
+    def chunk(self, rows: "np.ndarray | None") -> "ColumnChunk | None":
+        """`rows` (None: every row of the batch) grouped by exact trace id,
+        first-seen order kept, as one chunk (None: no row). A trace's
+        approximate bytes are `livetraces._approx_size` of the same spans
+        as dicts, 200 + 32 x attrs a span, counted off the attr-key
+        columns in one pass (a key a span repeats counts each time; a dict
+        keeps it once).
 
         Few numpy calls on purpose: under four request threads every
         call over 500 elements hands the interpreter over, and the wait
@@ -77,7 +79,7 @@ class ColumnSource:
         pick = slice(0, sb.n) if rows is None else rows
         n = sb.n if rows is None else len(rows)
         if not n:
-            return []
+            return None
         # the exact id is (padded bytes, length): one 17-byte key a row
         keys = np.empty((n, 17), np.uint8)
         keys[:, :16] = sb.trace_id[pick]
@@ -89,20 +91,40 @@ class ColumnSource:
         attrs = np.bincount(inverse, weights=(np.concatenate(
             (sb.span_attr_key[pick], sb.res_attr_key[pick]), axis=1)
             != INVALID_ID).sum(axis=1))
-        sizes = (200 * spans + 32 * attrs.astype(np.int64)).tolist()
-        seg_rows = order if rows is None else rows[order]
-        out = []
-        at = 0
-        for key, end, sz in zip(keys[first].tolist(),
-                                np.cumsum(spans).tolist(), sizes):
-            out.append((bytes(key[:key[16]]),
-                        ColumnSegment(self, seg_rows[at:end]), sz))
-            at = end
-        return out
+        return ColumnChunk(self, order if rows is None else rows[order],
+                           keys[first], spans,
+                           200 * spans + 32 * attrs.astype(np.int64))
+
+
+class ColumnChunk:
+    """One staged push in a live store: `rows` of `source` grouped by
+    trace (a trace's rows contiguous, in push order; traces in first-seen
+    order), each trace's index `keys` ([17] uint8), span count `spans` and
+    approximate `sizes`. The store sets `row_slot` (each row's trace slot;
+    the rows of refused traces leave) and `seq` (its arrival number, which
+    orders a trace's chunks)."""
+
+    __slots__ = ("source", "rows", "keys", "spans", "sizes", "row_slot",
+                 "seq")
+
+    def __init__(self, source: ColumnSource, rows: np.ndarray,
+                 keys: np.ndarray, spans: np.ndarray,
+                 sizes: np.ndarray) -> None:
+        self.source = source
+        self.rows = rows
+        self.keys = keys
+        self.spans = spans
+        self.sizes = sizes
+        self.row_slot = None
+        self.seq = -1
+
+    def segment(self, rows: np.ndarray) -> "ColumnSegment":
+        return ColumnSegment(self.source, rows)
 
 
 class ColumnSegment:
-    """One trace's rows of one staged push."""
+    """Rows of one staged push that belong to one trace: how a trace that
+    also holds span dicts keeps them, and how a read sees a chunk."""
 
     __slots__ = ("source", "rows")
 
@@ -121,24 +143,25 @@ class ColumnSegment:
 # the cut: live traces -> one arrow table
 # ---------------------------------------------------------------------------
 
-def cut_table(cut: Sequence, dedicated: Sequence[Any] = ()) -> pa.Table | None:
+def cut_table(cut: TraceSet, dedicated: Sequence[Any] = ()) -> pa.Table | None:
     """The WAL segment of one sweep: every cut live trace, deduplicated
     by span id (first wins), spans ordered by start time, traces by id.
     None when the cut holds no span."""
-    col_traces, flat = _split_routes(cut)
+    cols, flat, n_dicts = _split_routes(cut)
     groups = bs.spans_by_trace(flat)
     tables = []
-    keys: list[bytes] = []
-    if col_traces:
-        col_traces.sort(key=lambda lt: lt.trace_id)
-        keys = [lt.trace_id for lt in col_traces]
-        tables.append(_column_traces_table(col_traces, dedicated))
+    if cols is not None:
+        parts, trank, arrival, tkeys = cols
+        tables.append(_column_traces_table(parts, trank, arrival, dedicated))
+        CUT_SPANS.inc(len(trank), ("columns",))
+    CUT_SPANS.inc(n_dicts, ("dicts",))
     if groups:
         tables.append(bs.traces_to_table(groups, dedicated))
     if len(tables) < 2:
         return tables[0] if tables else None
     # both kinds in one sweep: whole traces interleave by trace id, and
     # only `trace_idx` knows about the other table
+    keys = [key_trace_id(k) for k in tkeys]
     dkeys = [k for k, _ in groups]
     rank = {k: i for i, k in enumerate(sorted(keys + dkeys))}
     merged = pa.concat_tables([
@@ -148,38 +171,77 @@ def cut_table(cut: Sequence, dedicated: Sequence[Any] = ()) -> pa.Table | None:
     return merged.take(pa.array(order)).combine_chunks()
 
 
-def _split_routes(cut: Sequence) -> tuple[list, list[dict]]:
-    """(traces held as columns only, the flat span dicts of the rest in
-    cut order). A trace goes the dict route when any of its segments is
-    dicts, when a dict span elsewhere in the sweep claims its trace id
-    (`spans_by_trace` would merge the two), or when its columns were
-    staged against another interner than the sweep's first."""
-    def dicts(lt) -> list[dict]:
-        return sort_spans(combine_spans(segment_spans(lt.segments)))
+def _split_routes(cut: TraceSet):
+    """(the column route, the flat span dicts of the rest in slot order,
+    the number of spans the rest held before `combine_spans`). The column
+    route is None, or (parts, trank, arrival, keys): `parts`
+    [(source, rows)], each row's trace rank by trace id and arrival (its
+    chunk's number), the traces' keys in rank order. A trace goes the dict
+    route when any of its segments is dicts (a LiveTrace), when a dict
+    span in the sweep claims its trace id (`spans_by_trace` would merge
+    the two), or when a chunk of it was staged against another interner
+    than the sweep's first chunk."""
+    parts, trace = cut.column_rows()
+    by_slot = []        # (slot, spans, spans before combine) of the dicts
+    for lt in cut.dicts:
+        spans = segment_spans(lt.segments)
+        by_slot.append((lt.slot, sort_spans(combine_spans(spans)), len(spans)))
+    demote = np.zeros(len(cut.pos), bool)
+    if parts:
+        interner = parts[0][0].source.batch.interner
+        at = 0
+        for c, rows in parts:
+            if c.source.batch.interner is not interner:
+                demote[trace[at:at + len(rows)]] = True
+            at += len(rows)
+    if by_slot and len(cut.pos):
+        claimed = {key_of(bytes(s.get("trace_id", b"")))
+                   for _, spans, _ in by_slot for s in spans}
+        demote |= np.fromiter(
+            (k.tobytes() in claimed for k in cut.keys), bool, len(cut.pos))
+    if demote.any():
+        # such traces are few: each takes its rows as dicts, arrival order
+        mine: dict[int, list[dict]] = {}
+        at = 0
+        for c, rows in parts:
+            t = trace[at:at + len(rows)]
+            at += len(rows)
+            hit = demote[t]
+            for i, s in zip(t[hit].tolist(),
+                            c.source.span_dicts(rows[hit])):
+                mine.setdefault(i, []).append(s)
+        for i, spans in mine.items():
+            by_slot.append((cut.base + int(cut.pos[i]),
+                            sort_spans(combine_spans(spans)), len(spans)))
+        kept, at = [], 0
+        for c, rows in parts:
+            t = trace[at:at + len(rows)]
+            at += len(rows)
+            if (~demote[t]).any():
+                kept.append((c, rows[~demote[t]]))
+        parts, trace = kept, trace[~demote[trace]]
+    by_slot.sort(key=lambda x: x[0])
+    flat = [s for _, spans, _ in by_slot for s in spans]
+    n_dicts = sum(n for _, _, n in by_slot)
+    if not parts:
+        return None, flat, n_dicts
+    # trace ranks by exact id: padded bytes, then length, orders as the
+    # ids do (a shorter id that is a prefix of a longer one comes first)
+    keys = cut.keys
+    live = np.flatnonzero(~demote)
+    k = keys[live]
+    order = live[np.lexsort((k[:, 16], _u64(k[:, 8:16]), _u64(k[:, :8])))]
+    rank = np.full(len(keys), -1, np.int64)
+    rank[order] = np.arange(len(order))
+    arrival = np.concatenate([np.full(len(rows), c.seq, np.int64)
+                              for c, rows in parts])
+    return ([(c.source, rows) for c, rows in parts], rank[trace], arrival,
+            keys[order]), flat, n_dicts
 
-    interner = None
-    mixed = []
-    for lt in cut:
-        cols = True
-        for seg in lt.segments:
-            if not isinstance(seg, ColumnSegment):
-                cols = False
-            elif interner is None:
-                interner = seg.source.batch.interner
-            elif seg.source.batch.interner is not interner:
-                cols = False
-        mixed.append(None if cols else dicts(lt))
-    claimed = {bytes(s.get("trace_id", b""))
-               for spans in mixed if spans is not None for s in spans}
-    col_traces, flat = [], []
-    for lt, spans in zip(cut, mixed):
-        if spans is None and lt.trace_id in claimed:
-            spans = dicts(lt)
-        if spans is None:
-            col_traces.append(lt)
-        else:
-            flat.extend(spans)
-    return col_traces, flat
+
+def _u64(cols: np.ndarray) -> np.ndarray:
+    """[n, 8] uint8 as big-endian uint64: byte order is number order."""
+    return np.ascontiguousarray(cols).view(">u8").ravel()
 
 
 def _with_trace_idx(table: pa.Table, ranks: list[int]) -> pa.Table:
@@ -188,24 +250,13 @@ def _with_trace_idx(table: pa.Table, ranks: list[int]) -> pa.Table:
                             table.schema.field("trace_idx"), pa.array(idx))
 
 
-def _column_traces_table(traces: Sequence, dedicated: Sequence[Any]
+def _column_traces_table(parts: Sequence, trank: np.ndarray,
+                         arrival: np.ndarray, dedicated: Sequence[Any]
                          ) -> pa.Table:
-    """Arrow table of `traces` (sorted by trace id) whose every segment
-    is a `ColumnSegment` over one interner."""
-    # gather: per source, the rows of every segment cut from it, with the
-    # trace each belongs to and the segment's place in its trace (arrival)
-    by_src: dict[int, list] = {}
-    for t, lt in enumerate(traces):
-        for j, seg in enumerate(lt.segments):
-            by_src.setdefault(id(seg.source), []).append((seg, t, j))
-    parts, trank, arrival = [], [], []
-    for group in by_src.values():
-        lens = [len(seg) for seg, _, _ in group]
-        parts.append((group[0][0].source,
-                      np.concatenate([seg.rows for seg, _, _ in group])))
-        trank.append(np.repeat([t for _, t, _ in group], lens))
-        arrival.append(np.repeat([j for _, _, j in group], lens))
-    trank, arrival = np.concatenate(trank), np.concatenate(arrival)
+    """Arrow table of column traces over one interner: `parts` [(source,
+    rows)], and for their rows concatenated the trace's rank by id and the
+    arrival (its push's number; rows of one trace in one push are in push
+    order)."""
 
     def col(name: str) -> np.ndarray:
         return np.concatenate([getattr(src.batch, name)[rows]

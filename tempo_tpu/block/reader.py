@@ -127,9 +127,9 @@ class BackendBlock:
                 tbl = pf.read_row_group(rg)
             querystats.add(inspected_bytes=tbl.nbytes,
                            inspected_spans=tbl.num_rows)
-            sel = np.asarray(tbl.column("trace_id").to_numpy(zero_copy_only=False)) == tid
-            if sel.any():
-                out.extend(_rows_to_spans(tbl, np.flatnonzero(sel)))
+            rows = trace_id_rows(tbl, tid)
+            if len(rows):
+                out.extend(_rows_to_spans(tbl, rows))
         return out or None
 
     # -- columnar scan -----------------------------------------------------
@@ -170,6 +170,20 @@ def pa_is_fixed(t) -> bool:
     import pyarrow as pa
 
     return not (pa.types.is_list(t) or pa.types.is_large_list(t))
+
+
+def trace_id_rows(tbl, trace_id: bytes) -> np.ndarray:
+    """Rows of `tbl` whose 16-byte trace id is `trace_id` (zero-padded),
+    matched on the column's buffer: a numpy compare of the column's bytes
+    objects with a bytes value drops that value's trailing zero bytes, so
+    an id ending in one was never found."""
+    want = np.frombuffer(bytes(trace_id).ljust(16, b"\0")[:16], np.uint8)
+    parts = [np.frombuffer(ch.buffers()[1], np.uint8)[
+        16 * ch.offset:16 * (ch.offset + len(ch))]
+        for ch in tbl.column("trace_id").chunks]
+    ids = (np.concatenate(parts) if parts
+           else np.zeros(0, np.uint8)).reshape(-1, 16)
+    return np.flatnonzero((ids == want).all(axis=1))
 
 
 def _rows_to_spans(tbl, rows: np.ndarray) -> list[dict]:

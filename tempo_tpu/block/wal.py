@@ -22,7 +22,7 @@ from typing import Iterable, Iterator
 import pyarrow.parquet as pq
 
 from tempo_tpu.block import schema as bs
-from tempo_tpu.block.reader import _rows_to_spans
+from tempo_tpu.block.reader import _rows_to_spans, trace_id_rows
 
 import numpy as np
 
@@ -82,12 +82,15 @@ class WALBlock:
         except FileNotFoundError:
             return []  # cleared by a concurrent completion — read as empty
 
-    def iter_spans(self) -> Iterator[dict]:
+    def _tables(self) -> Iterator:
         for seg in self.segments():
             try:
-                tbl = pq.read_table(os.path.join(self.dir, seg))
+                yield pq.read_table(os.path.join(self.dir, seg))
             except Exception:
                 continue  # torn segment: skip, like RescanBlocks tolerates
+
+    def iter_spans(self) -> Iterator[dict]:
+        for tbl in self._tables():
             yield from _rows_to_spans(tbl, np.arange(tbl.num_rows))
 
     def complete(self) -> list[tuple[bytes, list[dict]]]:
@@ -96,9 +99,15 @@ class WALBlock:
         return bs.spans_by_trace(self.iter_spans())
 
     def find_trace_by_id(self, trace_id: bytes) -> list[dict] | None:
-        tid = bytes(trace_id).ljust(16, b"\0")[:16]
-        out = [s for s in self.iter_spans()
-               if bytes(s["trace_id"]).ljust(16, b"\0")[:16] == tid]
+        """The trace's spans in segment order, or None. Each segment's
+        trace-id column is matched first and only the trace's rows become
+        dicts (a head block holds millions of spans under the write
+        stress, a trace a handful)."""
+        out: list[dict] = []
+        for tbl in self._tables():
+            rows = trace_id_rows(tbl, trace_id)
+            if len(rows):
+                out.extend(_rows_to_spans(tbl, rows))
         return out or None
 
     def clear(self) -> None:

@@ -76,8 +76,8 @@ class LocalBlocksProcessor:
 
     def push_batch(self, sb: SpanBatch) -> None:
         """Group the batch back by trace and append to live traces
-        (deterministic, `processor.go:155`): each trace keeps a column
-        slice of the batch, no span dicts."""
+        (deterministic, `processor.go:155`): the batch enters the store
+        as one chunk, no span dicts."""
         valid = sb.valid[: sb.n]
         self.inst.push_columns(
             ColumnSource(sb), None if valid.all() else np.flatnonzero(valid))

@@ -161,8 +161,8 @@ class Ingester:
         replica's traces arrive as a row-index slice over the shared
         columnar staging (`model.otlp_batch.StagedView`), and the live
         store keeps them so: the rows group by the trace-id column and
-        each trace gets a column slice of the push
-        (`block.live_columns`). No per-replica protobuf re-decode and no
+        the push enters the store as ONE chunk (`block.live_columns.
+        ColumnChunk`). No per-replica protobuf re-decode and no
         span dict before a read or a cut asks for one. Same return
         contract as `push_otlp`: {trace_id_hex: reason} for rejected
         traces only."""

@@ -33,9 +33,9 @@ from tempo_tpu.utils.livetraces import (
     ERR_LIVE_TRACES_EXCEEDED,
     ERR_TRACE_TOO_LARGE,
     LIVE_SPANS,
-    LiveTrace,
     LiveTraceStore,
-    segment_spans,
+    TraceSet,
+    key_trace_id,
 )
 
 PUSH_ERRORS = (ERR_LIVE_TRACES_EXCEEDED, ERR_TRACE_TOO_LARGE)
@@ -80,7 +80,7 @@ class TenantInstance:
     segment, so the reads look there too. None when no sweep is between
     its two holds of `lock`. Nobody appends to a trace once it is cut
     (spans that arrive for its id start a new live trace), so readers
-    use what they snapshot under `lock` after releasing it."""
+    use what they take under `lock` (`TraceSet`s) after releasing it."""
 
     def __init__(self, tenant: str, wal_dir: str, local_dir: str,
                  cfg: InstanceConfig | None = None,
@@ -102,7 +102,7 @@ class TenantInstance:
         self.head_created = 0.0
         self.completing: list[WALBlock] = []     # cut, awaiting completion
         self.complete: dict[str, LocalBlockEntry] = {}
-        self.cutting: list[LiveTrace] | None = None
+        self.cutting: TraceSet | None = None
         self.lock = threading.RLock()
         self.sweep_lock = threading.Lock()
         self.discarded: dict[str, int] = {}
@@ -120,22 +120,21 @@ class TenantInstance:
 
     def push_columns(self, source: ColumnSource,
                      rows) -> dict[bytes, str]:
-        """Append `rows` of a staged push (None: all of them), a column
-        slice a trace; returns {trace_id: PushErrorReason} for the traces
-        that were refused."""
-        groups = source.trace_groups(rows)
-        out: dict[bytes, str] = {}
-        kept = 0
+        """Append `rows` of a staged push (None: all of them) as ONE chunk
+        (grouped by trace outside the lock); returns {trace_id:
+        PushErrorReason} for the traces that were refused."""
+        chunk = source.chunk(rows)
+        if chunk is None:
+            return {}
+        spans = int(chunk.spans.sum())
         with self.lock:
-            for tid, seg, size in groups:
-                err = self.live.push_columns(tid, seg, size)
-                if err:
-                    self.discarded[err] = self.discarded.get(err, 0) + 1
-                    out[tid] = err
-                else:
-                    kept += len(seg)
-        LIVE_SPANS.inc(kept, ("columns",))
-        return out
+            refused = self.live.push_chunk(chunk)
+            for err in refused.values():
+                self.discarded[err] = self.discarded.get(err, 0) + 1
+        LIVE_SPANS.inc(spans - int(chunk.spans[list(refused)].sum()),
+                       ("columns",))
+        return {key_trace_id(chunk.keys[i]): err
+                for i, err in refused.items()}
 
     def cut_complete_traces(self, immediate: bool = False) -> int:
         """Idle/aged live traces → head WAL block (`CutCompleteTraces`).
@@ -165,8 +164,11 @@ class TenantInstance:
             finally:
                 # a failed write loses what it lost under the lock; it
                 # must not leave the traces published for ever
+                self.live.forget(cut)
+                spent = cut.spent_chunks()
                 with self.lock, tracing.span("instance.cut_locked"):
                     self.cutting = None
+                    self.live.drop_chunks(spent)
             return len(cut)
 
     def head_bytes(self) -> int:
@@ -277,15 +279,14 @@ class TenantInstance:
         in both: `combine_spans` keeps one span a span id."""
         parts: list[list[dict]] = []
         with self.lock:
-            lt = self.live.traces.get(trace_id)
-            live = lt.snapshot() if lt else None
-            cutting = self.cutting or ()
+            live = self.live.view(trace_id)
+            cutting = self.cutting
             heads = self._wal_blocks()
             complete = list(self.complete.values())
-        if live:
-            parts.append(segment_spans(live))
-        parts.extend(segment_spans(lt.segments) for lt in cutting
-                     if lt.trace_id == trace_id)
+        for held in (live, cutting):
+            spans = held.spans_of(trace_id) if held is not None else None
+            if spans:
+                parts.append(spans)
         for wb in heads:
             spans = wb.find_trace_by_id(trace_id)
             if spans:
@@ -302,16 +303,13 @@ class TenantInstance:
         """Snapshot of live + cutting + WAL data as (trace_id, spans)
         groups, for vectorized search over an in-memory ColumnView."""
         with self.lock:
-            live = [(tid, lt.snapshot())
-                    for tid, lt in self.live.traces.items()]
-            cutting = self.cutting or ()
+            live = self.live.view()
+            cutting = self.cutting
             heads = self._wal_blocks()
-        # column segments turn into dicts here, outside the lock
-        by_id: dict[bytes, list[dict]] = {
-            tid: segment_spans(segs) for tid, segs in live}
-        for lt in cutting:
-            by_id.setdefault(lt.trace_id, []).extend(
-                segment_spans(lt.segments))
+        # columns turn into dicts here, outside the lock
+        by_id: dict[bytes, list[dict]] = dict(live.groups())
+        for tid, spans in cutting.groups() if cutting is not None else ():
+            by_id.setdefault(tid, []).extend(spans)
         for wb in heads:
             for s in wb.iter_spans():
                 by_id.setdefault(s["trace_id"], []).append(s)

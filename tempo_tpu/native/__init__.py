@@ -271,6 +271,15 @@ def _load():
                 c.c_void_p, c.c_int64, c.c_int64, c.c_int64, c.c_int64,
                 u8p, i32p, i32p]
             lib.group_keys_strided.restype = c.c_int64
+            # live trace index
+            lib.tindex_new.restype = c.c_void_p
+            lib.tindex_free.argtypes = [c.c_void_p]
+            for name in ("tindex_lookup", "tindex_upsert", "tindex_discard"):
+                getattr(lib, name).argtypes = [
+                    c.c_void_p, c.c_void_p, c.c_int64, c.c_void_p]
+                getattr(lib, name).restype = None
+            lib.tindex_size.argtypes = [c.c_void_p]
+            lib.tindex_size.restype = c.c_int64
             _LIB = lib
         except Exception:
             _LIB = None
@@ -527,6 +536,90 @@ class NativeRowTable:
 
     def size(self) -> int:
         return int(self._lib.rowtable_size(self._h))
+
+
+class TraceIndex:
+    """Exact trace key -> int64 slot of a live store: a key is a [17]
+    uint8 row, the id zero-padded to 16 bytes, then its length (the key a
+    push is grouped by). A call takes a whole push's or cut's keys (one
+    key where a dict route pushes beside staged traces).
+    `discard` forgets a key only where it still names the given slot, so
+    a sweep may discard outside the store's lock while pushes upsert.
+    Without the native library (or with `use_native=False`) a dict under
+    a lock does the same."""
+
+    def __init__(self, use_native: bool = True) -> None:
+        self._lib = _load() if use_native else None
+        if self._lib is None:
+            self._d: dict[bytes, int] = {}
+            self._lock = threading.Lock()
+        else:
+            self._h = ctypes.c_void_p(self._lib.tindex_new())
+
+    def __del__(self) -> None:
+        h = getattr(self, "_h", None)
+        if h and self._lib is not None:
+            try:
+                self._lib.tindex_free(h)
+            except Exception:
+                pass
+
+    def __len__(self) -> int:
+        if self._lib is None:
+            return len(self._d)
+        return int(self._lib.tindex_size(self._h))
+
+    def lookup(self, keys: np.ndarray) -> np.ndarray:
+        """The slot of each key, -1 where it has none."""
+        keys = _trace_keys(keys)
+        out = np.empty(len(keys), np.int64)
+        if self._lib is None:
+            with self._lock:
+                out[:] = list(map(self._d.get, _key_bytes(keys),
+                                  [-1] * len(keys)))
+        elif len(keys):
+            self._lib.tindex_lookup(self._h, keys.ctypes.data, len(keys),
+                                    out.ctypes.data)
+        return out
+
+    def upsert(self, keys: np.ndarray, slots: np.ndarray) -> None:
+        keys = _trace_keys(keys)
+        slots = np.ascontiguousarray(slots, np.int64)
+        if slots.shape != (len(keys),):
+            raise ValueError("one slot a key")
+        if self._lib is None:
+            with self._lock:
+                self._d.update(zip(_key_bytes(keys), slots.tolist()))
+        elif len(keys):
+            self._lib.tindex_upsert(self._h, keys.ctypes.data, len(keys),
+                                    slots.ctypes.data)
+
+    def discard(self, keys: np.ndarray, slots: np.ndarray) -> None:
+        keys = _trace_keys(keys)
+        slots = np.ascontiguousarray(slots, np.int64)
+        if slots.shape != (len(keys),):
+            raise ValueError("one slot a key")
+        if self._lib is None:
+            with self._lock:
+                for k, s in zip(_key_bytes(keys), slots.tolist()):
+                    if self._d.get(k) == s:
+                        del self._d[k]
+        elif len(keys):
+            self._lib.tindex_discard(self._h, keys.ctypes.data, len(keys),
+                                     slots.ctypes.data)
+
+
+def _trace_keys(keys: np.ndarray) -> np.ndarray:
+    keys = np.ascontiguousarray(keys, np.uint8)
+    if keys.ndim != 2 or keys.shape[1] != 17:
+        raise ValueError(f"trace keys are [n, 17] uint8, not {keys.shape}")
+    return keys
+
+
+def _key_bytes(keys: np.ndarray) -> list[bytes]:
+    """Each [17] key row as one bytes object (a void view keeps trailing
+    zero bytes)."""
+    return keys.view(f"V{keys.shape[1]}").ravel().tolist()
 
 
 def otlp_stage(interner: "NativeInterner", data: bytes,

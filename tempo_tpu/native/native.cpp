@@ -1668,3 +1668,128 @@ int64_t group_keys_strided(const void* recs_p, int64_t n, int64_t rec_size,
 }
 
 }  // extern "C"
+
+// --- live trace index ---------------------------------------------------------
+//
+// Exact trace key -> int64 slot of a live store (`utils/livetraces.py`).
+// A key is 17 bytes: the id zero-padded to 16, then its length (the key
+// `group_keys` groups a push by). Lookup, upsert and discard take a whole
+// push's or a whole cut's keys in one call, so neither runs a Python step a
+// trace. Open addressing with tombstones, under its own mutex: a ctypes
+// call gives the interpreter up, and the cut discards outside the store's
+// lock.
+
+namespace {
+
+constexpr int64_t kTxEmpty = -1;
+constexpr int64_t kTxGone = -2;
+
+struct TraceIndex {
+    std::mutex mu;
+    std::vector<uint8_t> keys;     // cell i -> keys[17 i .. 17 i + 17)
+    std::vector<int64_t> vals;     // cell i -> slot, kTxEmpty or kTxGone
+    uint64_t mask = 0;
+    int64_t live = 0;
+    int64_t used = 0;              // live cells + tombstones
+
+    TraceIndex() { reset(1 << 12); }
+
+    void reset(size_t cap) {
+        keys.assign(cap * 17, 0);
+        vals.assign(cap, kTxEmpty);
+        mask = cap - 1;
+        live = used = 0;
+    }
+
+    static inline uint64_t hash(const uint8_t* k) {
+        uint64_t a, b;
+        memcpy(&a, k, 8);
+        memcpy(&b, k + 8, 8);
+        uint64_t x = a ^ (b * 0x9E3779B97F4A7C15ull) ^ ((uint64_t)k[16] << 56);
+        x ^= x >> 30; x *= 0xBF58476D1CE4E5B9ull;
+        x ^= x >> 27; x *= 0x94D049BB133111EBull;
+        return x ^ (x >> 31);
+    }
+
+    // the cell that holds `k`, or -1
+    int64_t find(const uint8_t* k) const {
+        uint64_t i = hash(k) & mask;
+        while (vals[i] != kTxEmpty) {
+            if (vals[i] != kTxGone && memcmp(&keys[i * 17], k, 17) == 0)
+                return (int64_t)i;
+            i = (i + 1) & mask;
+        }
+        return -1;
+    }
+
+    void rehash(size_t cap) {
+        std::vector<uint8_t> ok;
+        std::vector<int64_t> ov;
+        ok.swap(keys);
+        ov.swap(vals);
+        reset(cap);
+        for (size_t c = 0; c < ov.size(); c++)
+            if (ov[c] >= 0) put(&ok[c * 17], ov[c]);
+    }
+
+    void put(const uint8_t* k, int64_t v) {
+        int64_t c = find(k);
+        if (c >= 0) { vals[c] = v; return; }
+        uint64_t i = hash(k) & mask;
+        while (vals[i] >= 0) i = (i + 1) & mask;
+        if (vals[i] == kTxEmpty) used++;
+        memcpy(&keys[i * 17], k, 17);
+        vals[i] = v;
+        live++;
+        if (used * 10 > (int64_t)vals.size() * 7)
+            rehash(live * 10 > (int64_t)vals.size() * 4 ? vals.size() * 2
+                                                        : vals.size());
+    }
+};
+
+}  // namespace
+
+extern "C" {
+
+void* tindex_new() { return new TraceIndex(); }
+void tindex_free(void* h) { delete (TraceIndex*)h; }
+
+// out[i] = slot of keys[i], or -1
+void tindex_lookup(void* h, const uint8_t* keys, int64_t n, int64_t* out) {
+    TraceIndex* t = (TraceIndex*)h;
+    std::lock_guard<std::mutex> g(t->mu);
+    for (int64_t r = 0; r < n; r++) {
+        int64_t c = t->find(keys + r * 17);
+        out[r] = c < 0 ? -1 : t->vals[c];
+    }
+}
+
+// keys[i] -> slots[i], whether or not the key was there
+void tindex_upsert(void* h, const uint8_t* keys, int64_t n,
+                   const int64_t* slots) {
+    TraceIndex* t = (TraceIndex*)h;
+    std::lock_guard<std::mutex> g(t->mu);
+    for (int64_t r = 0; r < n; r++) t->put(keys + r * 17, slots[r]);
+}
+
+// forget keys[i] where it still names slots[i] (an upsert since wins)
+void tindex_discard(void* h, const uint8_t* keys, int64_t n,
+                    const int64_t* slots) {
+    TraceIndex* t = (TraceIndex*)h;
+    std::lock_guard<std::mutex> g(t->mu);
+    for (int64_t r = 0; r < n; r++) {
+        int64_t c = t->find(keys + r * 17);
+        if (c >= 0 && t->vals[c] == slots[r]) {
+            t->vals[c] = kTxGone;
+            t->live--;
+        }
+    }
+}
+
+int64_t tindex_size(void* h) {
+    TraceIndex* t = (TraceIndex*)h;
+    std::lock_guard<std::mutex> g(t->mu);
+    return t->live;
+}
+
+}  // extern "C"

@@ -322,7 +322,7 @@ def test_self_tracing_dogfood(tmp_path):
         # the self-tenant now holds framework spans, queryable
         names = set()
         inst = app.ingester.instance("tempo-self")
-        for _tid, lt in inst.live.traces.items():
+        for lt in inst.live.view():
             for sp in lt.spans:
                 names.add(sp["name"])
         assert "distributor.PushSpans" in names, names

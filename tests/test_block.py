@@ -145,6 +145,25 @@ def test_dedicated_column(block):
     assert "/r1" in vals
 
 
+@pytest.mark.parametrize("where", ["complete", "wal"])
+@pytest.mark.parametrize("tid", [bytes(range(1, 16)) + b"\0",
+                                 b"\0" * 15 + b"\x07", bytes(range(1, 8))],
+                         ids=["zero_last", "zeros_first", "short"])
+def test_find_trace_by_id_takes_the_id_byte_for_byte(where, tid, tmp_path):
+    """An id that ends in a zero byte (one random id in 256) or is shorter
+    than 16 bytes is found, and the id one bit away is not taken for it."""
+    other = tid[:-1] + bytes([tid[-1] ^ 1])
+    spans = [mkspan(tid, b"\x01" * 8), mkspan(other, b"\x02" * 8)]
+    if where == "wal":
+        b = WALBlock(str(tmp_path), "t1")
+        b.append(spans)
+    else:
+        be = MemBackend()
+        b = BackendBlock(be, write_block(be, "t1", spans_by_trace(spans)))
+    assert [s["span_id"] for s in b.find_trace_by_id(tid)] == [b"\x01" * 8]
+    assert [s["span_id"] for s in b.find_trace_by_id(other)] == [b"\x02" * 8]
+
+
 # -- WAL ---------------------------------------------------------------------
 
 def test_wal_append_replay_complete(tmp_path):

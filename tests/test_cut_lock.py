@@ -132,7 +132,7 @@ def test_reads_find_a_cut_trace_whole_all_through_the_sweep(
     _assert_reads_whole(inst, want)                  # live
     sweep, _ = _run(inst.cut_complete_traces, True)
     assert write.entered.wait(WAIT_S)
-    assert not inst.live.traces and not inst.head.segments()
+    assert not len(inst.live) and not inst.head.segments()
     _assert_reads_whole(inst, want)                  # in `cutting` alone
     write.start.set()
     assert write.written.wait(WAIT_S)
@@ -170,7 +170,7 @@ def test_a_write_that_raises_leaves_nothing_published(tmp_path, write):
     with pytest.raises(OSError, match="disk full"):
         inst.cut_complete_traces(immediate=True)
     assert inst.cutting is None and not inst.sweep_lock.locked()
-    assert not inst.live.traces and not inst.head.segments()
+    assert not len(inst.live) and not inst.head.segments()
     # the next sweep works, into the same head block
     write.fail = None
     spans = _k6_spans(6, groups=2, per=50)

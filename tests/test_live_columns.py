@@ -5,6 +5,7 @@ route makes of the same pushes."""
 
 from __future__ import annotations
 
+import sys
 import threading
 
 import numpy as np
@@ -22,7 +23,8 @@ from tempo_tpu.model.otlp import encode_spans_otlp
 from tempo_tpu.model.otlp_batch import StagedIngest, StagedView, stage_otlp
 from tempo_tpu.model.span_batch import SpanBatch
 from tempo_tpu.overrides.limits import IngestionLimits, Limits, ReadLimits
-from tempo_tpu.utils.livetraces import LIVE_SPANS, LiveTraceStore
+from tempo_tpu.utils import livetraces
+from tempo_tpu.utils.livetraces import CUT_SPANS, LIVE_SPANS, LiveTraceStore
 
 T0 = 1_700_000_000_000_000_000
 
@@ -203,10 +205,11 @@ def _push_columns(inst: TenantInstance, kind, x) -> list:
 
 
 def _instance(tmp_path, name: str, limits: Limits | None = None,
-              **cfg) -> TenantInstance:
+              now=None, **cfg) -> TenantInstance:
     return TenantInstance("t", str(tmp_path / name / "wal"),
                           str(tmp_path / name / "blocks"),
-                          cfg=InstanceConfig(**cfg), limits=limits)
+                          cfg=InstanceConfig(**cfg), limits=limits,
+                          **({"now": now} if now else {}))
 
 
 def _reference_table(pushes, dedicated=()):
@@ -347,6 +350,28 @@ def _case_deep_and_wide(it):
     return [("staged", _stage([spans[i] for i in order], it).view())]
 
 
+def _case_trace_over_chunks(it):
+    """Traces whose spans come in four pushes, each push holding the
+    traces in another order, and one span sent again in the last push
+    (the first copy wins)."""
+    spans = _k6_spans(17, groups=2, per=40)
+    order = np.random.default_rng(17).permutation(len(spans))
+    parts = [[spans[i] for i in order[k::4]] for k in range(4)]
+    parts[3].append(dict(parts[0][0], name="late-copy"))
+    return [("staged", _stage(p, it).view()) for p in parts]
+
+
+def _case_mixed_sizes(it):
+    """Nine traces of one to five spans in a first push and the rest of
+    their spans in a second: under a byte or a count limit one push holds
+    traces refused beside traces admitted."""
+    spans = _k6_spans(18, groups=1, per=45)
+    first = [s for i, s in enumerate(spans) if i % 5 <= (i // 5) % 5]
+    later = [s for i, s in enumerate(spans) if i % 5 > (i // 5) % 5]
+    return [("staged", _stage(first, it).view()),
+            ("staged", _stage(later, it).view())]
+
+
 CASES = {
     "k6": _case_k6,
     "rich": _case_rich,
@@ -359,6 +384,8 @@ CASES = {
     "claimed_trace_id": _case_claimed_trace_id,
     "two_interners": _case_two_interners,
     "deep_and_wide": _case_deep_and_wide,
+    "trace_over_chunks": _case_trace_over_chunks,
+    "mixed_sizes": _case_mixed_sizes,
 }
 
 DEDICATED = (DedicatedColumn("span", "http.method"),
@@ -381,6 +408,56 @@ def test_cut_table_equals_the_dict_routes(case, dedicated, tmp_path):
     _assert_tables_equal(got, want)
 
 
+@pytest.mark.parametrize("beside", ["columns", "dicts"])
+def test_a_partial_idle_cut_and_the_rest_later_equal_the_dict_routes(
+        beside, tmp_path):
+    """Twenty traces of five spans. At t = 0 a push holds the first three
+    spans of traces 0-9; at t = 10 one holds the last two of traces 0-4
+    and 7, and all of traces 10-14. An idle cut at t = 12 takes traces
+    5, 6, 8, 9 of the first chunk and leaves the rest of it; the immediate
+    cut after it takes the rest. With `dicts`, traces 15-19 come as dict
+    pushes at t = 0 and trace 7's last spans as one at t = 10 (its chunk
+    rows join it). Each cut's table is the dict route's."""
+    it = StringInterner()
+    spans = _k6_spans(19, groups=2, per=50)
+    trace = [(i // 50) * 10 + (i % 50) // 5 for i in range(len(spans))]
+    place = [i % 5 for i in range(len(spans))]
+
+    def pick(test) -> list[dict]:
+        return [s for s, n, j in zip(spans, trace, place) if test(n, j)]
+    pushes = [(0.0, "staged", _stage(pick(lambda n, j: n < 10 and j < 3),
+                                     it).view())]
+    tail = (lambda n: n < 5 or n == 7) if beside == "columns" else (
+        lambda n: n < 5)
+    pushes.append((10.0, "staged", _stage(pick(
+        lambda n, j: (tail(n) and j >= 3) or 10 <= n < 15), it).view()))
+    if beside == "dicts":
+        pushes.insert(1, (0.0, "dicts", bs.spans_by_trace(
+            pick(lambda n, j: n >= 15))))
+        pushes.append((10.0, "dicts", bs.spans_by_trace(
+            pick(lambda n, j: n == 7 and j >= 3))))
+    clock = [0.0]
+    ref = _instance(tmp_path, "ref", now=lambda: clock[0])
+    col = _instance(tmp_path, "col", now=lambda: clock[0])
+    for t, kind, x in pushes:
+        clock[0] = t
+        _push_dicts(ref.push_trace, kind, x)
+        _push_columns(col, kind, x)
+    clock[0] = 12.0
+    sizes = []
+    for cut in ({"idle_s": 5.0}, {"immediate": True}):
+        want, got = ref.live.cut(**cut), col.live.cut(**cut)
+        assert [lt.trace_id for lt in got] == [lt.trace_id for lt in want]
+        _assert_tables_equal(cut_table(got), cut_table(want))
+        col.live.forget(got)
+        col.live.drop_chunks(got.spent_chunks())
+        sizes.append((len(got), len(col.live.chunks)))
+    # the first chunk outlives the idle cut (traces 0-4 and 7 hold it)
+    assert sizes == ([(4, 2), (11, 0)] if beside == "columns"
+                     else [(9, 2), (11, 0)])
+    assert len(col.live) == 0 and len(col.live.index) == 0
+
+
 # -- (b) one trace fed by both kinds ------------------------------------------
 
 def test_trace_fed_by_dicts_and_columns_cuts_as_two_dict_pushes(tmp_path):
@@ -395,55 +472,80 @@ def test_trace_fed_by_dicts_and_columns_cuts_as_two_dict_pushes(tmp_path):
         assert inst.push_trace(tid, group) is None
     assert inst.push_columns(ColumnSource(view.staged.batch()[0], view.staged),
                              view.row_indices()) == {}
-    kinds = {type(seg).__name__ for lt in inst.live.traces.values()
+    kinds = {type(seg).__name__ for lt in inst.live.dict_traces.values()
              for seg in lt.segments}
     assert kinds == {"list", "ColumnSegment"}
+    assert not inst.live.chunks
     _assert_tables_equal(cut_table(inst.live.cut(immediate=True)), want)
 
 
 # -- (c) reads before the cut -------------------------------------------------
 
+@pytest.mark.parametrize("held", ["live", "cutting"])
 @pytest.mark.parametrize("case", ["k6", "rich", "repeated_span_id",
-                                  "bare_batch", "dicts_beside_columns"])
-def test_reads_before_the_cut_return_the_dict_routes_spans(case, tmp_path):
+                                  "bare_batch", "dicts_beside_columns",
+                                  "trace_over_chunks"])
+def test_reads_before_the_cut_return_the_dict_routes_spans(case, held,
+                                                          tmp_path):
+    """Both reads see a chunk's traces while they are live and while a
+    sweep holds them in `cutting`."""
     pushes = CASES[case](StringInterner())
     ref = _instance(tmp_path, "ref")
     col = _instance(tmp_path, "col")
     for kind, x in pushes:
         _push_dicts(ref.push_trace, kind, x)
         _push_columns(col, kind, x)
-    assert list(col.live.traces) == list(ref.live.traces)
+    want = ref.live.view().groups()
+    assert col.live.view().groups() == want
+    if held == "cutting":
+        for inst in (ref, col):
+            inst.cutting = inst.live.cut(immediate=True)
+            assert len(inst.live) == 0 and len(inst.cutting) == len(want)
+        assert col.cutting.groups() == want
     assert col.all_recent_traces() == ref.all_recent_traces()
-    for tid in ref.live.traces:
+    for tid, _ in want:
         assert col.find_trace_by_id(tid) == ref.find_trace_by_id(tid)
-        assert col.live.traces[tid].spans == ref.live.traces[tid].spans
+        assert col.find_trace_by_id(tid) is not None
 
 
 # -- (d) the limits -----------------------------------------------------------
 
 @pytest.mark.parametrize("case", ["k6", "rich", "sampled_view",
-                                  "bare_batch", "three_pushes"])
+                                  "bare_batch", "three_pushes",
+                                  "mixed_sizes"])
 @pytest.mark.parametrize("limit", ["trace_too_large", "live_traces_exceeded",
-                                   "none"])
+                                   "both", "none"])
 def test_limits_fire_on_the_same_pushes(case, limit, tmp_path):
     lim = {"trace_too_large": Limits(read=ReadLimits(max_bytes_per_trace=1100)),
            "live_traces_exceeded":
                Limits(ingestion=IngestionLimits(max_traces_per_user=3)),
+           "both": Limits(ingestion=IngestionLimits(max_traces_per_user=5),
+                          read=ReadLimits(max_bytes_per_trace=1100)),
            "none": Limits(ingestion=IngestionLimits(max_traces_per_user=0),
                           read=ReadLimits(max_bytes_per_trace=0))}[limit]
     pushes = CASES[case](StringInterner())
     ref = _instance(tmp_path, "ref", limits=lim)
     col = _instance(tmp_path, "col", limits=lim)
+    answers = []
     for kind, x in pushes:
         want = _push_dicts(ref.push_trace, kind, x)
         assert _push_columns(col, kind, x) == want
         assert col.live.total_bytes == ref.live.total_bytes
+        answers.append(want)
     assert col.discarded == ref.discarded
     assert col.live.pushes_rejected == ref.live.pushes_rejected
-    if limit != "none" and case in ("k6", "three_pushes"):
-        assert ref.discarded.get(limit, 0) > 0
-    assert ({t: lt.bytes for t, lt in col.live.traces.items()}
-            == {t: lt.bytes for t, lt in ref.live.traces.items()})
+    if limit != "none" and case in ("k6", "three_pushes", "mixed_sizes"):
+        assert sum(ref.discarded.values()) > 0
+    if limit != "none" and case == "mixed_sizes":
+        # one push with traces refused beside traces admitted
+        assert any(None in w and set(w) - {None} for w in answers)
+    if limit == "both" and case == "mixed_sizes":
+        assert set(ref.discarded) == {"trace_too_large",
+                                      "live_traces_exceeded"}
+    held = [lt.trace_id for lt in ref.live.view()]
+    assert [lt.trace_id for lt in col.live.view()] == held
+    assert ([col.live.bytes_of(t) for t in held]
+            == [ref.live.bytes_of(t) for t in held])
     _assert_tables_equal(cut_table(col.live.cut(immediate=True)),
                          cut_table(ref.live.cut(immediate=True)))
     assert col.live.total_bytes == ref.live.total_bytes == 0
@@ -464,7 +566,14 @@ def test_a_repeated_attr_key_counts_each_time_it_stands(tmp_path):
 
 # -- (e) pushes against sweeps ------------------------------------------------
 
-def test_threads_pushing_while_sweeps_run_lose_and_repeat_nothing(tmp_path):
+@pytest.mark.parametrize("ids", ["own", "shared"])
+def test_threads_pushing_while_sweeps_run_lose_and_repeat_nothing(
+        ids, tmp_path):
+    """Four threads push while a fifth sweeps, the interpreter handed over
+    every microsecond. With `shared` ids all four push to the same traces
+    (each its own span ids), so a trace is cut while another thread pushes
+    to it, and its id is pushed again after the cut while the index still
+    names the slot the cut took."""
     it = StringInterner()
     inst = _instance(tmp_path, "race", trace_idle_s=0.0, trace_live_s=0.0)
     acked: list[set] = [set() for _ in range(4)]
@@ -472,7 +581,11 @@ def test_threads_pushing_while_sweeps_run_lose_and_repeat_nothing(tmp_path):
 
     def pusher(k: int) -> None:
         for r in range(6):
-            spans = _k6_spans(100 + 10 * k + r, groups=2, per=50)
+            spans = _k6_spans(100 + (0 if ids == "shared" else 10 * k) + r,
+                              groups=2, per=50)
+            if ids == "shared":
+                spans = [dict(s, span_id=bytes([k]) + s["span_id"][1:])
+                         for s in spans]
             staged = _stage(spans, it)
             assert inst.push_columns(
                 ColumnSource(staged.batch()[0], staged),
@@ -485,18 +598,25 @@ def test_threads_pushing_while_sweeps_run_lose_and_repeat_nothing(tmp_path):
 
     threads = [threading.Thread(target=pusher, args=(k,)) for k in range(4)]
     sweep = threading.Thread(target=sweeper)
-    sweep.start()
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    stop.set()
-    sweep.join()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        sweep.start()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        stop.set()
+        sweep.join(60)
+    finally:
+        stop.set()
+        sys.setswitchinterval(interval)
+    assert not sweep.is_alive() and not any(t.is_alive() for t in threads)
     want = set().union(*acked)
     assert len(want) == 4 * 6 * 100
     seen = [(s["trace_id"], s["span_id"]) for s in inst.head.iter_spans()]
     seen += [(s["trace_id"], s["span_id"])
-             for lt in inst.live.traces.values() for s in lt.spans]
+             for lt in inst.live.view() for s in lt.spans]
     assert len(seen) == len(want) and set(seen) == want
     assert len(inst.head.segments()) >= 1
 
@@ -535,10 +655,34 @@ def test_columnar_segment_is_rescanned_and_completes_to_the_same_block(tmp_path)
     del pq
 
 
+@pytest.mark.parametrize("case", ["rich", "three_pushes", "repeated_span_id"])
+def test_a_wal_block_finds_a_trace_as_its_spans_read_back_whole(case,
+                                                                tmp_path):
+    """`WALBlock.find_trace_by_id` matches the trace-id column first: the
+    same spans, in the same order, as filtering every span of every
+    segment (ids shorter than 16 bytes included)."""
+    inst = _instance(tmp_path, "w")
+    for kind, x in CASES[case](StringInterner()):
+        _push_columns(inst, kind, x)
+        assert inst.cut_complete_traces(immediate=True) > 0
+    spans = list(inst.head.iter_spans())
+    assert len(inst.head.segments()) == len(CASES[case](StringInterner()))
+    for tid in dict.fromkeys(s["trace_id"] for s in spans):
+        for asked in (tid, tid.rstrip(b"\0")):
+            assert inst.head.find_trace_by_id(asked) == [
+                s for s in spans if s["trace_id"] == tid]
+    assert inst.head.find_trace_by_id(_id(0xFFFF, 16)) is None
+
+
 # -- the staged routes make no span dict; the counter says which form ----------
 
 def _live_spans() -> dict[str, float]:
     return {form: LIVE_SPANS.value((form,)) for form in ("columns", "dicts")}
+
+
+def _cut_spans() -> dict[str, float]:
+    return {route: CUT_SPANS.value((route,))
+            for route in ("columns", "dicts")}
 
 
 def test_staged_push_and_localblocks_make_no_span_dicts(tmp_path, monkeypatch):
@@ -578,16 +722,120 @@ def test_staged_push_and_localblocks_make_no_span_dicts(tmp_path, monkeypatch):
     assert (calls["view"], calls["batch"]) == (1, 1)
     # the cuts make none either, and the traces read back from the WAL
     calls.update(view=0, batch=0, attrs=0)
+    cut_before = _cut_spans()
     ing.sweep_instance("t", immediate=True)
     lb.cut_tick(immediate=False)
     lb.inst.cut_complete_traces(immediate=True)
     assert calls == {"view": 0, "batch": 0, "attrs": 0}
     assert len(ing.instance("t").live) == len(lb.inst.live) == 0
+    assert not ing.instance("t").live.chunks and not lb.inst.live.chunks
+    assert _cut_spans()["columns"] - cut_before["columns"] == 2 * len(spans)
     assert ([s["span_id"] for s in lb.inst.find_trace_by_id(tid)]
             == [s["span_id"] for s in got])
     # the dict route counts itself
     assert ing.push("t", bs.spans_by_trace(spans[:10])) == [None, None]
     assert _live_spans()["dicts"] - before["dicts"] == 10
+
+
+@pytest.mark.parametrize("traces", [1, 7, 200])
+def test_a_store_holds_a_chunk_a_push_and_no_live_trace_object(
+        traces, tmp_path, monkeypatch):
+    made = []
+    real = livetraces.LiveTrace.__init__
+
+    def counted(self, *a, **kw):
+        made.append(1)
+        real(self, *a, **kw)
+    monkeypatch.setattr(livetraces.LiveTrace, "__init__", counted)
+    it = StringInterner()
+    inst = _instance(tmp_path, "n")
+    pushes = 6
+    for k in range(pushes):
+        staged = _stage(_k6_spans(60 + k, groups=1, per=5 * traces), it)
+        assert inst.push_columns(ColumnSource(staged.batch()[0], staged),
+                                 None) == {}
+    assert len(inst.live.chunks) == pushes and not inst.live.dict_traces
+    assert len(inst.live) == pushes * traces and not made
+    assert inst.cut_complete_traces(immediate=True) == pushes * traces
+    assert not inst.live.chunks and not made and len(inst.live.index) == 0
+
+
+@pytest.mark.parametrize("beside", ["nothing", "columns"])
+def test_a_dict_push_asks_the_index_only_beside_column_traces(
+        beside, tmp_path, monkeypatch):
+    """The dict routes (Jaeger, Zipkin, gRPC, replay, blockbuilder) keep
+    their traces in a dict by id: with no live column trace a push never
+    asks the index; beside column traces it asks once a trace."""
+    asked = []
+    real = livetraces.TraceIndex.lookup
+
+    def lookup(self, keys):
+        asked.append(len(keys))
+        return real(self, keys)
+    monkeypatch.setattr(livetraces.TraceIndex, "lookup", lookup)
+    inst = _instance(tmp_path, "d")
+    if beside == "columns":
+        staged = _stage(_k6_spans(70, groups=1, per=25), StringInterner())
+        assert inst.push_columns(ColumnSource(staged.batch()[0], staged),
+                                 None) == {}
+        asked.clear()
+    groups = bs.spans_by_trace(_k6_spans(71, groups=2, per=50))
+    assert [inst.push_trace(t, s) for t, s in groups] == [None] * 20
+    assert asked == ([] if beside == "nothing" else [1] * 20)
+    columns = 5 if beside == "columns" else 0
+    assert len(inst.live) == 20 + columns
+    assert len(inst.live.dict_traces) == 20
+    assert len(inst.live.index) == columns
+
+
+@pytest.mark.parametrize("spread", [1, 3])
+def test_a_read_of_one_trace_gathers_only_the_chunks_it_spans(spread,
+                                                              tmp_path):
+    """`find_trace_by_id` takes the chunks from the trace's first to its
+    last, not every chunk of the store: six pushes, the trace's spans in
+    the second (and, with `spread` 3, the fourth)."""
+    it = StringInterner()
+    target = _k6_spans(80, groups=1, per=5)
+    inst = _instance(tmp_path, "f")
+    for k in range(6):
+        spans = _k6_spans(81 + k, groups=1, per=10)
+        if k == 1:
+            spans += target[:3] if spread == 3 else target
+        if k == 3 and spread == 3:
+            spans += target[3:]
+        staged = _stage(spans, it)
+        assert inst.push_columns(ColumnSource(staged.batch()[0], staged),
+                                 None) == {}
+    tid = target[0]["trace_id"]
+    assert len(inst.live.chunks) == 6
+    assert len(inst.live.view(tid).chunks) == spread
+    got = inst.find_trace_by_id(tid)
+    assert sorted(s["span_id"] for s in got) == sorted(
+        s["span_id"] for s in target)
+
+
+@pytest.mark.parametrize("case", ["k6", "dicts_beside_columns",
+                                  "claimed_trace_id", "two_interners"])
+def test_cut_spans_count_every_cut_span_once_by_route(case, tmp_path):
+    """`tempo_ingester_cut_spans_total{route}`: spans taken from chunks
+    count as columns, the rest (dict pushes, and the columns of a trace a
+    dict span claims or staged against a second interner) as dicts."""
+    pushes = CASES[case](StringInterner())
+    inst = _instance(tmp_path, "c")
+    for kind, x in pushes:
+        _push_columns(inst, kind, x)
+    staged = sum(x.n for kind, x in pushes if kind == "staged")
+    total = staged + sum(len(spans) for kind, x in pushes if kind == "dicts"
+                         for _, spans in x)
+    demoted = {"claimed_trace_id": 5, "two_interners": 20}.get(case, 0)
+    before = _cut_spans()
+    assert inst.cut_complete_traces(immediate=True) > 0
+    after = _cut_spans()
+    assert after["columns"] - before["columns"] == staged - demoted
+    assert after["dicts"] - before["dicts"] == total - staged + demoted
+    # nothing left, nothing counted twice
+    assert inst.cut_complete_traces(immediate=True) == 0
+    assert _cut_spans() == after
 
 
 def test_staging_without_span_attrs_is_refused_as_before(tmp_path):

@@ -209,3 +209,38 @@ def test_otlp_stage_mt_matches_serial(monkeypatch):
     with pytest.raises(ValueError):
         native.otlp_stage(it_mt.native_handle(), payload[:-5],
                           skip_span_attrs=True)
+
+
+@pytest.mark.parametrize("use_native", [True, False],
+                         ids=["native", "dict"])
+def test_trace_index_upserts_looks_up_and_discards_what_it_still_names(
+        use_native):
+    """The live stores' id -> slot index: both forms answer alike through
+    growth, tombstones and a discard that an upsert got ahead of."""
+    rng = np.random.default_rng(3)
+    keys = rng.integers(0, 256, (50_000, 17), dtype=np.uint8)
+    keys[:, 16] = 16
+    keys[0] = 0                       # a key that is all zero bytes
+    keys[1] = keys[2]                 # another key's padded bytes,
+    keys[1, 16] = 7                   # another length
+    idx = native.TraceIndex(use_native=use_native)
+    slots = np.arange(len(keys), dtype=np.int64) + 10
+    idx.upsert(keys, slots)
+    assert len(idx) == len(keys)
+    np.testing.assert_array_equal(idx.lookup(keys), slots)
+    other = keys.copy()
+    other[:, 0] ^= 1
+    assert (idx.lookup(other[:1000]) == -1).all()
+    # discard half; one of them was given a new slot first, so it stays
+    idx.upsert(keys[:1], np.array([7], np.int64))
+    idx.discard(keys[::2], slots[::2])
+    want = slots.copy()
+    want[::2] = -1
+    want[0] = 7
+    np.testing.assert_array_equal(idx.lookup(keys), want)
+    assert len(idx) == len(keys) // 2 + 1
+    # tombstones are reused and the index still answers after a rehash
+    idx.upsert(other, slots + 100_000)
+    np.testing.assert_array_equal(idx.lookup(other), slots + 100_000)
+    np.testing.assert_array_equal(idx.lookup(keys), want)
+    assert idx.lookup(keys[:0]).shape == (0,)

@@ -64,7 +64,7 @@ def test_rf3_replication(rig):
         assert len(inst.live) == 10
     # spans grouped per trace
     inst = ingesters["ing-0"].instance("t1")
-    assert len(inst.live.traces[bytes([1]) * 16].spans) == 3
+    assert len(inst.live.view().spans_of(bytes([1]) * 16)) == 3
 
 
 def test_invalid_trace_id_discarded(rig):
