@@ -98,6 +98,38 @@ class Generator:
             help="Spans discarded by the distributor, by reason, and by "
                  "the metrics-generator's slack filter (outside_slack)",
             labels=("reason",), shared=True)
+        # the service-graph store, per tenant (upstream's names where
+        # upstream has the family)
+        def graphs():
+            return [(t, p) for t, gi in insts().items()
+                    for p in [gi.processors.get("service-graphs")]
+                    if p is not None]
+
+        reg.counter_func(
+            "tempo_metrics_generator_processor_service_graphs_dropped_spans",
+            lambda: [((t,), p.dropped) for t, p in graphs()],
+            help="Client/server spans the service-graph store refused "
+                 "because it held max_items pending halves",
+            labels=("tenant",))
+        reg.counter_func(
+            "tempo_metrics_generator_processor_service_graphs_expired_edges",
+            lambda: [((t,), p.expired) for t, p in graphs()],
+            help="Pending halves that waited out the service-graph wait "
+                 "unmatched (a virtual-node edge or none)",
+            labels=("tenant",))
+        reg.counter_func(
+            "tempo_metrics_generator_servicegraphs_edges_total",
+            lambda: [((t, kind), n) for t, p in graphs()
+                     for kind, n in p.edges.items()],
+            help="Service-graph edges emitted: completed = a client and "
+                 "its server paired; virtual = an expired half under the "
+                 "virtual-node rules",
+            labels=("tenant", "kind"))
+        reg.gauge_func(
+            "tempo_metrics_generator_servicegraphs_store_items",
+            lambda: [((t,), p.store_items()) for t, p in graphs()],
+            help="Pending halves in the service-graph store",
+            labels=("tenant",))
         self.collect_duration = reg.histogram(
             "tempo_metrics_generator_collect_duration_seconds",
             "One tenant collection tick: device-state gather through "
@@ -145,6 +177,20 @@ class Generator:
                 if ta_patch:
                     cfg.traceanalytics = dataclasses.replace(
                         cfg.traceanalytics, **ta_patch)
+                sg_patch = {}
+                if lim.generator.sg_histogram_buckets:
+                    sg_patch["histogram_buckets"] = tuple(
+                        lim.generator.sg_histogram_buckets)
+                if lim.generator.sg_peer_attributes:
+                    sg_patch["peer_attributes"] = tuple(
+                        lim.generator.sg_peer_attributes)
+                if lim.generator.sg_wait_s:
+                    sg_patch["wait_s"] = lim.generator.sg_wait_s
+                if lim.generator.sg_max_items:
+                    sg_patch["max_items"] = lim.generator.sg_max_items
+                if sg_patch:
+                    cfg.servicegraphs = dataclasses.replace(
+                        cfg.servicegraphs, **sg_patch)
                 inst = GeneratorInstance(tenant, cfg, now=self.now)
                 inst._matview_limits = \
                     lambda t=tenant: self.overrides.for_tenant(t)

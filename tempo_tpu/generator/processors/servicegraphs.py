@@ -10,9 +10,12 @@ Reference semantics (`modules/generator/processor/servicegraphs/`):
   for PRODUCER/CONSUMER pairs), labeled (client, server) service names.
 - expiring edge store (`store/store.go:29,78,119`): TTL ring; expired
   half-edges infer virtual nodes (`servicegraphs.go:390-421`): an unmatched
-  SERVER span with a remote parent gets client="user"; an unmatched CLIENT
-  span pointing at a known peer (db/messaging attrs, `servicegraphs.go:
-  287-343` heuristics) gets a server node named from peer attributes.
+  ROOT SERVER span (no parent) gets client="user"; an unmatched CLIENT span
+  that carries a peer attribute (`peer_attributes`, first present wins)
+  gets a server node named by its value. Any other expired half (a server
+  whose client never came, a client with no peer attribute) emits nothing.
+  Expiry runs inside the tenant's own pushes: a tenant that stops pushing
+  keeps its pending halves until its next push.
 
 TPU split: edge *matching* is pointer-chasing and stays on the host (a dict
 keyed by 24-byte trace+span ids, vectorized staging in/out); the metric
@@ -45,11 +48,18 @@ from tempo_tpu.model.span_batch import (
 from tempo_tpu.obs.jaxruntime import RUNTIME, instrumented_jit
 from tempo_tpu.registry import metrics as rm
 from tempo_tpu.registry.registry import DEFAULT_HISTOGRAM_EDGES, ManagedRegistry
-from tempo_tpu.sched import bucket_rows
-from tempo_tpu.utils import turn
+from tempo_tpu.utils import tracing, turn
 
 _PEER_ATTRS = ("peer.service", "db.name", "db.system", "messaging.system",
                "net.peer.name")  # `servicegraphs.go:287-343` heuristics
+# edges a device step: an emit of more (a push that completes hundreds of
+# edges while a stall's worth of halves expires) takes several steps. A
+# step pads to one of TWO shapes, 16 rows (a push of a few pairs) or
+# _EMIT_ROWS: every shape is a cold compile on the chip, and a step's
+# time there (~0.3 ms on a v5e) is the relayout of its state planes, not
+# its rows
+_EMIT_ROWS = 512
+_EMIT_MIN_ROWS = 16
 
 EMITS = RUNTIME.counter(
     "tempo_metrics_generator_servicegraphs_emits_total",
@@ -94,6 +104,9 @@ class ServiceGraphsConfig:
     histogram_buckets: tuple[float, ...] = DEFAULT_HISTOGRAM_EDGES
     wait_s: float = 10.0                 # edge TTL before expiry
     max_items: int = 10000               # store capacity
+    # span / resource attributes that name an uninstrumented peer of an
+    # unmatched CLIENT span, in order of precedence
+    peer_attributes: tuple[str, ...] = _PEER_ATTRS
     enable_client_server_prefix: bool = False
     enable_messaging_system_latency_histogram: bool = False
     enable_virtual_node_label: bool = False
@@ -109,6 +122,7 @@ class _HalfEdge:
     peer_id: int          # interned peer-attr value (client side), or INVALID_ID
     start_ns: int
     expire_at: float
+    is_root: bool = False  # a server span with no parent
 
 
 class ServiceGraphsProcessor:
@@ -147,11 +161,17 @@ class ServiceGraphsProcessor:
         # Device state is NOT this lock's: `_emit` takes the registry's
         # state_lock (order: store lock, then state_lock)
         self._store_lock = threading.Lock()
+        # read by the generator's per-tenant families on /metrics
         self.dropped = 0  # store-full drops (`store.go` max_items)
-        self.expired = 0
+        self.expired = 0  # halves that waited out `wait_s` unmatched
+        self.edges = {"completed": 0, "virtual": 0}   # edges emitted
 
     def name(self) -> str:
         return "service-graphs"
+
+    def store_items(self) -> int:
+        """Pending halves in the store (no lock: a length read)."""
+        return len(self._store)
 
     # -- ingestion ---------------------------------------------------------
 
@@ -180,6 +200,7 @@ class ServiceGraphsProcessor:
         # exact 24-byte concatenation the old loop produced)
         keys_client = void_keys(sb.trace_id, sb.span_id)
         keys_server = void_keys(sb.trace_id, sb.parent_span_id)
+        root = ~sb.parent_span_id.any(axis=1)
         completed: list[tuple] = []
         for i in interesting.tolist():
             is_client = bool(client_like[i])
@@ -214,15 +235,17 @@ class ServiceGraphsProcessor:
                     continue
                 he = _HalfEdge(int(sb.service_id[i]), float(dur_s[i]), bool(failed[i]),
                                is_client, is_messaging, int(peer_col[i]),
-                               int(sb.start_unix_nano[i]), now + self.cfg.wait_s)
+                               int(sb.start_unix_nano[i]), now + self.cfg.wait_s,
+                               not is_client and bool(root[i]))
                 self._store[key] = he
                 self._ttl.append((he.expire_at, key))
+        self.edges["completed"] += len(completed)
         # completed and expired edges ride ONE emit a push: adds commute
         self._emit(completed + self._expire(now))
 
     def _peer_col(self, sb: SpanBatch) -> np.ndarray:
         col = np.full(sb.capacity, INVALID_ID, np.int32)
-        for key in _PEER_ATTRS:
+        for key in self.cfg.peer_attributes:
             nxt = sb.attr_sval_column(key)
             col = np.where(col != INVALID_ID, col, nxt)
         return col
@@ -230,15 +253,17 @@ class ServiceGraphsProcessor:
     # -- emission ----------------------------------------------------------
 
     def _emit(self, edges: list[tuple]) -> None:
-        if not edges:
-            return
+        for at in range(0, len(edges), _EMIT_ROWS):
+            self._emit_step(edges[at:at + _EMIT_ROWS])
+
+    def _emit_step(self, edges: list[tuple]) -> None:
         it = self.registry.interner
         conn_ids = {c: it.intern(c) for c in ("", "messaging_system", "virtual_node")}
         n = len(edges)
-        # pad the edge batch to a pow-2 shape bucket: the matched-edge
-        # count varies per tick and unbucketed scatters would re-trace on
-        # every new cardinality (padding rows ride slot -1 → dropped)
-        cap = bucket_rows(n, lo=16)
+        # pad the edge batch to a fixed shape: the matched-edge count
+        # varies per push and unbucketed scatters would re-trace on every
+        # new cardinality (padding rows ride slot -1 → dropped)
+        cap = _EMIT_MIN_ROWS if n <= _EMIT_MIN_ROWS else _EMIT_ROWS
         messaging = self.messaging_hist is not None
         rows = np.array([(e[0], e[1], conn_ids[e[2]]) for e in edges], np.int32)
         # rows: slots, fail, cdur, sdur (+ mslots, mdur), `_edge_update_impl`
@@ -283,6 +308,12 @@ class ServiceGraphsProcessor:
 
     def _expire(self, now: float) -> list[tuple]:
         """Expired half-edges become virtual-node edges (`servicegraphs.go:390-421`)."""
+        with tracing.span("servicegraphs.expire"):
+            edges = self._expire_halves(now)
+        self.edges["virtual"] += len(edges)
+        return edges
+
+    def _expire_halves(self, now: float) -> list[tuple]:
         it = self.registry.interner
         expired_edges = []
         while self._ttl and self._ttl[0][0] <= now:
@@ -303,8 +334,10 @@ class ServiceGraphsProcessor:
                     expired_edges.append((he.service_id, it.intern(peer),
                                           "virtual_node", he.duration_s, 0.0,
                                           he.failed, 0.0))
-            else:
-                # unmatched server with remote parent → synthetic "user" client
+            elif he.is_root:
+                # unmatched root server: the request came from outside
+                # (a browser, curl) → synthetic "user" client. A server
+                # with a parent lost its client span; it names no edge
                 expired_edges.append((it.intern("user"), he.service_id,
                                       "virtual_node", 0.0, he.duration_s,
                                       he.failed, 0.0))

@@ -57,12 +57,15 @@ class GeneratorLimits:
     span_multiplier_key: str = ""
     target_info_enabled: bool = True
     native_histograms: str = "classic"      # classic | native | both
-    # service-graphs knobs
+    # service-graphs knobs (empty / 0 = the process default from
+    # generator.servicegraphs). `sg_dimensions` is refused: the
+    # processor's edge series carry client, server and connection_type
+    # alone (`refuse_unimplemented`)
     sg_histogram_buckets: tuple[float, ...] = ()
     sg_dimensions: tuple[str, ...] = ()
     sg_peer_attributes: tuple[str, ...] = ()
-    sg_wait_s: float = 10.0
-    sg_max_items: int = 10_000
+    sg_wait_s: float = 0.0
+    sg_max_items: int = 0
     # localblocks knobs
     lb_max_live_traces: int = 0
     lb_max_block_duration_s: float = 60.0
@@ -125,3 +128,15 @@ class Limits:
 
 def limits_from_dict(d: dict) -> Limits:
     return Limits().merged_with(d)
+
+
+def refuse_unimplemented(patch: dict, where: str = "overrides") -> None:
+    """Raise ValueError if `patch` (a nested limits dict) sets
+    `generator.sg_dimensions`: declared, but the service-graph processor
+    does not implement it, and a knob that is ignored must not load."""
+    fields = (patch or {}).get("generator")
+    if isinstance(fields, dict) and fields.get("sg_dimensions"):
+        raise ValueError(
+            f"{where}: generator.sg_dimensions is not implemented: the "
+            "service-graph edge series carry client, server and "
+            "connection_type alone")

@@ -11,6 +11,7 @@ set (validated in `cmd/tempo/app/overrides_validation.go`).
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import threading
@@ -18,7 +19,7 @@ import threading
 import yaml
 
 from tempo_tpu.backend.raw import DoesNotExist, KeyPath, RawReader, RawWriter
-from tempo_tpu.overrides.limits import Limits
+from tempo_tpu.overrides.limits import Limits, refuse_unimplemented
 
 WILDCARD = "*"
 
@@ -40,6 +41,8 @@ class Overrides:
                  runtime_config_path: str | None = None,
                  user_configurable: "UserConfigurableOverrides | None" = None):
         self.defaults = defaults or Limits()
+        refuse_unimplemented(dataclasses.asdict(self.defaults),
+                             "overrides_defaults")
         self.path = runtime_config_path
         self.user_configurable = user_configurable
         self._mtime = 0.0
@@ -62,6 +65,8 @@ class Overrides:
         with open(self.path) as f:
             doc = yaml.safe_load(f) or {}
         per_tenant = dict(doc.get("overrides", {}))
+        for tenant, patch in per_tenant.items():
+            refuse_unimplemented(patch, f"{self.path}: tenant {tenant!r}")
         with self._lock:
             self._mtime = mtime
             self._wildcard = per_tenant.pop(WILDCARD, {}) or {}
@@ -70,6 +75,7 @@ class Overrides:
 
     def set_tenant_patch(self, tenant: str, patch: dict) -> None:
         """Programmatic override injection (tests, single-binary config)."""
+        refuse_unimplemented(patch, f"tenant {tenant!r}")
         with self._lock:
             self._per_tenant[tenant] = patch
 

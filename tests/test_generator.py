@@ -233,10 +233,14 @@ def test_servicegraphs_expiry_virtual_nodes():
     clock = FakeClock()
     reg = ManagedRegistry(now=clock)
     p = ServiceGraphsProcessor(reg, ServiceGraphsConfig(wait_s=5.0))
-    # unmatched server span -> "user" virtual client after expiry
+    # unmatched ROOT server span -> "user" virtual client after expiry; a
+    # server whose client span never came, and a client with no peer
+    # attribute, expire into no edge (`servicegraphs.go` onExpire)
     sb = _mk_batch(interner=reg.interner, spans=[
-        _span(1, service="api", kind=KIND_SERVER, parent=bytes([9]) * 8),
+        _span(1, service="api", kind=KIND_SERVER),
         _span(2, service="web", kind=KIND_CLIENT, attrs={"db.system": "mysql"}),
+        _span(3, service="orphan", kind=KIND_SERVER, parent=bytes([9]) * 8),
+        _span(4, service="web", kind=KIND_CLIENT),
     ])
     p.push_batch(sb)
     assert series_value(reg.collect(1), "traces_service_graph_request_total",
@@ -248,7 +252,12 @@ def test_servicegraphs_expiry_virtual_nodes():
                         client="user", server="api") == 1.0
     assert series_value(samples, "traces_service_graph_request_total",
                         client="web", server="mysql") == 1.0
-    assert p.expired == 2
+    assert series_value(samples, "traces_service_graph_request_total",
+                        server="orphan") is None
+    assert sum(s.value for s in samples
+               if s.name == "traces_service_graph_request_total") == 2.0
+    assert p.expired == 4
+    assert p.edges == {"completed": 0, "virtual": 2}
 
 
 def emits_by_path() -> dict:
@@ -317,19 +326,21 @@ def _assert_same_edge_state(fused, family):
 
 
 @pytest.mark.parametrize("messaging", [False, True], ids=["plain", "messaging"])
-@pytest.mark.parametrize("n", [1, 8, 16, 17, 40])
+@pytest.mark.parametrize("n", [1, 8, 16, 17, 40, 1100])
 def test_servicegraphs_fused_step_equals_family_calls(messaging, n):
     """The jitted, donating step and the family-level calls run the same
     registry update functions: counts and buckets bit-equal, sums to f32
     accumulation order, over batches that fill, spill and pad their
-    pow-2 bucket (padding rides slot -1), three emits deep."""
+    shape (16 or 512 rows; padding rides slot -1), three emits deep. An
+    emit of more than 512 edges takes one step a 512 of them."""
     pair = _sg_pair(messaging)
     e0 = emits_by_path()
     for seed in range(3):
         for reg, p in pair:
             p._emit(_edges(reg, n, seed))
-    assert emits_by_path() == {"fused": e0["fused"] + 3,
-                               "family": e0["family"] + 3}
+    steps = 3 * -(-n // 512)
+    assert emits_by_path() == {"fused": e0["fused"] + steps,
+                               "family": e0["family"] + steps}
     _assert_same_edge_state(*pair)
     total = pair[0][1].total.state.values
     assert float(total.sum()) == 3 * n
@@ -346,7 +357,7 @@ def test_servicegraphs_completed_and_expired_edges_ride_one_emit(messaging):
         for reg, p in pair:
             p.push_batch(_mk_batch(spans, interner=reg.interner))
 
-    push([_span(1, service="api", kind=KIND_SERVER, parent=bytes([9]) * 8),
+    push([_span(1, service="api", kind=KIND_SERVER),
           _span(2, service="web", kind=KIND_CLIENT, attrs={"db.system": "mysql"})])
     clock.t += 10.0
     e0 = emits_by_path()
@@ -390,7 +401,7 @@ def _pair_batch(reg, pairs: int, k: int):
 
 def test_servicegraphs_one_dispatch_a_push():
     """N pushes of one bucket shape: N fused emits, no family-level one,
-    and at most one compile a bucket shape (16 and 32 columns here)."""
+    and at most one compile a bucket shape (16 and 512 columns here)."""
     reg = ManagedRegistry(now=FakeClock())
     p = ServiceGraphsProcessor(reg, ServiceGraphsConfig())
     e0, c0 = emits_by_path(), _edge_compiles()

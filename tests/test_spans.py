@@ -44,8 +44,8 @@ SPAN_NAMES = frozenset({
     "ingester.push", "ingester.cut", "instance.cut_locked",
     "generator.Push", "generator.resolve", "generator.collect",
     "generator.drain", "generator.tick",
-    "spanmetrics.push", "servicegraphs.push", "localblocks.push",
-    "traceanalytics.push",
+    "spanmetrics.push", "servicegraphs.push", "servicegraphs.expire",
+    "localblocks.push", "traceanalytics.push",
     "registry.purge", "registry.gather", "registry.format", "pages.alloc",
     "remote_write.encode", "remote_write.send",
     "sched.wait", "sched.dispatch", "sched.h2d", "sched.enqueue",
@@ -448,7 +448,8 @@ REQUEST_TREE = {"api.push", "distributor.admit", "distributor.decode",
                 "distributor.PushSpans", "ingester.push",
                 "distributor.GeneratorTee", "distributor.turn",
                 "generator.Push", "spanmetrics.push", "generator.resolve",
-                "servicegraphs.push", "localblocks.push"}
+                "servicegraphs.push", "servicegraphs.expire",
+                "localblocks.push"}
 DISPATCH_TREE = {"sched.dispatch", "sched.h2d", "sched.enqueue"}
 
 
@@ -507,7 +508,7 @@ def test_cpu_family_and_the_process_counter_are_on_metrics(tmp_path):
     a, b = (f["samples"][("process_cpu_seconds_total", ())] for f in fams)
     assert 0.0 < a <= b
     # every span under the rows is one of the frozen names, as ever
-    assert len(SPAN_NAMES) == 39
+    assert len(SPAN_NAMES) == 40
     assert {n for n, _ in counts} <= SPAN_NAMES
 
 
@@ -624,7 +625,8 @@ def test_paged_fused_update_keeps_its_jit_name():
 def test_edge_update_stays_outside_the_fused_update_match():
     """The service-graph step is a module of its own in a profile: no
     layer file's prefix (`jit__fused_update` above all, the spanmetrics
-    kernel's roofline) may pick it up."""
+    kernel's roofline) may pick it up but its own roofline's
+    (`edge_update_roofline_pct.hotrod`)."""
     from tempo_tpu.generator.processors import servicegraphs as sg
     from tempo_tpu.registry import ManagedRegistry
 
@@ -633,7 +635,8 @@ def test_edge_update_stays_outside_the_fused_update_match():
         tuple(f.state for f in p._families), np.zeros((4, 16), np.float32)))
     assert name == "jit__edge_update_impl"
     assert "jit__fused_update" in _layer_prefixes()
-    assert not any(name.startswith(pre) for pre in _layer_prefixes())
+    assert {pre for pre in _layer_prefixes() if name.startswith(pre)} == {
+        "jit__edge_update"}
 
 
 def test_search_mask_keeps_its_jit_name():
