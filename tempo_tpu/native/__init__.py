@@ -17,6 +17,7 @@ import threading
 import numpy as np
 
 _LIB = None
+_LIB_HOLD = None   # the key index's calls that keep the interpreter lock
 _TRIED = False
 _LOCK = threading.Lock()
 
@@ -165,7 +166,7 @@ def _build() -> str | None:
 
 
 def _load():
-    global _LIB, _TRIED
+    global _LIB, _LIB_HOLD, _TRIED
     with _LOCK:
         if _TRIED:
             return _LIB
@@ -271,19 +272,45 @@ def _load():
                 c.c_void_p, c.c_int64, c.c_int64, c.c_int64, c.c_int64,
                 u8p, i32p, i32p]
             lib.group_keys_strided.restype = c.c_int64
-            # live trace index
-            lib.tindex_new.restype = c.c_void_p
-            lib.tindex_free.argtypes = [c.c_void_p]
-            for name in ("tindex_lookup", "tindex_upsert", "tindex_discard"):
-                getattr(lib, name).argtypes = [
-                    c.c_void_p, c.c_void_p, c.c_int64, c.c_void_p]
-                getattr(lib, name).restype = None
-            lib.tindex_size.argtypes = [c.c_void_p]
-            lib.tindex_size.restype = c.c_int64
+            _bind_key_index(lib)
+            # the same entry points through a handle that keeps the
+            # interpreter lock across the call: a call of tens of
+            # microseconds that gives the lock up can wait a whole switch
+            # interval (5 ms) for it again behind a busy Python thread
+            _LIB_HOLD = _bind_key_index(ctypes.PyDLL(so))
             _LIB = lib
         except Exception:
             _LIB = None
         return _LIB
+
+
+def _bind_key_index(lib):
+    """The exact key index's entry points (live traces, service-graph
+    halves)."""
+    c = ctypes
+    lib.kindex_new.argtypes = [c.c_int64]
+    lib.kindex_new.restype = c.c_void_p
+    lib.tindex_free.argtypes = [c.c_void_p]
+    for name in ("tindex_lookup", "tindex_upsert", "tindex_discard"):
+        getattr(lib, name).argtypes = [
+            c.c_void_p, c.c_void_p, c.c_int64, c.c_void_p]
+        getattr(lib, name).restype = None
+    lib.tindex_size.argtypes = [c.c_void_p]
+    lib.tindex_size.restype = c.c_int64
+    lib.sg_pair.argtypes = [
+        c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p,
+        c.c_int64, c.c_void_p, c.c_int64, c.c_void_p, c.c_void_p,
+        c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p]
+    lib.sg_pair.restype = c.c_int64
+    lib.first_svals.argtypes = [
+        c.c_void_p, c.c_void_p, c.c_int64, c.c_void_p, c.c_int64,
+        c.c_void_p, c.c_int64, c.c_void_p]
+    lib.first_svals.restype = None
+    lib.sg_expire.argtypes = [
+        c.c_void_p, c.c_void_p, c.c_int64, c.c_void_p, c.c_int64,
+        c.c_double, c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p]
+    lib.sg_expire.restype = c.c_int64
+    return lib
 
 
 def available() -> bool:
@@ -538,23 +565,35 @@ class NativeRowTable:
         return int(self._lib.rowtable_size(self._h))
 
 
-class TraceIndex:
-    """Exact trace key -> int64 slot of a live store: a key is a [17]
-    uint8 row, the id zero-padded to 16 bytes, then its length (the key a
-    push is grouped by). A call takes a whole push's or cut's keys (one
-    key where a dict route pushes beside staged traces).
-    `discard` forgets a key only where it still names the given slot, so
-    a sweep may discard outside the store's lock while pushes upsert.
-    Without the native library (or with `use_native=False`) a dict under
-    a lock does the same."""
+class KeyIndex:
+    """Exact fixed-width key -> int64 value: a key is a [width] uint8 row.
+    A call takes a whole push's or cut's keys. `discard` forgets a key
+    only where it still names the given value, so a sweep may discard
+    outside the store's lock while pushes upsert. Without the native
+    library (or with `use_native=False`) a dict under a lock does the
+    same, call for call."""
 
-    def __init__(self, use_native: bool = True) -> None:
+    width = 0
+    # keep the interpreter lock through a call: for an index that a lock
+    # of its owner already serialises, whose calls are short
+    hold_gil = False
+
+    def __init__(self, use_native: bool = True, width: int = 0) -> None:
+        self.width = width or self.width
+        if self.width < 8:
+            raise ValueError(f"a key is 8 bytes or more, not {self.width}")
         self._lib = _load() if use_native else None
+        if self._lib is not None and self.hold_gil:
+            self._lib = _LIB_HOLD
         if self._lib is None:
             self._d: dict[bytes, int] = {}
             self._lock = threading.Lock()
         else:
-            self._h = ctypes.c_void_p(self._lib.tindex_new())
+            self._h = ctypes.c_void_p(self._lib.kindex_new(self.width))
+
+    @property
+    def native(self) -> bool:
+        return self._lib is not None
 
     def __del__(self) -> None:
         h = getattr(self, "_h", None)
@@ -570,8 +609,8 @@ class TraceIndex:
         return int(self._lib.tindex_size(self._h))
 
     def lookup(self, keys: np.ndarray) -> np.ndarray:
-        """The slot of each key, -1 where it has none."""
-        keys = _trace_keys(keys)
+        """The value of each key, -1 where it has none."""
+        keys = self._keys(keys)
         out = np.empty(len(keys), np.int64)
         if self._lib is None:
             with self._lock:
@@ -583,7 +622,7 @@ class TraceIndex:
         return out
 
     def upsert(self, keys: np.ndarray, slots: np.ndarray) -> None:
-        keys = _trace_keys(keys)
+        keys = self._keys(keys)
         slots = np.ascontiguousarray(slots, np.int64)
         if slots.shape != (len(keys),):
             raise ValueError("one slot a key")
@@ -595,7 +634,7 @@ class TraceIndex:
                                     slots.ctypes.data)
 
     def discard(self, keys: np.ndarray, slots: np.ndarray) -> None:
-        keys = _trace_keys(keys)
+        keys = self._keys(keys)
         slots = np.ascontiguousarray(slots, np.int64)
         if slots.shape != (len(keys),):
             raise ValueError("one slot a key")
@@ -608,16 +647,204 @@ class TraceIndex:
             self._lib.tindex_discard(self._h, keys.ctypes.data, len(keys),
                                      slots.ctypes.data)
 
+    def _keys(self, keys: np.ndarray) -> np.ndarray:
+        """[n, width] uint8, from those rows or from one void key a row."""
+        if keys.dtype == np.dtype(f"V{self.width}") and keys.ndim == 1:
+            keys = np.ascontiguousarray(keys).view(np.uint8).reshape(
+                -1, self.width)
+        keys = np.ascontiguousarray(keys, np.uint8)
+        if keys.ndim != 2 or keys.shape[1] != self.width:
+            raise ValueError(f"keys are [n, {self.width}] uint8, "
+                             f"not {keys.shape}")
+        return keys
 
-def _trace_keys(keys: np.ndarray) -> np.ndarray:
-    keys = np.ascontiguousarray(keys, np.uint8)
-    if keys.ndim != 2 or keys.shape[1] != 17:
-        raise ValueError(f"trace keys are [n, 17] uint8, not {keys.shape}")
-    return keys
+
+class TraceIndex(KeyIndex):
+    """Exact trace key -> int64 slot of a live store: a key is a [17]
+    uint8 row, the id zero-padded to 16 bytes, then its length (the key a
+    push is grouped by). A call takes a whole push's or cut's keys (one
+    key where a dict route pushes beside staged traces)."""
+
+    width = 17
+
+
+class HalfIndex(KeyIndex):
+    """The service-graph half-edge store's keys: a [24] uint8 row, trace id
+    + span id of a CLIENT/PRODUCER half, trace id + parent span id of a
+    SERVER/CONSUMER half; the value of a waiting half is `2 * slot +
+    is_client`. `pair` walks a whole push's halves in one call, `expire`
+    a whole due prefix of the TTL ring."""
+
+    width = 24
+    hold_gil = True
+
+    def pair(self, trace_ids: np.ndarray, span_ids: np.ndarray,
+             parent_ids: np.ndarray, rows: np.ndarray, is_client: np.ndarray,
+             max_items: int, fresh: np.ndarray) -> tuple:
+        """Walk batch rows `rows`, in order, against the waiting halves. A
+        row's key is its trace id ([cap, 16]) and its own span id ([cap,
+        8]) where it is a client, its parent's where it is a server. A row
+        that meets a half of the other side under its key completes it:
+        that half leaves, `out` = its slot, `matched` True. Else, while
+        fewer than `max_items` halves wait, the row waits in the next slot
+        of `fresh` (`out`), replacing a same-side half under its key
+        (`prev` = that half's slot, else -1); with `max_items` waiting it
+        is dropped (`out` -1) and a same-side half stays. Returns (keys,
+        one void key a row; out; matched; prev; root, the rows whose
+        parent id is all zero; how many of `fresh` were taken, in order);
+        `fresh` holds a slot a row, none taken twice."""
+        trace_ids = np.ascontiguousarray(trace_ids, np.uint8)
+        span_ids = np.ascontiguousarray(span_ids, np.uint8)
+        parent_ids = np.ascontiguousarray(parent_ids, np.uint8)
+        rows = np.ascontiguousarray(rows, np.int64)
+        n, cap = len(rows), len(trace_ids)
+        side = np.ascontiguousarray(is_client, np.uint8)
+        fresh = np.ascontiguousarray(fresh, np.int64)
+        if (trace_ids.shape != (cap, 16) or span_ids.shape != (cap, 8)
+                or parent_ids.shape != (cap, 8)):
+            raise ValueError("ids are [cap, 16] and [cap, 8] uint8")
+        if side.shape != (n,) or len(fresh) < n or (
+                n and (rows.min() < 0 or rows.max() >= cap)):
+            raise ValueError("a row, a side and a fresh slot a half")
+        keys = np.empty((n, self.width), np.uint8)
+        out = np.empty(n, np.int64)
+        matched = np.zeros(n, np.uint8)
+        prev = np.empty(n, np.int64)
+        root = np.empty(n, np.uint8)
+        if self._lib is None:
+            keys[:, :16] = trace_ids[rows]
+            keys[:, 16:] = np.where(side[:, None] == 1, span_ids[rows],
+                                    parent_ids[rows])
+            root[:] = ~parent_ids[rows].any(axis=1)
+            with self._lock:
+                taken = _pair_dict(self._d, _key_bytes(keys), side.tolist(),
+                                   max_items, fresh.tolist(), out, matched,
+                                   prev)
+        elif n:
+            taken = int(self._lib.sg_pair(
+                self._h, trace_ids.ctypes.data, span_ids.ctypes.data,
+                parent_ids.ctypes.data, rows.ctypes.data, n,
+                side.ctypes.data, int(max_items), fresh.ctypes.data,
+                out.ctypes.data, matched.ctypes.data, prev.ctypes.data,
+                keys.ctypes.data, root.ctypes.data))
+        else:
+            taken = 0
+        return (keys.view(f"V{self.width}").ravel(), out,
+                matched.view(np.bool_), prev, root.view(np.bool_), taken)
+
+    def expire(self, keys: np.ndarray, expire_at: np.ndarray, now: float
+               ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Walk the ring entries `keys` in order: the half waiting under a
+        key is due where `expire_at[slot] <= now`; it leaves, and its slot
+        joins `gone` (in order; another entry of the same key then finds
+        nothing). A half due later gives its entry's row to `later` and
+        its time to `later_at`. Returns (gone, later, later_at)."""
+        keys = self._keys(keys)
+        n = len(keys)
+        expire_at = np.ascontiguousarray(expire_at, np.float64)
+        gone = np.empty(n, np.int64)
+        later = np.empty(n, np.int64)
+        later_at = np.empty(n, np.float64)
+        if self._lib is None:
+            with self._lock:
+                ng, nl = _expire_dict(self._d, _key_bytes(keys), expire_at,
+                                      now, gone, later, later_at)
+        elif n:
+            n_later = np.zeros(1, np.int64)
+            ng = int(self._lib.sg_expire(
+                self._h, keys.ctypes.data, n, expire_at.ctypes.data,
+                len(expire_at), float(now), gone.ctypes.data,
+                later.ctypes.data, later_at.ctypes.data, n_later.ctypes.data))
+            if ng < 0:
+                raise ValueError("a waiting half's slot is past expire_at")
+            nl = int(n_later[0])
+        else:
+            ng = nl = 0
+        return gone[:ng], later[:nl], later_at[:nl]
+
+
+def first_svals(attr_keys: np.ndarray, attr_svals: np.ndarray,
+                rows: np.ndarray, kids: list, use_native: bool = True
+                ) -> np.ndarray:
+    """For each batch row in `rows`: the interned string value of the
+    first attribute key of `kids` (in order) that the row carries with
+    one, else -1. A row's value for a key is that of the key's first
+    column of `attr_keys` ([cap, width] int32), as
+    `SpanBatch.attr_sval_column` reads it."""
+    rows = np.ascontiguousarray(rows, np.int64)
+    out = np.full(len(rows), -1, np.int32)
+    width = attr_keys.shape[1]
+    if not len(rows) or not kids or width == 0:
+        return out
+    lib = _load() if use_native else None
+    if lib is None:
+        keys, svals = attr_keys[rows], attr_svals[rows]
+        at = np.arange(len(rows))
+        for kid in kids:
+            hit = keys == kid
+            val = np.where(hit.any(axis=1), svals[at, hit.argmax(axis=1)],
+                           -1)
+            out = np.where(out != -1, out, val)
+        return out
+    attr_keys = np.ascontiguousarray(attr_keys, np.int32)
+    attr_svals = np.ascontiguousarray(attr_svals, np.int32)
+    if attr_svals.shape != attr_keys.shape or rows.min() < 0 \
+            or rows.max() >= len(attr_keys):
+        raise ValueError("rows of one [cap, width] key and value table")
+    kids = np.asarray(kids, np.int32)
+    _LIB_HOLD.first_svals(attr_keys.ctypes.data, attr_svals.ctypes.data,
+                          width, rows.ctypes.data, len(rows),
+                          kids.ctypes.data, len(kids), out.ctypes.data)
+    return out
+
+
+def _pair_dict(d: dict, keys: list, sides: list, max_items: int,
+               fresh: list, out: np.ndarray, matched: np.ndarray,
+               prev: np.ndarray) -> int:
+    """`HalfIndex.pair` over a dict: the native walk, step for step."""
+    taken = 0
+    for r, (k, side) in enumerate(zip(keys, sides)):
+        v = d.get(k)
+        prev[r] = -1
+        if v is not None and (v & 1) != side:
+            del d[k]
+            out[r] = v >> 1
+            matched[r] = 1
+            continue
+        if len(d) >= max_items:
+            out[r] = -1
+            continue
+        s = fresh[taken]
+        taken += 1
+        out[r] = s
+        if v is not None:
+            prev[r] = v >> 1
+        d[k] = 2 * s + side
+    return taken
+
+
+def _expire_dict(d: dict, keys: list, expire_at: np.ndarray, now: float,
+                 gone: np.ndarray, later: np.ndarray,
+                 later_at: np.ndarray) -> tuple[int, int]:
+    """`HalfIndex.expire` over a dict: the native walk, step for step."""
+    ng = nl = 0
+    for r, k in enumerate(keys):
+        v = d.get(k)
+        if v is None:
+            continue
+        at = expire_at[v >> 1]
+        if at <= now:
+            del d[k]
+            gone[ng] = v >> 1
+            ng += 1
+        else:
+            later[nl], later_at[nl] = r, at
+            nl += 1
+    return ng, nl
 
 
 def _key_bytes(keys: np.ndarray) -> list[bytes]:
-    """Each [17] key row as one bytes object (a void view keeps trailing
+    """Each [w] key row as one bytes object (a void view keeps trailing
     zero bytes)."""
     return keys.view(f"V{keys.shape[1]}").ravel().tolist()
 

@@ -1669,43 +1669,55 @@ int64_t group_keys_strided(const void* recs_p, int64_t n, int64_t rec_size,
 
 }  // extern "C"
 
-// --- live trace index ---------------------------------------------------------
+// --- exact key index ---------------------------------------------------------
 //
-// Exact trace key -> int64 slot of a live store (`utils/livetraces.py`).
-// A key is 17 bytes: the id zero-padded to 16, then its length (the key
-// `group_keys` groups a push by). Lookup, upsert and discard take a whole
-// push's or a whole cut's keys in one call, so neither runs a Python step a
-// trace. Open addressing with tombstones, under its own mutex: a ctypes
-// call gives the interpreter up, and the cut discards outside the store's
-// lock.
+// Exact fixed-width key -> int64 value. Two users: the live trace stores
+// (`utils/livetraces.py`), whose key is 17 bytes (the trace id zero-padded
+// to 16, then its length: the key `group_keys` groups a push by) and whose
+// value is a store slot; and the service-graph half-edge store
+// (`generator/processors/servicegraphs.py`), whose key is 24 bytes (trace
+// id + span id of a client, trace id + parent span id of a server) and
+// whose value is `2 * slot + is_client`. Lookup, upsert, discard and the
+// pairing walk take a whole push's or a whole cut's keys in one call, so
+// none runs a Python step a key. Open addressing with tombstones, under
+// its own mutex: the live stores' calls give the interpreter up, and a cut
+// discards outside the store's lock (the half-edge store's calls keep the
+// interpreter: its owner's lock serialises them, and they are short).
 
 namespace {
 
 constexpr int64_t kTxEmpty = -1;
 constexpr int64_t kTxGone = -2;
 
-struct TraceIndex {
+struct KeyIndex {
     std::mutex mu;
-    std::vector<uint8_t> keys;     // cell i -> keys[17 i .. 17 i + 17)
-    std::vector<int64_t> vals;     // cell i -> slot, kTxEmpty or kTxGone
+    const size_t w;                // key width in bytes, >= 8
+    std::vector<uint8_t> keys;     // cell i -> keys[w i .. w i + w)
+    std::vector<int64_t> vals;     // cell i -> value >= 0, kTxEmpty or kTxGone
     uint64_t mask = 0;
     int64_t live = 0;
     int64_t used = 0;              // live cells + tombstones
 
-    TraceIndex() { reset(1 << 12); }
+    explicit KeyIndex(size_t width) : w(width) { reset(1 << 12); }
 
     void reset(size_t cap) {
-        keys.assign(cap * 17, 0);
+        keys.assign(cap * w, 0);
         vals.assign(cap, kTxEmpty);
         mask = cap - 1;
         live = used = 0;
     }
 
-    static inline uint64_t hash(const uint8_t* k) {
-        uint64_t a, b;
-        memcpy(&a, k, 8);
-        memcpy(&b, k + 8, 8);
-        uint64_t x = a ^ (b * 0x9E3779B97F4A7C15ull) ^ ((uint64_t)k[16] << 56);
+    // the key's 8-byte words, then its tail bytes, folded and mixed
+    inline uint64_t hash(const uint8_t* k) const {
+        uint64_t x = w;
+        size_t i = 0;
+        for (; i + 8 <= w; i += 8) {
+            uint64_t a;
+            memcpy(&a, k + i, 8);
+            x = (x ^ a) * 0x9E3779B97F4A7C15ull;
+            x ^= x >> 32;
+        }
+        for (; i < w; i++) x = (x ^ k[i]) * 0x100000001B3ull;
         x ^= x >> 30; x *= 0xBF58476D1CE4E5B9ull;
         x ^= x >> 27; x *= 0x94D049BB133111EBull;
         return x ^ (x >> 31);
@@ -1715,7 +1727,7 @@ struct TraceIndex {
     int64_t find(const uint8_t* k) const {
         uint64_t i = hash(k) & mask;
         while (vals[i] != kTxEmpty) {
-            if (vals[i] != kTxGone && memcmp(&keys[i * 17], k, 17) == 0)
+            if (vals[i] != kTxGone && memcmp(&keys[i * w], k, w) == 0)
                 return (int64_t)i;
             i = (i + 1) & mask;
         }
@@ -1729,7 +1741,7 @@ struct TraceIndex {
         ov.swap(vals);
         reset(cap);
         for (size_t c = 0; c < ov.size(); c++)
-            if (ov[c] >= 0) put(&ok[c * 17], ov[c]);
+            if (ov[c] >= 0) put(&ok[c * w], ov[c]);
     }
 
     void put(const uint8_t* k, int64_t v) {
@@ -1738,12 +1750,17 @@ struct TraceIndex {
         uint64_t i = hash(k) & mask;
         while (vals[i] >= 0) i = (i + 1) & mask;
         if (vals[i] == kTxEmpty) used++;
-        memcpy(&keys[i * 17], k, 17);
+        memcpy(&keys[i * w], k, w);
         vals[i] = v;
         live++;
         if (used * 10 > (int64_t)vals.size() * 7)
             rehash(live * 10 > (int64_t)vals.size() * 4 ? vals.size() * 2
                                                         : vals.size());
+    }
+
+    void drop_cell(int64_t c) {
+        vals[c] = kTxGone;
+        live--;
     }
 };
 
@@ -1751,15 +1768,15 @@ struct TraceIndex {
 
 extern "C" {
 
-void* tindex_new() { return new TraceIndex(); }
-void tindex_free(void* h) { delete (TraceIndex*)h; }
+void* kindex_new(int64_t width) { return new KeyIndex((size_t)width); }
+void tindex_free(void* h) { delete (KeyIndex*)h; }
 
-// out[i] = slot of keys[i], or -1
+// out[i] = value of keys[i], or -1
 void tindex_lookup(void* h, const uint8_t* keys, int64_t n, int64_t* out) {
-    TraceIndex* t = (TraceIndex*)h;
+    KeyIndex* t = (KeyIndex*)h;
     std::lock_guard<std::mutex> g(t->mu);
     for (int64_t r = 0; r < n; r++) {
-        int64_t c = t->find(keys + r * 17);
+        int64_t c = t->find(keys + r * t->w);
         out[r] = c < 0 ? -1 : t->vals[c];
     }
 }
@@ -1767,29 +1784,131 @@ void tindex_lookup(void* h, const uint8_t* keys, int64_t n, int64_t* out) {
 // keys[i] -> slots[i], whether or not the key was there
 void tindex_upsert(void* h, const uint8_t* keys, int64_t n,
                    const int64_t* slots) {
-    TraceIndex* t = (TraceIndex*)h;
+    KeyIndex* t = (KeyIndex*)h;
     std::lock_guard<std::mutex> g(t->mu);
-    for (int64_t r = 0; r < n; r++) t->put(keys + r * 17, slots[r]);
+    for (int64_t r = 0; r < n; r++) t->put(keys + r * t->w, slots[r]);
 }
 
 // forget keys[i] where it still names slots[i] (an upsert since wins)
 void tindex_discard(void* h, const uint8_t* keys, int64_t n,
                     const int64_t* slots) {
-    TraceIndex* t = (TraceIndex*)h;
+    KeyIndex* t = (KeyIndex*)h;
     std::lock_guard<std::mutex> g(t->mu);
     for (int64_t r = 0; r < n; r++) {
-        int64_t c = t->find(keys + r * 17);
-        if (c >= 0 && t->vals[c] == slots[r]) {
-            t->vals[c] = kTxGone;
-            t->live--;
-        }
+        int64_t c = t->find(keys + r * t->w);
+        if (c >= 0 && t->vals[c] == slots[r]) t->drop_cell(c);
     }
 }
 
 int64_t tindex_size(void* h) {
-    TraceIndex* t = (TraceIndex*)h;
+    KeyIndex* t = (KeyIndex*)h;
     std::lock_guard<std::mutex> g(t->mu);
     return t->live;
+}
+
+// The service-graph pairing walk over one push's halves, in row order.
+// Row r is batch row rows[r]: its key (written to keys[24 r ..]) is the
+// row's 16-byte trace id, then its own 8-byte span id where it is a client
+// or its parent's where it is a server; root[r] = 1 where the parent id is
+// all zero. A key names the half waiting under it: its value is `2 * slot
+// + is_client`. A row meets a half of the other side under its key: that
+// half leaves the index and out[r] = its slot, matched[r] = 1. Else, while
+// fewer than `max_items` halves wait, the row takes the next of `fresh`
+// (a half of its own side under the key is replaced: prev[r] = that
+// half's slot) and out[r] = its slot; with `max_items` waiting the row is
+// dropped (out[r] = -1) and a same-side half stays. Returns how many of
+// `fresh` were taken, in order. No slot is taken twice in a call. The
+// index's keys are 24 bytes wide.
+int64_t sg_pair(void* h, const uint8_t* trace_ids, const uint8_t* span_ids,
+                const uint8_t* parent_ids, const int64_t* rows, int64_t n,
+                const uint8_t* is_client, int64_t max_items,
+                const int64_t* fresh, int64_t* out, uint8_t* matched,
+                int64_t* prev, uint8_t* keys, uint8_t* root) {
+    KeyIndex* t = (KeyIndex*)h;
+    std::lock_guard<std::mutex> g(t->mu);
+    int64_t taken = 0;
+    for (int64_t r = 0; r < n; r++) {
+        const int64_t side = is_client[r] ? 1 : 0;
+        const uint8_t* parent = parent_ids + rows[r] * 8;
+        uint8_t* k = keys + r * 24;
+        memcpy(k, trace_ids + rows[r] * 16, 16);
+        memcpy(k + 16, side ? span_ids + rows[r] * 8 : parent, 8);
+        uint64_t p;
+        memcpy(&p, parent, 8);
+        root[r] = p == 0;
+        int64_t c = t->find(k);
+        matched[r] = 0;
+        prev[r] = -1;
+        if (c >= 0 && (t->vals[c] & 1) != side) {
+            out[r] = t->vals[c] >> 1;
+            matched[r] = 1;
+            t->drop_cell(c);
+            continue;
+        }
+        if (t->live >= max_items) {
+            out[r] = -1;
+            continue;
+        }
+        const int64_t s = fresh[taken++];
+        out[r] = s;
+        if (c >= 0) {
+            prev[r] = t->vals[c] >> 1;
+            t->vals[c] = 2 * s + side;
+        } else {
+            t->put(k, 2 * s + side);
+        }
+    }
+    return taken;
+}
+
+// For each batch row rows[r]: the string value of the first attribute
+// key of `kids` (in order) that the row carries with one, else -1. A
+// row's attributes are attr_keys / attr_svals[row * width ..] and its
+// value for a key is that of the key's first column (SpanBatch's
+// `attr_sval_column` rule, applied key by key).
+void first_svals(const int32_t* attr_keys, const int32_t* attr_svals,
+                 int64_t width, const int64_t* rows, int64_t n,
+                 const int32_t* kids, int64_t n_kids, int32_t* out) {
+    for (int64_t r = 0; r < n; r++) {
+        const int32_t* ks = attr_keys + rows[r] * width;
+        const int32_t* vs = attr_svals + rows[r] * width;
+        int32_t v = -1;
+        for (int64_t q = 0; q < n_kids && v < 0; q++)
+            for (int64_t j = 0; j < width; j++)
+                if (ks[j] == kids[q]) { v = vs[j]; break; }
+        out[r] = v;
+    }
+}
+
+// The service-graph TTL ring's due prefix, in order. An entry names a key:
+// the key's waiting half (value `2 * slot + is_client`) is due where
+// expire_at[slot] <= now; it leaves the index and its slot goes to gone[]
+// (a later entry of the same key finds nothing). A half due later gives
+// its entry's row to later[] and its time to later_at[] (the caller queues
+// the entry again). Returns how many are gone, or -1 where a slot is past
+// the n_at times given; n_later[0] = how many later.
+int64_t sg_expire(void* h, const uint8_t* keys, int64_t n,
+                  const double* expire_at, int64_t n_at, double now,
+                  int64_t* gone, int64_t* later, double* later_at,
+                  int64_t* n_later) {
+    KeyIndex* t = (KeyIndex*)h;
+    std::lock_guard<std::mutex> g(t->mu);
+    int64_t ng = 0, nl = 0;
+    for (int64_t r = 0; r < n; r++) {
+        int64_t c = t->find(keys + r * t->w);
+        if (c < 0) continue;
+        const int64_t s = t->vals[c] >> 1;
+        if (s >= n_at) return -1;
+        if (expire_at[s] <= now) {
+            t->drop_cell(c);
+            gone[ng++] = s;
+        } else {
+            later[nl] = r;
+            later_at[nl++] = expire_at[s];
+        }
+    }
+    n_later[0] = nl;
+    return ng;
 }
 
 }  // extern "C"

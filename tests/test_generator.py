@@ -273,8 +273,11 @@ def _edge_compiles() -> float:
 
 
 def _edges(reg, n: int, seed: int = 0) -> list:
-    """`n` completed-edge tuples over 5 (client, server) pairs, so slots
-    repeat inside a batch; every third failed, every fourth messaging."""
+    """`n` completed edges, as columns, over 5 (client, server) pairs, so
+    slots repeat inside a batch; every third failed, every fourth
+    messaging."""
+    from tempo_tpu.generator.processors.servicegraphs import EdgeColumns
+
     it = reg.interner
     r = np.random.default_rng(seed)
     out = []
@@ -282,10 +285,12 @@ def _edges(reg, n: int, seed: int = 0) -> list:
         pair = int(r.integers(5))
         msg = j % 4 == 1
         out.append((it.intern(f"cli-{pair}"), it.intern(f"srv-{pair}"),
-                    "messaging_system" if msg else "",
+                    1 if msg else 0,
                     float(r.uniform(0.001, 20.0)), float(r.uniform(0.001, 20.0)),
                     j % 3 == 0, float(r.uniform(0.0, 2.0)) if msg else 0.0))
-    return out
+    return EdgeColumns(*(np.array(col, dt) for col, dt in zip(
+        zip(*out), (np.int32, np.int32, np.int8, np.float64, np.float64,
+                    np.bool_, np.float64))))
 
 
 def _sg_pair(messaging: bool, clock=None):
