@@ -72,14 +72,23 @@ class ColumnSource:
         columns in one pass (a key a span repeats counts each time; a dict
         keeps it once).
 
-        Few numpy calls on purpose: under four request threads every
-        call over 500 elements hands the interpreter over, and the wait
-        to get it back, not the arithmetic, is what a push pays."""
+        Every row of a batch that carries the staging's trace order
+        (`SpanBatch.trace_order`) takes that order, where this source keys
+        rows as it does: with the staging's id lengths, or where every id
+        is 16 bytes long (a bare batch keys every row at 16). Else few
+        numpy calls on purpose: under four request threads every call over
+        500 elements hands the interpreter over, and the wait to get it
+        back, not the arithmetic, is what a push pays."""
         sb = self.batch
         pick = slice(0, sb.n) if rows is None else rows
         n = sb.n if rows is None else len(rows)
         if not n:
             return None
+        got = sb.trace_order
+        if rows is None and got is not None and (
+                self.staged is not None or got.same_length):
+            return ColumnChunk(self, got.order, got.keys, got.trace_spans,
+                               got.trace_sizes, "staged")
         # the exact id is (padded bytes, length): one 17-byte key a row
         keys = np.empty((n, 17), np.uint8)
         keys[:, :16] = sb.trace_id[pick]
@@ -93,28 +102,30 @@ class ColumnSource:
             != INVALID_ID).sum(axis=1))
         return ColumnChunk(self, order if rows is None else rows[order],
                            keys[first], spans,
-                           200 * spans + 32 * attrs.astype(np.int64))
+                           200 * spans + 32 * attrs.astype(np.int64), "own")
 
 
 class ColumnChunk:
     """One staged push in a live store: `rows` of `source` grouped by
     trace (a trace's rows contiguous, in push order; traces in first-seen
     order), each trace's index `keys` ([17] uint8), span count `spans` and
-    approximate `sizes`. The store sets `row_slot` (each row's trace slot;
-    the rows of refused traces leave) and `seq` (its arrival number, which
-    orders a trace's chunks)."""
+    approximate `sizes`; `grouping` says who grouped them (`staged`: the
+    staging's native pass, `own`: `ColumnSource.chunk`). The store sets
+    `row_slot` (each row's trace slot; the rows of refused traces leave)
+    and `seq` (its arrival number, which orders a trace's chunks)."""
 
-    __slots__ = ("source", "rows", "keys", "spans", "sizes", "row_slot",
-                 "seq")
+    __slots__ = ("source", "rows", "keys", "spans", "sizes", "grouping",
+                 "row_slot", "seq")
 
     def __init__(self, source: ColumnSource, rows: np.ndarray,
                  keys: np.ndarray, spans: np.ndarray,
-                 sizes: np.ndarray) -> None:
+                 sizes: np.ndarray, grouping: str) -> None:
         self.source = source
         self.rows = rows
         self.keys = keys
         self.spans = spans
         self.sizes = sizes
+        self.grouping = grouping
         self.row_slot = None
         self.seq = -1
 

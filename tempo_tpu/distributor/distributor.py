@@ -754,11 +754,15 @@ class Distributor:
                 return errs
 
         # regroup by trace over the staged id columns (id ‖ wire length,
-        # as the columnar path keys) — straight off the StageRec rows
+        # as the columnar path keys): where every row is valid, the order
+        # the staged batch's native pass found, which the live stores
+        # take too; else straight off the StageRec rows
         from tempo_tpu import native as _native
 
         vrows = np.flatnonzero(valid)
-        got = _native.group_keys_strided(recs, valid)
+        order = staged.batch()[0].trace_order if len(vrows) == n else None
+        got = (order.first, order.inverse) if order is not None else \
+            _native.group_keys_strided(recs, valid)
         if got is not None:
             first, inverse = got
         else:

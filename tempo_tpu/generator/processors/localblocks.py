@@ -77,7 +77,8 @@ class LocalBlocksProcessor:
     def push_batch(self, sb: SpanBatch) -> None:
         """Group the batch back by trace and append to live traces
         (deterministic, `processor.go:155`): the batch enters the store
-        as one chunk, no span dicts."""
+        as one chunk, no span dicts. A staged batch whose every row is
+        valid comes grouped already (`ColumnSource.chunk`)."""
         valid = sb.valid[: sb.n]
         self.inst.push_columns(
             ColumnSource(sb), None if valid.all() else np.flatnonzero(valid))

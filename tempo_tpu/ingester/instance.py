@@ -30,6 +30,7 @@ from tempo_tpu.model.combine import combine_spans, sort_spans
 from tempo_tpu.overrides.limits import Limits
 from tempo_tpu.utils import tracing
 from tempo_tpu.utils.livetraces import (
+    CHUNK_SPANS,
     ERR_LIVE_TRACES_EXCEEDED,
     ERR_TRACE_TOO_LARGE,
     LIVE_SPANS,
@@ -127,6 +128,7 @@ class TenantInstance:
         if chunk is None:
             return {}
         spans = int(chunk.spans.sum())
+        CHUNK_SPANS.inc(spans, (chunk.grouping,))
         with self.lock:
             refused = self.live.push_chunk(chunk)
             for err in refused.values():

@@ -18,6 +18,8 @@ span; here one C scan emits interned columns and numpy finishes the job.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import numpy as np
 
 from tempo_tpu.model.interner import INVALID_ID, StringInterner
@@ -267,6 +269,55 @@ def _batch_from_staged(data: bytes, interner: StringInterner, staged,
     return sb
 
 
+class TraceOrder(NamedTuple):
+    """A staged push's rows grouped by exact trace id: the id zero-padded
+    to 16 bytes, then its length capped at 16 (the key
+    `block.live_columns.ColumnSource.chunk` groups by). Traces come in
+    first-seen order. Built with the staged batch, and set on that batch
+    object alone (`SpanBatch.trace_order`)."""
+
+    first: np.ndarray          # each trace's first row
+    inverse: np.ndarray        # each row's trace
+    order: np.ndarray          # the rows trace by trace, push order within
+    trace_spans: np.ndarray    # a trace's spans
+    trace_sizes: np.ndarray    # its bytes: 200 a span + 32 an attr key
+    keys: np.ndarray           # [17] uint8 a trace
+    same_length: bool          # every id is 16 bytes: the padded bytes
+                               # alone group the rows so too
+
+
+def _derive_staged(st: "StagedIngest"
+                   ) -> "tuple[SpanBatch, np.ndarray] | None":
+    """What `_batch_from_staged` makes of `st` (the batch and the wire
+    sizes, equal column for column), with the push's `TraceOrder` on the
+    batch, from one native pass over the staged records. None where the
+    native library is absent or the records need Python: a non-scalar
+    attribute value (`_fix_nonscalar`) or a service.name that is no string
+    (`needs_service_fixup`)."""
+    from tempo_tpu import native
+
+    it = st.interner
+    it.sync()
+    n = st.n
+    sattrs = st.sattrs if st.has_span_attrs else st.sattrs[:0]
+    with_res = bool(st.include_res_attrs and n and len(st.res))
+    widths = native.stage_widths(sattrs, st.rattrs, st.res, n,
+                                 it.get("service.name"), with_res)
+    if widths is None:
+        return None
+    sw = _pad_width(min(widths[0], _MAX_SPAN_ATTRS)) if n else 0
+    rw = _pad_width(min(widths[1], _MAX_RES_ATTRS)) if with_res else 0
+    got = native.stage_derive(st.spans, sattrs, st.rattrs, st.res,
+                              _pad_rows(max(n, 1)), sw, rw, it.intern(""))
+    if got is None:
+        return None
+    sizes = got.pop("sizes")
+    order = TraceOrder(*(got.pop(f) for f in TraceOrder._fields))
+    sb = SpanBatch(n=n, interner=it, **got)
+    sb.trace_order = order
+    return sb, sizes
+
+
 # ---------------------------------------------------------------------------
 # decode-once staging: one OTLP payload, shared by every tee target
 # ---------------------------------------------------------------------------
@@ -358,14 +409,19 @@ class StagedIngest:
 
     def batch(self) -> tuple["SpanBatch", np.ndarray]:
         """The staged columnar SpanBatch + per-span wire sizes, built on
-        first use and shared by every subsequent view."""
+        first use and shared by every subsequent view: by one native pass
+        that also groups the rows by trace (`SpanBatch.trace_order`), or
+        by numpy where that pass cannot (no order then)."""
         if self._batch is None:
-            self._batch, self._sizes = _batch_from_staged(
-                self.raw, self.interner,
-                (self.spans, self.sattrs, self.rattrs, self.res),
-                return_sizes=True,
-                include_span_attrs=self.has_span_attrs,
-                include_res_attrs=self.include_res_attrs)
+            got = _derive_staged(self)
+            if got is None:
+                got = _batch_from_staged(
+                    self.raw, self.interner,
+                    (self.spans, self.sattrs, self.rattrs, self.res),
+                    return_sizes=True,
+                    include_span_attrs=self.has_span_attrs,
+                    include_res_attrs=self.include_res_attrs)
+            self._batch, self._sizes = got
         return self._batch, self._sizes
 
     def events_links(self) -> tuple[dict, dict]:

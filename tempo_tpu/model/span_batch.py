@@ -103,6 +103,11 @@ class SpanBatch:
     valid: np.ndarray             # [N] bool
     interner: StringInterner
 
+    # the push's rows grouped by trace (`otlp_batch.TraceOrder`): set on
+    # the batch a staging's native pass built, and on no gather, copy or
+    # `dataclasses.replace` of it
+    trace_order = None
+
     @property
     def capacity(self) -> int:
         return self.valid.shape[0]

@@ -17,7 +17,7 @@ import threading
 import numpy as np
 
 _LIB = None
-_LIB_HOLD = None   # the key index's calls that keep the interpreter lock
+_LIB_HOLD = None   # the short calls that keep the interpreter lock
 _TRIED = False
 _LOCK = threading.Lock()
 
@@ -272,21 +272,22 @@ def _load():
                 c.c_void_p, c.c_int64, c.c_int64, c.c_int64, c.c_int64,
                 u8p, i32p, i32p]
             lib.group_keys_strided.restype = c.c_int64
-            _bind_key_index(lib)
+            _bind_held(lib)
             # the same entry points through a handle that keeps the
             # interpreter lock across the call: a call of tens of
             # microseconds that gives the lock up can wait a whole switch
             # interval (5 ms) for it again behind a busy Python thread
-            _LIB_HOLD = _bind_key_index(ctypes.PyDLL(so))
+            _LIB_HOLD = _bind_held(ctypes.PyDLL(so))
             _LIB = lib
         except Exception:
             _LIB = None
         return _LIB
 
 
-def _bind_key_index(lib):
-    """The exact key index's entry points (live traces, service-graph
-    halves)."""
+def _bind_held(lib):
+    """The entry points also called with the interpreter lock held: the
+    exact key index's (live traces, service-graph halves) and the staged
+    batch's."""
     c = ctypes
     lib.kindex_new.argtypes = [c.c_int64]
     lib.kindex_new.restype = c.c_void_p
@@ -310,6 +311,15 @@ def _bind_key_index(lib):
         c.c_void_p, c.c_void_p, c.c_int64, c.c_void_p, c.c_int64,
         c.c_double, c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p]
     lib.sg_expire.restype = c.c_int64
+    lib.stage_widths.argtypes = [
+        c.c_void_p, c.c_int64, c.c_void_p, c.c_int64, c.c_void_p,
+        c.c_int64, c.c_int64, c.c_int32, c.c_int32]
+    lib.stage_widths.restype = c.c_int64
+    lib.stage_derive.argtypes = [
+        c.c_void_p, c.c_int64, c.c_int64, c.c_void_p, c.c_int64,
+        c.c_int64, c.c_void_p, c.c_int64, c.c_int64, c.c_void_p,
+        c.c_int64, c.c_int32, c.c_void_p, c.c_void_p]
+    lib.stage_derive.restype = c.c_int64
     return lib
 
 
@@ -912,6 +922,116 @@ def otlp_stage(interner: "NativeInterner", data: bytes,
             return out
         cap, acap = max(cap, ns), max(acap, na)
         rcap, rescap = max(rcap, nr), max(rescap, nres)
+
+
+def stage_widths(sattrs: np.ndarray, rattrs: np.ndarray, res: np.ndarray,
+                 n: int, svc_key: int, with_res: bool
+                 ) -> "tuple[int, int] | None":
+    """(most attributes one span holds, one resource) in a staging's
+    records (`otlp_stage`; `n` spans, resource attributes read only
+    `with_res`), or None where the library is absent or the numpy route
+    must build the batch: a non-scalar value, a `service.name` (id
+    `svc_key`) that is no string, attributes out of their owners' order."""
+    if _load() is None:
+        return None
+    sattrs, rattrs, res = (np.ascontiguousarray(a)
+                           for a in (sattrs, rattrs, res))
+    _check_staged(None, sattrs, rattrs, res)
+    got = _LIB_HOLD.stage_widths(sattrs.ctypes.data, len(sattrs),
+                                 rattrs.ctypes.data, len(rattrs),
+                                 res.ctypes.data, len(res), n, svc_key,
+                                 int(with_res))
+    if got < 0:
+        return None
+    return got >> 32, got & 0xFFFFFFFF
+
+
+def _check_staged(spans, sattrs, rattrs, res) -> None:
+    """`otlp_stage`'s record arrays, or a ValueError before any pointer
+    reaches native code."""
+    for a, dt in ((spans, STAGE_REC_DTYPE), (sattrs, STAGE_ATTR_DTYPE),
+                  (rattrs, STAGE_ATTR_DTYPE), (res, STAGE_RES_DTYPE)):
+        if a is not None and (a.dtype != dt or a.ndim != 1):
+            raise ValueError(f"staged records are 1-D {dt}, not {a.dtype}")
+
+
+# the fields of `stage_derive`'s one output buffer, in the order of its
+# offsets (see native.cpp); a shape names `cap`, `sw` or `rw`
+_DERIVED = (
+    ("name_id", np.int32, ("cap",)), ("status_message_id", np.int32, ("cap",)),
+    ("service_id", np.int32, ("cap",)), ("kind", np.int32, ("cap",)),
+    ("status_code", np.int32, ("cap",)),
+    ("start_unix_nano", np.int64, ("cap",)),
+    ("end_unix_nano", np.int64, ("cap",)),
+    ("trace_id", np.uint8, ("cap", 16)), ("span_id", np.uint8, ("cap", 8)),
+    ("parent_span_id", np.uint8, ("cap", 8)),
+    ("span_attr_key", np.int32, ("cap", "sw")),
+    ("span_attr_sval", np.int32, ("cap", "sw")),
+    ("span_attr_fval", np.float32, ("cap", "sw")),
+    ("span_attr_typ", np.int8, ("cap", "sw")),
+    ("res_attr_key", np.int32, ("cap", "rw")),
+    ("res_attr_sval", np.int32, ("cap", "rw")),
+    ("res_attr_fval", np.float32, ("cap", "rw")),
+    ("res_attr_typ", np.int8, ("cap", "rw")),
+    ("valid", np.bool_, ("cap",)), ("sizes", np.float32, ("cap",)),
+    ("first", np.int32, ("cap",)), ("inverse", np.int32, ("cap",)),
+    ("order", np.int64, ("cap",)), ("trace_spans", np.int64, ("cap",)),
+    ("trace_sizes", np.int64, ("cap",)), ("keys", np.uint8, ("cap", 17)),
+    ("info", np.int64, (2,)),
+)
+_DERIVED_LAYOUTS: dict = {}
+
+
+def _derived_layout(cap: int, sw: int, rw: int):
+    """(the buffer's dtype, its field names, their byte offsets as an
+    int64 array) for one shape, made once."""
+    key = (cap, sw, rw)
+    got = _DERIVED_LAYOUTS.get(key)
+    if got is None:
+        dims = {"cap": cap, "sw": sw, "rw": rw}
+        dt = np.dtype([(name, t, tuple(dims.get(d, d) for d in shape))
+                       for name, t, shape in _DERIVED], align=True)
+        names = tuple(name for name, _, _ in _DERIVED)
+        got = (dt, names,
+               np.array([dt.fields[f][1] for f in names], np.int64))
+        if len(_DERIVED_LAYOUTS) < 1024:
+            _DERIVED_LAYOUTS[key] = got
+    return got
+
+
+def stage_derive(spans: np.ndarray, sattrs: np.ndarray, rattrs: np.ndarray,
+                 res: np.ndarray, cap: int, sw: int, rw: int, empty_id: int
+                 ) -> "dict | None":
+    """A staging's padded SpanBatch columns and its trace order, one pass.
+
+    Returns {field: array}, None without the library or where the records
+    need the numpy route. The SpanBatch's own column names ([cap] rows;
+    `sw`, `rw`: the attribute matrices' padded widths, 0 leaves that
+    scope out), `sizes` ([cap] float32 wire bytes), and over the n rows
+    `first`, `inverse`, `order`, `trace_spans`, `trace_sizes`, `keys`,
+    `same_length` (`model.otlp_batch.TraceOrder` says what each is)."""
+    if _load() is None:
+        return None
+    spans, sattrs, rattrs, res = (np.ascontiguousarray(a)
+                                  for a in (spans, sattrs, rattrs, res))
+    _check_staged(spans, sattrs, rattrs, res)
+    n = len(spans)
+    dt, names, offs = _derived_layout(cap, sw, rw)
+    buf = np.empty((), dt)
+    if _LIB_HOLD.stage_derive(spans.ctypes.data, n, cap, sattrs.ctypes.data,
+                              len(sattrs), sw, rattrs.ctypes.data,
+                              len(rattrs), rw, res.ctypes.data, len(res),
+                              empty_id, buf.ctypes.data,
+                              offs.ctypes.data) < 0:
+        return None
+    out = {f: buf[f] for f in names}
+    ng, same = (int(x) for x in out.pop("info"))
+    for f in ("first", "trace_spans", "trace_sizes", "keys"):
+        out[f] = out[f][:ng]
+    out["inverse"] = out["inverse"][:n]
+    out["order"] = out["order"][:n]
+    out["same_length"] = bool(same)
+    return out
 
 
 def otlp_events(data: bytes, ev_hint: int = 256, link_hint: int = 64

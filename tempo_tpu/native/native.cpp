@@ -1669,6 +1669,269 @@ int64_t group_keys_strided(const void* recs_p, int64_t n, int64_t rec_size,
 
 }  // extern "C"
 
+// --- a staged push's padded SpanBatch and its trace order --------------------
+//
+// What `model/otlp_batch.py::_batch_from_staged` makes of the staging's
+// records (the padded columns, both attribute matrices, the wire sizes),
+// and the push's rows grouped by exact trace id (the id zero-padded to 16
+// bytes, then its length capped at 16: the key `ColumnSource.chunk` groups
+// by), in one pass over the records. `stage_widths` first says how wide the
+// attribute matrices are, or that the records need Python: a non-scalar
+// AnyValue (stringified there), a service.name of another type than string,
+// or attributes out of their owners' order.
+
+namespace {
+
+static inline uint64_t mix64(uint64_t x) {      // splitmix64's finalizer
+    x ^= x >> 30; x *= 0xBF58476D1CE4E5B9ull;
+    x ^= x >> 27; x *= 0x94D049BB133111EBull;
+    return x ^ (x >> 31);
+}
+
+static inline float scalar_fval(const StageAttr& a) {
+    switch (a.typ) {
+        case 2: case 4: return (float)a.fval;
+        case 3: return (float)a.ival;
+        default: return 0.0f;
+    }
+}
+
+// One scope's attributes into [rows, w] matrices, every cell written (key
+// and string value -1 where empty); a row keeps its first min(w,
+// max_attrs). `starts[o]`: the index of owner o's first attribute. Adds
+// each row's kept keys to `kept`. False where an owner is out of range or
+// an attribute stands before its owner's first.
+static bool stage_attr_matrix(const StageAttr* a, int64_t na,
+                              const int64_t* starts, int64_t n_owners,
+                              int64_t rows, int64_t w, int64_t max_attrs,
+                              int32_t* key, int32_t* sval, float* fval,
+                              int8_t* typ, int32_t* kept) {
+    std::fill(key, key + rows * w, -1);
+    std::fill(sval, sval + rows * w, -1);
+    std::fill(fval, fval + rows * w, 0.0f);
+    std::fill(typ, typ + rows * w, (int8_t)0);
+    int64_t lim = w < max_attrs ? w : max_attrs;
+    for (int64_t i = 0; i < na; i++) {
+        int64_t o = a[i].owner;
+        if (o < 0 || o >= n_owners || i < starts[o]) return false;
+        int64_t pos = i - starts[o];
+        if (pos >= lim) continue;
+        int64_t at = o * w + pos;
+        key[at] = a[i].key_id;
+        sval[at] = a[i].sval_id;
+        fval[at] = scalar_fval(a[i]);
+        typ[at] = (int8_t)a[i].typ;
+        if (a[i].key_id != -1) kept[o]++;
+    }
+    return true;
+}
+
+// Each span's row of its resource's [nres, w] matrix.
+template <typename T>
+static void stage_res_rows(T* out, const T* by_res,
+                           const StageRec* spans, int64_t n, int64_t w) {
+    for (int64_t i = 0; i < n; i++)
+        memcpy(out + i * w, by_res + spans[i].res_idx * w, w * sizeof(T));
+}
+
+}  // namespace
+
+extern "C" {
+
+// (most attributes one span holds) << 32 | (most one resource holds); -1:
+// the numpy route must build this batch.
+int64_t stage_widths(const StageAttr* sattrs, int64_t na,
+                     const StageAttr* rattrs, int64_t nr,
+                     const StageRes* res, int64_t nres, int64_t n,
+                     int32_t svc_key, int32_t with_res) {
+    int64_t span_w = 0, res_w = 0, run = 0;
+    for (int64_t i = 0; i < na; i++) {
+        const StageAttr& a = sattrs[i];
+        if (a.typ == 0 || a.owner < 0 || a.owner >= n) return -1;
+        if (i && a.owner < sattrs[i - 1].owner) return -1;
+        run = (i && a.owner == sattrs[i - 1].owner) ? run + 1 : 1;
+        if (run > span_w) span_w = run;
+    }
+    for (int64_t i = 0; i < nr; i++) {
+        const StageAttr& a = rattrs[i];
+        if (a.key_id == svc_key && a.typ != 1) return -1;
+        if (!with_res) continue;
+        if (a.typ == 0 || a.owner < 0 || a.owner >= nres) return -1;
+        int64_t pos = i - res[a.owner].attr_start;
+        if (pos < 0) return -1;
+        if (pos + 1 > res_w) res_w = pos + 1;
+    }
+    return span_w << 32 | res_w;
+}
+
+// Writes into one buffer `base`, at the byte offsets `off`: name_id,
+// status_message_id, service_id, kind, status_code (int32 [cap]), start,
+// end (int64 [cap]), trace_id, span_id, parent_span_id ([cap, 16|8]
+// uint8), the span attribute key, sval, fval, typ ([cap, sw]: int32,
+// int32, float32, int8), the resource ones ([cap, rw]), valid (uint8
+// [cap]), sizes (float32 [cap]); then the trace order, over the n rows:
+// first (int32, each trace's first row, traces in first-seen order),
+// inverse (int32, each row's trace), order (int64, the rows trace by
+// trace, in push order within one), spans and sizes (int64, a trace's
+// spans and its approximate bytes: 200 a span + 32 an attribute key its
+// rows keep), keys (uint8 [17] a trace), and info (int64 [2]: the number
+// of traces; 1 where every id is 16 bytes). Returns 0, or -1 where the
+// numpy route must build the batch.
+int64_t stage_derive(const StageRec* spans, int64_t n, int64_t cap,
+                     const StageAttr* sattrs, int64_t na, int64_t sw,
+                     const StageAttr* rattrs, int64_t nr, int64_t rw,
+                     const StageRes* res, int64_t nres, int32_t empty_id,
+                     uint8_t* base, const int64_t* off) {
+    int32_t* name_id = (int32_t*)(base + off[0]);
+    int32_t* sm_id = (int32_t*)(base + off[1]);
+    int32_t* service_id = (int32_t*)(base + off[2]);
+    int32_t* kind = (int32_t*)(base + off[3]);
+    int32_t* status_code = (int32_t*)(base + off[4]);
+    int64_t* start = (int64_t*)(base + off[5]);
+    int64_t* end = (int64_t*)(base + off[6]);
+    uint8_t* tid = base + off[7];
+    uint8_t* sid = base + off[8];
+    uint8_t* pid = base + off[9];
+    uint8_t* valid = base + off[18];
+    float* sizes = (float*)(base + off[19]);
+    int32_t* first = (int32_t*)(base + off[20]);
+    int32_t* inverse = (int32_t*)(base + off[21]);
+    int64_t* order = (int64_t*)(base + off[22]);
+    int64_t* t_spans = (int64_t*)(base + off[23]);
+    int64_t* t_sizes = (int64_t*)(base + off[24]);
+    uint8_t* t_keys = base + off[25];
+    int64_t* info = (int64_t*)(base + off[26]);
+    if (n > cap) return -1;
+    for (int64_t i = 0; i < n; i++)
+        if (nres && (spans[i].res_idx < 0 || spans[i].res_idx >= nres))
+            return -1;
+
+    // attribute keys each row keeps (span scope, then its resource's)
+    std::vector<int32_t> kept(n, 0), res_kept(nres, 0);
+    if (sw) {
+        std::vector<int64_t> starts(n, 0);
+        for (int64_t i = na - 1; i >= 0; i--) {
+            int64_t o = sattrs[i].owner;
+            if (o < 0 || o >= n || (i + 1 < na && sattrs[i + 1].owner < o))
+                return -1;
+            starts[o] = i;
+        }
+        int32_t* key = (int32_t*)(base + off[10]);
+        int32_t* sval = (int32_t*)(base + off[11]);
+        float* fval = (float*)(base + off[12]);
+        int8_t* typ = (int8_t*)(base + off[13]);
+        if (!stage_attr_matrix(sattrs, na, starts.data(), n, cap, sw, 64,
+                               key, sval, fval, typ, kept.data()))
+            return -1;
+    }
+    if (rw) {
+        std::vector<int64_t> starts(nres);
+        for (int64_t r = 0; r < nres; r++) starts[r] = res[r].attr_start;
+        std::vector<int32_t> key(nres * rw), sval(nres * rw);
+        std::vector<float> fval(nres * rw);
+        std::vector<int8_t> typ(nres * rw);
+        if (!stage_attr_matrix(rattrs, nr, starts.data(), nres, nres, rw, 32,
+                               key.data(), sval.data(), fval.data(),
+                               typ.data(), res_kept.data()))
+            return -1;
+        int32_t* rkey = (int32_t*)(base + off[14]);
+        int32_t* rsval = (int32_t*)(base + off[15]);
+        float* rfval = (float*)(base + off[16]);
+        int8_t* rtyp = (int8_t*)(base + off[17]);
+        stage_res_rows(rkey, key.data(), spans, n, rw);
+        stage_res_rows(rsval, sval.data(), spans, n, rw);
+        stage_res_rows(rfval, fval.data(), spans, n, rw);
+        stage_res_rows(rtyp, typ.data(), spans, n, rw);
+        std::fill(rkey + n * rw, rkey + cap * rw, -1);
+        std::fill(rsval + n * rw, rsval + cap * rw, -1);
+        std::fill(rfval + n * rw, rfval + cap * rw, 0.0f);
+        std::fill(rtyp + n * rw, rtyp + cap * rw, (int8_t)0);
+    }
+
+    // the padded columns
+    for (int64_t i = 0; i < n; i++) {
+        const StageRec& s = spans[i];
+        name_id[i] = s.name_id;
+        int32_t sm = s.status_msg_id;
+        sm_id[i] = (sm < 0 || sm == empty_id) ? -1 : sm;
+        service_id[i] = nres ? res[s.res_idx].service_id : empty_id;
+        kind[i] = s.kind;
+        status_code[i] = s.status_code;
+        start[i] = (int64_t)s.start_ns;
+        end[i] = (int64_t)s.end_ns;
+        memcpy(tid + i * 16, s.trace_id, 16);
+        memcpy(sid + i * 8, s.span_id, 8);
+        memcpy(pid + i * 8, s.parent_span_id, 8);
+        sizes[i] = (float)s.span_len;
+    }
+    std::fill(name_id + n, name_id + cap, -1);
+    std::fill(sm_id + n, sm_id + cap, -1);
+    std::fill(service_id + n, service_id + cap, -1);
+    std::fill(kind + n, kind + cap, 0);
+    std::fill(status_code + n, status_code + cap, 0);
+    std::fill(start + n, start + cap, 0);
+    std::fill(end + n, end + cap, 0);
+    memset(tid + n * 16, 0, (cap - n) * 16);
+    memset(sid + n * 8, 0, (cap - n) * 8);
+    memset(pid + n * 8, 0, (cap - n) * 8);
+    memset(valid, 1, n);
+    memset(valid + n, 0, cap - n);
+    std::fill(sizes + n, sizes + cap, 0.0f);
+
+    // the trace order: first-seen traces, then a counting sort of the rows
+    struct Key { uint64_t a, b; uint64_t len; };
+    std::vector<Key> keys;
+    keys.reserve(64);
+    uint64_t tcap = 64;
+    while (tcap < (uint64_t)n * 2) tcap <<= 1;
+    std::vector<int32_t> table(tcap, -1);
+    uint64_t mask = tcap - 1;
+    int64_t same = 1;
+    for (int64_t r = 0; r < n; r++) {
+        const StageRec& s = spans[r];
+        if (s.tid_len != 16) same = 0;
+        Key k;
+        memcpy(&k.a, s.trace_id, 8);
+        memcpy(&k.b, s.trace_id + 8, 8);
+        k.len = (uint64_t)(s.tid_len < 16 ? s.tid_len : 16);
+        uint64_t i = mix64(k.a ^ mix64(k.b ^ (k.len << 56))) & mask;
+        int32_t g;
+        while (true) {
+            g = table[i];
+            if (g == -1) {
+                g = (int32_t)keys.size();
+                table[i] = g;
+                first[g] = (int32_t)r;
+                t_spans[g] = 0;
+                t_sizes[g] = 0;
+                keys.push_back(k);
+                break;
+            }
+            const Key& e = keys[g];
+            if (e.a == k.a && e.b == k.b && e.len == k.len) break;
+            i = (i + 1) & mask;
+        }
+        inverse[r] = g;
+        t_spans[g]++;
+        t_sizes[g] += 200 + 32 * (int64_t)(kept[r] + (rw ? res_kept[
+            s.res_idx] : 0));
+    }
+    int64_t n_groups = (int64_t)keys.size();
+    std::vector<int64_t> at(n_groups, 0);
+    for (int64_t g = 0; g < n_groups; g++) {
+        if (g) at[g] = at[g - 1] + t_spans[g - 1];
+        memcpy(t_keys + g * 17, &keys[g].a, 8);
+        memcpy(t_keys + g * 17 + 8, &keys[g].b, 8);
+        t_keys[g * 17 + 16] = (uint8_t)keys[g].len;
+    }
+    for (int64_t r = 0; r < n; r++) order[at[inverse[r]]++] = r;
+    info[0] = n_groups;
+    info[1] = same;
+    return 0;
+}
+
+}  // extern "C"
+
 // --- exact key index ---------------------------------------------------------
 //
 // Exact fixed-width key -> int64 value. Two users: the live trace stores

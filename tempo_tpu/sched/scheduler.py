@@ -30,7 +30,9 @@ This module is the shared seam:
 - **Power-of-two shape bucketing** with a warm-bucket cache: merged
   batches pad to the next power of two (floor `min_bucket_rows`), so the
   set of shapes reaching jit is small and steady state never re-traces
-  — the compile counters in `obs/jaxruntime` are the proof surface.
+  — the compile counters in `obs/jaxruntime` are the proof surface. A
+  burst outgrows the buckets its kernel has used only where its rows
+  fill the larger one; else it goes out in used ones.
 - **An adaptive batch window**: a merge group closes when its occupancy
   reaches `occupancy_target * max_batch_rows` OR when `batch_window_ms`
   elapses since its first job, whichever comes first — p99 ingest
@@ -400,6 +402,9 @@ class DeviceScheduler:
         self.comp_forced_total = 0
         self.occupancy_sum: dict[str, float] = {}
         self._warm_buckets: set[tuple] = set()
+        # kernel → the buckets its dispatches have used (guarded by
+        # _stats_lock): what a coalesced chunk may grow into freely
+        self._warm_rows: dict[str, set[int]] = {}
         # pressure → keep-fraction controller state (EWMA-smoothed; see
         # keep_fraction below). Guarded by _frac_lock: the distributor
         # reads per push from any receiver thread.
@@ -859,19 +864,43 @@ class DeviceScheduler:
 
     def _run_group(self, g: _MergeGroup) -> None:
         """Coalesce one merge group into padded pow-2 tensors and
-        dispatch, chunked at `max_batch_rows`."""
+        dispatch, chunked at `max_batch_rows`. Once its kernel has
+        dispatched, a chunk of several jobs grows into a bucket no
+        dispatch of that kernel has used only where the group's rows
+        still to go fill that bucket: a burst that outgrows the warm
+        buckets for a moment goes out in warm ones, and compiles nothing
+        on the serving path; a backlog that fills the larger bucket
+        compiles it once."""
         jobs = g.jobs
+        left = sum(j.n_rows for j in jobs)
         i = 0
         while i < len(jobs):
+            with self._stats_lock:
+                warm = set(self._warm_rows.get(g.kernel, ()))
             chunk = [jobs[i]]
             rows = jobs[i].n_rows
             i += 1
-            while i < len(jobs) and \
-                    rows + jobs[i].n_rows <= self.cfg.max_batch_rows:
-                rows += jobs[i].n_rows
+            while i < len(jobs):
+                more = rows + jobs[i].n_rows
+                if more > self.cfg.max_batch_rows:
+                    break
+                bucket = self._bucket(g, more)
+                if warm and bucket not in warm and left < bucket:
+                    break
+                rows = more
                 chunk.append(jobs[i])
                 i += 1
+            left -= rows
             self._dispatch_chunk(g, chunk, rows)
+
+    def _bucket(self, g: _MergeGroup, rows: int) -> int:
+        """The padded row count of a chunk of `rows` rows in group `g`."""
+        bucket = bucket_rows(max(rows, 1), self.cfg.min_bucket_rows)
+        if g.align > 1 and bucket % g.align:
+            # serving mesh: the padded window must split evenly over
+            # the 'data' shards for the single shard_map dispatch
+            bucket = -(-bucket // g.align) * g.align
+        return bucket
 
     @staticmethod
     def _pad_chunk(g: _MergeGroup, chunk: list[Job], rows: int,
@@ -922,11 +951,7 @@ class DeviceScheduler:
             # must land on the jobs, never escape to kill the worker
             if faults.ARMED:
                 faults.fire("sched.dispatch")
-            bucket = bucket_rows(max(rows, 1), self.cfg.min_bucket_rows)
-            if g.align > 1 and bucket % g.align:
-                # serving mesh: the padded window must split evenly over
-                # the 'data' shards for the single shard_map dispatch
-                bucket = -(-bucket // g.align) * g.align
+            bucket = self._bucket(g, rows)
             # slow dispatches are findable by trace: same span surface
             # as distributor.push / frontend.Search. The span LINKS the
             # coalesced batch back to each contributing request's tree
@@ -1011,6 +1036,7 @@ class DeviceScheduler:
         sig = (g.kernel, bucket) + dtypes
         occ = rows / bucket
         with self._stats_lock:
+            self._warm_rows.setdefault(g.kernel, set()).add(bucket)
             if sig not in self._warm_buckets:
                 self._warm_buckets.add(sig)
                 self.bucket_warmups[g.kernel] = \

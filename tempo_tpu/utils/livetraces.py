@@ -50,6 +50,14 @@ CUT_SPANS = RUNTIME.counter(
     "local-blocks processor's), by the route that built them: columns = "
     "taken from the chunks of staged pushes; dicts = through span dicts",
     labels=("route",))
+CHUNK_SPANS = RUNTIME.counter(
+    "tempo_ingester_chunk_spans_total",
+    "Spans a live store (the ingester's and the local-blocks processor's) "
+    "took in as one chunk of a staged push, by where the chunk's trace "
+    "grouping came from: staged = the staging's native pass, shared by "
+    "every store and the distributor; own = the store grouped the rows "
+    "itself",
+    labels=("grouping",))
 
 # a slot's state
 DEAD, COLUMNS = 0, 1

@@ -153,6 +153,39 @@ def test_max_batch_rows_chunks_oversized_groups():
     assert len(seen) == 4 and all(s == 128 for s in seen)
 
 
+@pytest.mark.parametrize("jobs, want", [
+    (5, [4096, 1024]),          # a burst past the warm 4096: cut at it
+    (9, [8192, 1024]),          # a backlog that fills 8192 grows into it
+    (4, [4096]),                # the warm bucket holds them all
+])
+def test_a_burst_grows_into_a_new_bucket_only_where_it_fills_it(jobs, want):
+    sc = _manual(SchedConfig(max_batch_rows=16384, min_bucket_rows=64))
+    seen = []
+
+    def submit(n):
+        sc.submit_rows("k", "m", (np.zeros(n, np.int32),), n,
+                       lambda slots: seen.append(len(slots)), pads=(-1,))
+
+    submit(4000)                               # warms the 4096 bucket
+    sc.drain_once(force=True)
+    seen.clear()
+    for _ in range(jobs):
+        submit(1000)
+    sc.drain_once(force=True)
+    assert seen == want
+    assert sc.coalesced_total["k"] == 1 + jobs
+
+
+def test_a_kernel_that_never_dispatched_coalesces_freely():
+    sc = _manual(SchedConfig(max_batch_rows=16384, min_bucket_rows=64))
+    seen = []
+    for _ in range(5):
+        sc.submit_rows("k", "m", (np.zeros(1000, np.int32),), 1000,
+                       lambda slots: seen.append(len(slots)), pads=(-1,))
+    sc.drain_once(force=True)
+    assert seen == [8192]
+
+
 # ---------------------------------------------------------------------------
 # batch-close policy: occupancy target or deadline, whichever first
 # ---------------------------------------------------------------------------
