@@ -23,10 +23,11 @@ paired in ONE native call: the pending halves are columns by store slot
 open-addressing map; a dict under `use_native=False`), and the TTL ring is
 arrays of (expire_at, key) that expiry takes a due prefix of at once. The
 metric updates for a push's edges (completed and expired together) are ONE
-device step over the shared registry's families: on the dense layout one
-jitted, donating call fed by one packed f32 matrix (`_edge_update_impl`),
-on the paged layout the families' own arena scatters. Latencies feed the
-classic histograms only; there is no sketch per edge series.
+jitted, donating device step over the shared registry's families, fed by
+one packed f32 matrix: on the dense layout over the families' states
+(`_edge_update_impl`), on the paged layout over their arenas through the
+page tables (`ops.pages.edge_step`). Latencies feed the classic
+histograms only; there is no sketch per edge series.
 """
 
 from __future__ import annotations
@@ -48,6 +49,7 @@ from tempo_tpu.model.span_batch import (
 )
 from tempo_tpu.native import HalfIndex, first_svals
 from tempo_tpu.obs.jaxruntime import RUNTIME, instrumented_jit
+from tempo_tpu.ops import pages as pages_op
 from tempo_tpu.registry import metrics as rm
 from tempo_tpu.registry.registry import DEFAULT_HISTOGRAM_EDGES, ManagedRegistry
 from tempo_tpu.utils import tracing, turn
@@ -66,8 +68,8 @@ _EMIT_MIN_ROWS = 16
 EMITS = RUNTIME.counter(
     "tempo_metrics_generator_servicegraphs_emits_total",
     "Service-graph edge emits by device path: fused = one jitted, "
-    "donating step over the dense families; family = the families' own "
-    "calls (paged layout)",
+    "donating step a push, dense or paged; family = the families' own "
+    "calls (a series table of 2^24 slots or more)",
     labels=("path",))
 HALVES = RUNTIME.counter(
     "tempo_metrics_generator_servicegraphs_halves_total",
@@ -276,11 +278,17 @@ class ServiceGraphsProcessor:
         self._families = [self.total, self.failed, self.client_hist,
                           self.server_hist] + (
             [self.messaging_hist] if self.messaging_hist is not None else [])
-        # the layout decides the device path: dense families take the
-        # jitted step (slots ride its f32 matrix exactly below 2^24),
-        # paged families keep their arena scatters under the pool's lock
-        self._fused = registry.pages is None \
-            and self.total.table.capacity < (1 << 24)
+        # one jitted step a push while slots ride its f32 matrix exactly
+        # (below 2^24), over the dense families' states or, bound here,
+        # the paged families' arenas; above it the families' own calls
+        self._fused = self.total.table.capacity < (1 << 24)
+        self._paged_step = None
+        if self._fused and registry.pages is not None:
+            self._paged_step = pages_op.edge_step(
+                self.client_hist.edges, registry.pages.page_shift,
+                self.messaging_hist is not None)
+            self._planes = [p for f in self._families
+                            for p in f.planes_in_order()]
         self._store = _HalfStore(use_native)
         self._route = "native" if self._store.index.native else "dict"
         self._nodes = np.zeros(0, np.int32)   # peer value -> node, `_node_of`
@@ -397,7 +405,8 @@ class ServiceGraphsProcessor:
         messaging = self.messaging_hist is not None
         rows = np.stack([edges.client, edges.server, conn_ids[edges.conn]],
                         axis=1).astype(np.int32)
-        # rows: slots, fail, cdur, sdur (+ mslots, mdur), `_edge_update_impl`
+        # rows: slots, fail, cdur, sdur (+ mslots, mdur), as both steps
+        # (`_edge_update_impl`, `pages_op.edge_step`) read them
         packed = np.zeros((6 if messaging else 4, cap), np.float32)
         packed[1, :n] = edges.failed
         packed[2, :n] = edges.client_s
@@ -421,15 +430,20 @@ class ServiceGraphsProcessor:
                 mslots = np.full(cap, -1, np.int32)
                 mslots[:n] = np.where(msg, slots[:n], -1)
                 packed[4] = mslots
-            if self._fused:
+            if self._paged_step is not None:
+                arenas = self._paged_step(
+                    *(p.data for p in self._planes),
+                    *(p.device_map() for p in self._planes), packed)
+                for plane, arena in zip(self._planes, arenas):
+                    plane.rebind(arena)
+            elif self._fused:
                 states = _edge_update(
                     tuple(f.state for f in self._families), packed)
                 for fam, state in zip(self._families, states):
                     fam.state = state
             else:
-                # family-level slot updates: the families own the device
-                # half, which the paged layout (registry/pages.py) swaps
-                # for arena scatters
+                # the families' own calls, a launch each: slots of a
+                # table of 2^24 or more do not ride the f32 matrix exactly
                 self.total.add_slots(slots)
                 self.failed.add_slots(slots, packed[1])
                 self.client_hist.observe_slots(slots, packed[2])

@@ -629,7 +629,8 @@ class SpanMetricsProcessor:
             align=sm.data_shards if sm is not None else 1,
             shards=sm.data_shards if sm is not None else 0,
             place=self._mesh_place_packed
-            if packed and sm is not None else None)
+            if packed and sm is not None else None,
+            warm=True)
 
     def needs_attr_columns(self) -> tuple[bool, bool]:
         """(span_attrs, res_attrs) this processor reads — owned HERE so a

@@ -133,6 +133,20 @@ def _add1(arena, table, slots, vals, page_shift):
     return arena.at[r].add(vals, mode="drop")
 
 
+def _observe(ab, a_sums, a_counts, t_bucket, t_sums, t_counts, slots,
+             values, weights, edges: tuple, page_shift: int):
+    """A classic histogram's paged update: bucket increments in the wide
+    arena, then sums and counts (the family step's body and the edge
+    step's, so the two cannot drift)."""
+    v = jnp.asarray(values, jnp.float32)
+    w = jnp.asarray(weights, jnp.float32)
+    e = jnp.asarray(edges, jnp.float32)
+    b = jnp.sum(v[:, None] > e[None, :], axis=1).astype(jnp.int32)
+    return (_hist_scatter(ab, t_bucket, slots, b, w, page_shift),
+            _add1(a_sums, t_sums, slots, v * w, page_shift),
+            _add1(a_counts, t_counts, slots, w, page_shift))
+
+
 def histogram_observe_step(edges: tuple, page_shift: int):
     """fn(a_sums, a_counts, ab[Rb,B+1], t_bucket, t_sums, t_counts,
     slots, values, weights) -> (a_sums, a_counts, ab) — classic
@@ -143,13 +157,9 @@ def histogram_observe_step(edges: tuple, page_shift: int):
     def build():
         def step(a_sums, a_counts, ab, t_bucket, t_sums, t_counts, slots,
                  values, weights):
-            v = jnp.asarray(values, jnp.float32)
-            w = jnp.asarray(weights, jnp.float32)
-            e = jnp.asarray(edges, jnp.float32)
-            b = jnp.sum(v[:, None] > e[None, :], axis=1).astype(jnp.int32)
-            ab = _hist_scatter(ab, t_bucket, slots, b, w, page_shift)
-            a_sums = _add1(a_sums, t_sums, slots, v * w, page_shift)
-            a_counts = _add1(a_counts, t_counts, slots, w, page_shift)
+            ab, a_sums, a_counts = _observe(
+                ab, a_sums, a_counts, t_bucket, t_sums, t_counts, slots,
+                values, weights, edges, page_shift)
             return a_sums, a_counts, ab
         return instrumented_jit(step, name="paged_histogram_update",
                                 donate_argnums=(0, 1, 2))
@@ -485,3 +495,50 @@ def fused_step(edges: tuple, gamma: float, min_value: float, dd_rows: int,
                                 donate_argnums=tuple(range(n_arenas)))
 
     return _cached(key, build)
+
+
+# ---------------------------------------------------------------------------
+# the service-graph edge step (request counters + client/server histograms)
+# ---------------------------------------------------------------------------
+
+def edge_step(edges: tuple, page_shift: int, messaging: bool):
+    """The paged service-graph emit, memoized per static meta: ONE
+    donating step for the edge families that `counter_add_step` and
+    `histogram_observe_step` update a launch each.
+
+    Signature: fn(*arenas, *tables, packed) -> arenas, arenas and tables
+    role-aligned as (total/values, failed/values, client buckets, sums,
+    counts, server buckets, sums, counts[, messaging buckets, sums,
+    counts]). `packed` is the emit's ONE f32 matrix of rows `slots, fail,
+    cdur, sdur` (+ `mslots, mdur` with `messaging`), slot ids exact in f32
+    under the caller's capacity < 2^24 gate; pad rows ride slot -1 and
+    drop, as do rows of unbacked pages. The families' helpers run in the
+    families' order with their weights (1 a row), so the two routes give
+    the same cells. Arenas are DONATED: callers hold the pool lock across
+    dispatch + rebind."""
+    edges = tuple(edges)
+    n = 11 if messaging else 8
+
+    def build():
+        # the name is the step's handle in a profile (module
+        # `jit__edge_update_paged_impl`): outside every
+        # `jit__fused_update*` prefix, pinned by tests/test_spans.py
+        def _edge_update_paged_impl(*args):
+            a, t, packed = args[:n], args[n:2 * n], args[2 * n]
+            slots = packed[0].astype(jnp.int32)
+            ones = jnp.ones_like(packed[0])
+            out = (_add1(a[0], t[0], slots, ones, page_shift),
+                   _add1(a[1], t[1], slots, packed[1], page_shift))
+            out += _observe(*a[2:5], *t[2:5], slots, packed[2], ones,
+                            edges, page_shift)
+            out += _observe(*a[5:8], *t[5:8], slots, packed[3], ones,
+                            edges, page_shift)
+            if messaging:
+                out += _observe(*a[8:], *t[8:], packed[4].astype(jnp.int32),
+                                packed[5], ones, edges, page_shift)
+            return out
+
+        return instrumented_jit(_edge_update_paged_impl,
+                                name="servicegraphs_edge_update_paged",
+                                donate_argnums=tuple(range(n)))
+    return _cached(("edge", edges, page_shift, bool(messaging)), build)

@@ -102,6 +102,11 @@ class PagedCounter(_PagedBase, Counter):
                 np.ascontiguousarray(slots, np.int32),
                 self._w(slots, weights)))
 
+    def planes_in_order(self) -> tuple:
+        """The planes in the order a fused step over several families
+        takes them (`ops.pages.edge_step`)."""
+        return (self.values,)
+
     def _snap(self) -> tuple:
         return (self._gather_full(self.values),)
 
@@ -148,6 +153,11 @@ class PagedHistogram(_PagedBase, Histogram):
             self.sums.rebind(a_sums)
             self.counts.rebind(a_counts)
             self.buckets.rebind(ab)
+
+    def planes_in_order(self) -> tuple:
+        """The planes in the order a fused step over several families
+        takes them (`ops.pages.edge_step`): buckets, sums, counts."""
+        return (self.buckets, self.sums, self.counts)
 
     def _snap(self) -> tuple:
         return (self._gather_full(self.buckets),

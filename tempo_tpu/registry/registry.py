@@ -514,15 +514,22 @@ class ManagedRegistry:
         total = 0
         for fams in by_table.values():
             table = fams[0].table
-            stale = np.flatnonzero(table.active & (table.last_seen < cutoff))
-            if not stale.size:
-                continue
-            # pad to a small set of static shapes to bound recompiles
-            padded = np.full(_pad_len(stale.size), table.capacity, np.int32)
-            padded[: stale.size] = stale
             # one lock over the WHOLE shared-table eviction: a concurrent
-            # collect must never see the slot-aligned trio half-zeroed
+            # collect must never see the slot-aligned trio half-zeroed.
+            # The stale set is read under it too: a resolve under the
+            # lock (the service-graph emit's) marks a fresh slot active
+            # before it stamps its last-seen, or touches a stale series
+            # again, and a set read before either would zero the counts
+            # of a series this purge does not evict
             with self.state_lock:
+                stale = np.flatnonzero(table.active
+                                       & (table.last_seen < cutoff))
+                if not stale.size:
+                    continue
+                # pad to a small set of static shapes to bound recompiles
+                padded = np.full(_pad_len(stale.size), table.capacity,
+                                 np.int32)
+                padded[: stale.size] = stale
                 for mt in fams:
                     mt.note_stale(stale)
                     mt.zero_evicted(padded)

@@ -32,7 +32,10 @@ This module is the shared seam:
   set of shapes reaching jit is small and steady state never re-traces
   — the compile counters in `obs/jaxruntime` are the proof surface. A
   burst outgrows the buckets its kernel has used only where its rows
-  fill the larger one; else it goes out in used ones.
+  fill the larger one; else it goes out in used ones, the smallest that
+  holds it. A kernel that compiles a program a shape (`warm=True`) has
+  its top bucket compiled at its first dispatch, so its backlogs always
+  find a used bucket and compile nothing on the serving path.
 - **An adaptive batch window**: a merge group closes when its occupancy
   reaches `occupancy_target * max_batch_rows` OR when `batch_window_ms`
   elapses since its first job, whichever comes first — p99 ingest
@@ -336,12 +339,12 @@ class _MergeGroup:
     one dispatch closure, one eventual padded tensor."""
 
     __slots__ = ("kernel", "pads", "dispatch", "jobs", "rows", "first_t",
-                 "pack", "align", "shards", "place")
+                 "pack", "align", "shards", "place", "warm")
 
     def __init__(self, kernel: str, pads: tuple, dispatch: Callable,
                  first_t: float, pack: bool = False, align: int = 1,
-                 shards: int = 0,
-                 place: "Callable | None" = None) -> None:
+                 shards: int = 0, place: "Callable | None" = None,
+                 warm: bool = False) -> None:
         self.kernel = kernel
         self.pads = pads
         self.dispatch = dispatch
@@ -352,6 +355,7 @@ class _MergeGroup:
         self.align = align
         self.shards = shards
         self.place = place
+        self.warm = warm
 
 
 class DeviceScheduler:
@@ -555,7 +559,8 @@ class DeviceScheduler:
                     pads: "Sequence | None" = None,
                     tenant: str = "", pack: bool = False,
                     align: int = 1, shards: int = 0,
-                    place: "Callable | None" = None) -> Job:
+                    place: "Callable | None" = None,
+                    warm: bool = False) -> Job:
         """Enqueue a coalescible row batch (live-ingest class).
 
         `arrays` are row-aligned host vectors (one per kernel argument
@@ -584,7 +589,11 @@ class DeviceScheduler:
         upload of the packed matrix: `place(matrix)` returns the placed
         operand and the bytes it cost the host link. The scheduler calls
         it inside `sched.h2d`, where it makes the one-device group's
-        `device_put`, and hands the operand to `dispatch`.
+        `device_put`, and hands the operand to `dispatch`. `warm=True`
+        says that `dispatch` compiles a program for each bucket shape (a
+        jitted step): the kernel's first dispatch then also dispatches
+        its top bucket with pad rows alone, which change no state, so
+        its backlogs coalesce into that bucket without a compile.
 
         Never blocks and never drops data: on a saturated queue the job
         executes inline on the caller (shed, counted) — ADMISSION control
@@ -597,7 +606,7 @@ class DeviceScheduler:
                   arrays=tuple(arrays), pads=pads, n_rows=int(n_rows),
                   dispatch=dispatch, tenant=tenant)
         if not self.cfg.enabled:
-            self._run_group(_group_of(job, pack, align, shards, place))
+            self._run_group(_group_of(job, pack, align, shards, place, warm))
             return job
         if self.cfg.tuning == "auto":
             # arrival-rate accounting for the window tuner (outside
@@ -614,7 +623,7 @@ class DeviceScheduler:
                 if g is None:
                     g = self._groups[merge_key] = _MergeGroup(
                         kernel, pads, dispatch, job.enqueue_t, pack=pack,
-                        align=align, shards=shards, place=place)
+                        align=align, shards=shards, place=place, warm=warm)
                 g.jobs.append(job)
                 g.rows += job.n_rows
                 self.jobs_total["ingest"] += 1
@@ -629,7 +638,7 @@ class DeviceScheduler:
                     self._cond.notify_all()
                 return job
         # shed path: dispatch inline, outside the lock
-        self._run_group(_group_of(job, pack, align, shards, place))
+        self._run_group(_group_of(job, pack, align, shards, place, warm))
         return job
 
     def run(self, fn: Callable, kernel: str = "fn",
@@ -865,12 +874,15 @@ class DeviceScheduler:
     def _run_group(self, g: _MergeGroup) -> None:
         """Coalesce one merge group into padded pow-2 tensors and
         dispatch, chunked at `max_batch_rows`. Once its kernel has
-        dispatched, a chunk of several jobs grows into a bucket no
-        dispatch of that kernel has used only where the group's rows
-        still to go fill that bucket: a burst that outgrows the warm
-        buckets for a moment goes out in warm ones, and compiles nothing
-        on the serving path; a backlog that fills the larger bucket
-        compiles it once."""
+        dispatched, a chunk of several jobs goes out in a bucket no
+        dispatch of that kernel has used only where no used bucket holds
+        it and the group's rows still to go fill that bucket: a burst
+        that outgrows the warm buckets for a moment goes out in warm
+        ones, a backlog in the smallest warm one that holds it, and only
+        a backlog past every warm bucket compiles a new one. A `warm`
+        kernel's first dispatch compiles its top bucket (`_warm_top`), so
+        after it no chunk of several jobs compiles (a compile on the
+        serving path holds every push ~19 s on a v5e)."""
         jobs = g.jobs
         left = sum(j.n_rows for j in jobs)
         i = 0
@@ -885,13 +897,47 @@ class DeviceScheduler:
                 if more > self.cfg.max_batch_rows:
                     break
                 bucket = self._bucket(g, more)
-                if warm and bucket not in warm and left < bucket:
+                if warm and left < bucket \
+                        and not any(b >= bucket for b in warm):
                     break
                 rows = more
                 chunk.append(jobs[i])
                 i += 1
             left -= rows
-            self._dispatch_chunk(g, chunk, rows)
+            bucket = self._bucket(g, rows)
+            if len(chunk) > 1 and warm and bucket not in warm:
+                bucket = min((b for b in warm if b > bucket), default=bucket)
+            self._dispatch_chunk(g, chunk, rows, bucket)
+            if g.warm and not warm:
+                self._warm_top(g, chunk[0])
+
+    def _warm_top(self, g: _MergeGroup, like: Job) -> None:
+        """Compile a `warm` kernel's top bucket at its first dispatch: ONE
+        dispatch of pad rows alone (`like`'s dtypes), which every kernel
+        drops (the `pads` contract), so no state changes. A backlog of
+        any size then coalesces into a warm bucket. Counted as a bucket
+        warm-up, not as a batch; a failure is a dispatch error, and the
+        kernel's backlogs then compile as any other kernel's."""
+        top = self._bucket(g, self.cfg.max_batch_rows)
+        with self._stats_lock:
+            if top in self._warm_rows.get(g.kernel, ()):
+                return
+        empty = Job(priority=PRIO_INGEST, kernel=g.kernel,
+                    arrays=tuple(np.asarray(a)[:0] for a in like.arrays))
+        try:
+            padded, _ = self._pad_chunk(g, [empty], 0, top)
+            dtypes = tuple(a.dtype.str for a in padded)
+            g.dispatch(*self._upload(g, padded)[0])
+        except BaseException as e:           # noqa: BLE001 — counted
+            self._note_dispatch_error(g.kernel, e)
+            return
+        with self._stats_lock:
+            self._warm_rows.setdefault(g.kernel, set()).add(top)
+            sig = (g.kernel, top) + dtypes
+            if sig not in self._warm_buckets:
+                self._warm_buckets.add(sig)
+                self.bucket_warmups[g.kernel] = \
+                    self.bucket_warmups.get(g.kernel, 0) + 1
 
     def _bucket(self, g: _MergeGroup, rows: int) -> int:
         """The padded row count of a chunk of `rows` rows in group `g`."""
@@ -930,8 +976,25 @@ class DeviceScheduler:
             padded.append(cat)
         return padded, waste
 
+    @staticmethod
+    def _upload(g: _MergeGroup, padded: list) -> tuple[list, int]:
+        """A padded batch's operands as `dispatch` takes them, and the
+        bytes that cost the host link. A packed mesh group's placement
+        is the caller's (`place`: a copy a 'series' shard); a packed
+        one-device matrix is ONE `device_put`, made here so that it is
+        timed apart from the enqueue. Unpacked operands stay host arrays
+        (some closures read them on the host, an unpacked mesh group's
+        closure places its own): their H2D is inside `sched.enqueue`."""
+        h2d_bytes = sum(int(a.nbytes) for a in padded)
+        if g.place is not None:
+            placed, h2d_bytes = g.place(padded[0])
+            return [placed], h2d_bytes
+        if g.pack and not g.shards:
+            return [jax.device_put(padded[0])], h2d_bytes
+        return padded, h2d_bytes
+
     def _dispatch_chunk(self, g: _MergeGroup, chunk: list[Job],
-                        rows: int) -> None:
+                        rows: int, bucket: int) -> None:
         # queue wait stamps at execution start (enqueue → now), summed
         # into the ledger so wait vs device-wall shares are separable
         t_start = self.now()
@@ -943,7 +1006,7 @@ class DeviceScheduler:
                 queue_wait_ns += int(j.wait_s * 1e9)
             tenant_rows[j.tenant] = tenant_rows.get(j.tenant, 0) + j.n_rows
         t0 = time.perf_counter()
-        bucket = h2d_bytes = 0
+        h2d_bytes = 0
         err: "BaseException | None" = None
         try:
             # the WHOLE build+dispatch sits under the guard: a failure
@@ -951,7 +1014,6 @@ class DeviceScheduler:
             # must land on the jobs, never escape to kill the worker
             if faults.ARMED:
                 faults.fire("sched.dispatch")
-            bucket = self._bucket(g, rows)
             # slow dispatches are findable by trace: same span surface
             # as distributor.push / frontend.Search. The span LINKS the
             # coalesced batch back to each contributing request's tree
@@ -976,22 +1038,7 @@ class DeviceScheduler:
                 with tracing.span("sched.h2d") as hs:
                     padded, waste = self._pad_chunk(g, chunk, rows, bucket)
                     dtypes = tuple(a.dtype.str for a in padded)
-                    h2d_bytes = sum(int(a.nbytes) for a in padded)
-                    if g.place is not None:
-                        # a packed mesh group: the sharded placement is
-                        # the upload, made here like the one-device
-                        # group's below, and h2d_bytes is what crosses
-                        # the link (a copy a 'series' shard)
-                        placed, h2d_bytes = g.place(padded[0])
-                        padded = [placed]
-                    elif g.pack and not g.shards:
-                        # the packed matrix exists to be ONE H2D: made
-                        # here, on one device, it is timed apart from
-                        # the enqueue. Unpacked operands stay host
-                        # arrays (some closures read them on the host,
-                        # an unpacked mesh group's closure places its
-                        # own): their H2D is inside sched.enqueue.
-                        padded = [jax.device_put(padded[0])]
+                    padded, h2d_bytes = self._upload(g, padded)
                     if hs is not None:
                         hs.attrs["h2d_bytes"] = h2d_bytes
                 self._note_batch(g, len(chunk), rows, bucket, dtypes, waste)
@@ -1138,10 +1185,11 @@ class DeviceScheduler:
 
 
 def _group_of(job: Job, pack: bool = False, align: int = 1,
-              shards: int = 0,
-              place: "Callable | None" = None) -> _MergeGroup:
+              shards: int = 0, place: "Callable | None" = None,
+              warm: bool = False) -> _MergeGroup:
     g = _MergeGroup(job.kernel, job.pads, job.dispatch, job.enqueue_t,
-                    pack=pack, align=align, shards=shards, place=place)
+                    pack=pack, align=align, shards=shards, place=place,
+                    warm=warm)
     g.jobs.append(job)
     g.rows = job.n_rows
     return g

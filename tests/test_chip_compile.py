@@ -233,6 +233,35 @@ def test_traceql_grid_update_compiles(one_chip):
         rows, _shape((65536,), jnp.float32, one_chip)).compile())
 
 
+@pytest.mark.parametrize("rows", [16, 512])
+def test_paged_edge_step_compiles(one_chip, rows):
+    """The service-graph emit's one paged step a push
+    (`ops.pages.edge_step`) at `multitenant-zipf-256`'s 262,144 arena
+    slots and pages of 256 rows, at both padded shapes: every arena is
+    donated, so the update is in place on the chip, and it carries no
+    arena-sized temporary, where the span-metrics step beside it carries
+    one as large as its DDSketch arena (0 B at 16 rows and 2,254,336 B
+    at 512 against the 15.7 MB bucket arena, as compiled here)."""
+    import jax.numpy as jnp
+
+    from tempo_tpu.ops import pages as op
+    from tempo_tpu.registry.registry import DEFAULT_HISTOGRAM_EDGES as edges
+
+    f32, i32 = jnp.float32, jnp.int32
+    arena_rows = (2 * ARENA_SLOTS // PAGE_ROWS + 1) * PAGE_ROWS
+    row = _shape((arena_rows,), f32, one_chip)
+    hist = [_shape((arena_rows, len(edges) + 1), f32, one_chip), row, row]
+    step = op.edge_step(edges, PAGE_ROWS.bit_length() - 1, False)
+    compiled = step._jit.lower(
+        row, row, *hist, *hist,
+        *[_shape((CAP // PAGE_ROWS,), i32, one_chip)] * 8,
+        _shape((4, rows), f32, one_chip)).compile()
+    _fits_one_chip(compiled)
+    ma = compiled.memory_analysis()
+    assert ma.output_size_in_bytes - ma.alias_size_in_bytes < 4096
+    assert ma.temp_size_in_bytes < arena_rows * (len(edges) + 1) * 4 // 4
+
+
 @pytest.mark.parametrize("arena_slots", [ARENA_SLOTS, 2 * ARENA_SLOTS])
 def test_paged_xla_step_compiles(one_chip, arena_slots):
     """(e) the paged layout's composed-scatter step (`pages.enabled`), at
@@ -257,3 +286,29 @@ def test_paged_xla_step_compiles(one_chip, arena_slots):
     _fits_one_chip(step._jit.lower(
         *arenas, *tables, _shape((4, BUCKET), f32, one_chip)).compile())
 
+
+
+def test_the_top_bucket_a_first_dispatch_warms_compiles(one_chip):
+    """(f) the paged span-metrics step at the scheduler's top bucket
+    (`SchedConfig.max_batch_rows` rows), which the kernel's first dispatch
+    also compiles so that its backlogs never compile: it fits one v5e at
+    `multitenant-zipf-256`'s arena of 262,144 slots."""
+    import jax.numpy as jnp
+
+    from tempo_tpu.ops import pages as op
+    from tempo_tpu.sched import SchedConfig
+
+    cfg, gamma, nb = _meta()
+    edges = tuple(cfg.histogram_buckets)
+    f32, i32 = jnp.float32, jnp.int32
+    row = _shape((2 * ARENA_SLOTS,), f32, one_chip)
+    arenas = [row, row, row, row,
+              _shape((2 * ARENA_SLOTS, len(edges) + 1), f32, one_chip),
+              row, _shape((2 * ARENA_SLOTS, nb), f32, one_chip)]
+    tables = [_shape((CAP // PAGE_ROWS,), i32, one_chip)] * 5 \
+        + [_shape((DD_ROWS // PAGE_ROWS,), i32, one_chip)] * 2
+    step = op.fused_step(edges, gamma, cfg.sketch_min_s, DD_ROWS,
+                         PAGE_ROWS.bit_length() - 1, True)
+    _fits_one_chip(step._jit.lower(
+        *arenas, *tables,
+        _shape((4, SchedConfig().max_batch_rows), f32, one_chip)).compile())

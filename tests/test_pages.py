@@ -297,9 +297,9 @@ def test_servicegraphs_paged_bit_identical():
 
 
 def test_servicegraphs_paged_emits_through_the_families():
-    """The layout decides the device path: the same pushes count under
-    `family` with the page pool on (arena scatters under the pool's
-    lock) and under `fused` on the dense layout, never compile the
+    """One step a push on either layout: the same pushes count under
+    `fused` with the page pool on (the paged edge step over the arenas,
+    under the pool's lock) and on the dense layout, never compile the
     dense step for a paged tenant, and give the same answers."""
     from tempo_tpu.generator.processors.servicegraphs import (
         EMITS, ServiceGraphsConfig, ServiceGraphsProcessor)
@@ -322,7 +322,7 @@ def test_servicegraphs_paged_emits_through_the_families():
 
     grew_p, compiles_p, out_p = run(True)
     grew_d, compiles_d, out_d = run(False)
-    assert grew_p == {"fused": 0, "family": 4} and compiles_p == 0
+    assert grew_p == {"fused": 4, "family": 0} and compiles_p == 0
     assert grew_d == {"fused": 4, "family": 0} and compiles_d <= 1
     assert out_p == out_d and out_p
 

@@ -186,6 +186,81 @@ def test_a_kernel_that_never_dispatched_coalesces_freely():
     assert seen == [8192]
 
 
+@pytest.mark.parametrize("warm, want", [
+    (False, [512, 512]),        # grows where the rows still to go fill
+    (True, [16384]),            # the top bucket, warm since the first job
+])
+def test_a_backlog_behind_one_small_first_job_still_coalesces(warm, want):
+    """A cold kernel whose first dispatch is ONE small job: a backlog
+    after it still goes out in fewer launches than it has jobs, past the
+    one bucket that job used."""
+    sc = _manual(SchedConfig(max_batch_rows=16384, min_bucket_rows=64))
+    seen = []
+
+    def submit(n):
+        sc.submit_rows("k", "m", (np.zeros(n, np.int32),), n,
+                       lambda slots: seen.append(len(slots)), pads=(-1,),
+                       warm=warm)
+
+    submit(100)
+    sc.drain_once(force=True)
+    assert seen == ([128, 16384] if warm else [128])
+    seen.clear()
+    for _ in range(10):
+        submit(100)
+    sc.drain_once(force=True)
+    assert seen == want
+
+
+def test_a_warm_kernels_first_dispatch_adds_its_top_bucket_in_pad_rows():
+    """`warm=True`: the kernel's first dispatch is followed by ONE
+    dispatch of its top bucket holding pad rows alone (which every
+    kernel drops), counted as a bucket warm-up and not as a batch; no
+    later dispatch repeats it."""
+    sc = _manual(SchedConfig(max_batch_rows=4096, min_bucket_rows=64))
+    got = []
+    for _ in range(2):
+        sc.submit_rows("k", "m", (np.arange(5, dtype=np.float32),
+                                  np.full(5, 2.0, np.float32)), 5,
+                       lambda mat: got.append(np.asarray(mat).copy()),
+                       pads=(-1.0, 0.0), pack=True, warm=True)
+        sc.drain_once(force=True)
+    assert [m.shape for m in got] == [(2, 64), (2, 4096), (2, 64)]
+    assert (got[1][0] == -1.0).all() and (got[1][1] == 0.0).all()
+    assert sc.batches_total["k"] == 2 and sc.coalesced_total["k"] == 2
+    assert sc.bucket_warmups["k"] == 2
+
+
+@pytest.mark.parametrize("jobs, want", [
+    (4, [4096]),                # its own bucket is warm
+    (5, [16384]),               # a burst: the smallest warm that holds it
+    (9, [16384]),               # a backlog: the same, no 8192 compiled
+    (20, [16384, 4096]),        # past max_batch_rows: cut there
+])
+def test_a_warm_kernels_backlog_goes_out_in_warm_buckets(jobs, want):
+    """After a `warm` kernel's first dispatch no chunk of several jobs
+    takes a bucket the kernel has not used: nothing compiles on the
+    serving path, whatever the backlog."""
+    sc = _manual(SchedConfig(max_batch_rows=16384, min_bucket_rows=64))
+    seen = []
+
+    def submit(n):
+        sc.submit_rows("k", "m", (np.zeros(n, np.int32),), n,
+                       lambda slots: seen.append(len(slots)), pads=(-1,),
+                       warm=True)
+
+    submit(4000)
+    sc.drain_once(force=True)
+    assert seen == [4096, 16384]
+    warmups = dict(sc.bucket_warmups)
+    seen.clear()
+    for _ in range(jobs):
+        submit(1000)
+    sc.drain_once(force=True)
+    assert seen == want
+    assert sc.bucket_warmups == warmups
+
+
 # ---------------------------------------------------------------------------
 # batch-close policy: occupancy target or deadline, whichever first
 # ---------------------------------------------------------------------------
@@ -582,6 +657,36 @@ def test_zero_recompiles_after_warmup():
             sc.drain_once(force=True)
         assert JIT_COMPILES.value(label) == warm
         assert sc.bucket_warmups == warm_buckets
+
+
+def test_a_spanmetrics_backlog_compiles_nothing_after_the_first_push():
+    """The span-metrics step is a `warm` kernel: its first push also
+    compiles the top bucket (with pad rows, which change no series), so
+    a backlog of pushes that lands in ONE drain compiles nothing more,
+    and the state equals direct, unbatched dispatch."""
+    from tempo_tpu.obs.jaxruntime import JIT_COMPILES
+
+    sc = DeviceScheduler(SchedConfig(batch_window_ms=0.0),
+                         start_worker=False)
+    reg, proc = _mk_proc()
+    ref, proc_ref = _mk_proc(use_scheduler=False)
+    label = ("spanmetrics_fused_update",)
+    batches = [_spans_for("t", 30 + 11 * i, seed=70 + i) for i in range(13)]
+    with sched.use(sc):
+        _push_spans(proc, reg, batches[0])
+        sc.drain_once(force=True)
+        warm = JIT_COMPILES.value(label)
+        for b in batches[1:]:
+            _push_spans(proc, reg, b)
+        sc.drain_once(force=True)
+        assert JIT_COMPILES.value(label) == warm
+    assert sc.batches_total["spanmetrics_fused_update"] == 2
+    for b in batches:
+        _push_spans(proc_ref, ref, b)
+    np.testing.assert_array_equal(np.asarray(proc.calls.state.values),
+                                  np.asarray(proc_ref.calls.state.values))
+    np.testing.assert_array_equal(np.asarray(proc.dd.counts),
+                                  np.asarray(proc_ref.dd.counts))
 
 
 def test_collect_flushes_queued_batches():

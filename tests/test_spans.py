@@ -244,8 +244,10 @@ def test_a_wait_reads_as_duration_and_no_cpu():
     step = _cpu_step_ns()
     lock = threading.Lock()
     lock.acquire()
-    threading.Timer(0.05, lock.release).start()
     with tracing.span("parked"):
+        # the release is timed from inside the span, so the span lasts
+        # at least the 50 ms however late the main thread reaches it
+        threading.Timer(0.05, lock.release).start()
         assert lock.acquire(timeout=10)
     row = _row("parked")
     assert row[1] >= 50e6 and 0 <= row[5] < 10e6 + step and row[6] == 1
@@ -429,7 +431,15 @@ def _served_push(tmp_path):
         req = urllib.request.Request(
             f"{base}/v1/traces", data=payload,
             headers={"Content-Type": "application/x-protobuf"})
+        key = ("api.push", "clear")
+        before = tracing.span_rows().get(key, [0])[0]
         urllib.request.urlopen(req, timeout=60).close()
+        # `api.push` closes after its response is written: wait for the
+        # server thread to record it before anything reads the rows
+        deadline = time.monotonic() + 30
+        while tracing.span_rows().get(key, [0])[0] == before \
+                and time.monotonic() < deadline:
+            time.sleep(0.001)
         sched.flush()
 
     def metrics() -> str:
@@ -622,18 +632,31 @@ def test_paged_fused_update_keeps_its_jit_name():
         "jit__fused_update_paged", "jit__fused_update"}
 
 
-def test_edge_update_stays_outside_the_fused_update_match():
-    """The service-graph step is a module of its own in a profile: no
-    layer file's prefix (`jit__fused_update` above all, the spanmetrics
-    kernel's roofline) may pick it up but its own roofline's
-    (`edge_update_roofline_pct.hotrod`)."""
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_edge_update_stays_outside_the_fused_update_match(layout):
+    """The service-graph step is a module of its own in a profile on
+    either layout: no layer file's prefix (`jit__fused_update` above all,
+    the spanmetrics kernel's roofline) may pick it up but its own
+    roofline's (`edge_update_roofline_pct.hotrod`, which reads the dense
+    cell only, where the paged step never runs)."""
     from tempo_tpu.generator.processors import servicegraphs as sg
+    from tempo_tpu.ops import pages as op
     from tempo_tpu.registry import ManagedRegistry
 
-    p = sg.ServiceGraphsProcessor(ManagedRegistry("t"))
-    name = _module_name(sg._edge_update._jit.lower(
-        tuple(f.state for f in p._families), np.zeros((4, 16), np.float32)))
-    assert name == "jit__edge_update_impl"
+    packed = np.zeros((4, 16), np.float32)
+    if layout == "dense":
+        p = sg.ServiceGraphsProcessor(ManagedRegistry("t"))
+        name = _module_name(sg._edge_update._jit.lower(
+            tuple(f.state for f in p._families), packed))
+    else:
+        f32 = np.float32
+        row, table = np.zeros(128, f32), np.zeros(2, np.int32)
+        hist = [np.zeros((128, 3), f32), row, row]
+        step = op.edge_step((0.1, 1.0), 6, False)
+        name = _module_name(step._jit.lower(
+            row, row, *hist, *hist, *[table] * 8, packed))
+    assert name == {"dense": "jit__edge_update_impl",
+                    "paged": "jit__edge_update_paged_impl"}[layout]
     assert "jit__fused_update" in _layer_prefixes()
     assert {pre for pre in _layer_prefixes() if name.startswith(pre)} == {
         "jit__edge_update"}
